@@ -71,7 +71,7 @@ def _timed_generate(mode, params, cfg, prompts, new_tokens, cache_len,
         t1 = time.perf_counter()
         tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         if mode == "scan":
-            toks = dec(params, tok, state, key, ids)
+            toks, _ = dec(params, tok, state, key, ids)
             jax.block_until_ready(toks)
             out = jnp.concatenate([tok[:, None], toks], axis=1)
         else:

@@ -69,6 +69,7 @@ def main(argv=None):
     from repro.launch.mesh import make_production_mesh
     from repro.launch.steps import TrainSpec
 
+    dryrun.fake_host_devices()
     mesh = make_production_mesh(multi_pod=(args.mesh == "multi"))
     lowered = dryrun.lower_combination(args.arch, args.shape, mesh,
                                        TrainSpec(rank=64), unroll=True,

@@ -6,10 +6,11 @@ multi-pod) and print the memory / cost / collective analysis.
 """
 import sys
 
-from repro.launch import dryrun  # sets XLA_FLAGS before jax init
+from repro.launch import dryrun
 
 
 def main():
+    dryrun.fake_host_devices()
     arch = sys.argv[1] if len(sys.argv) > 1 else "granite-moe-1b-a400m"
     from repro.launch.mesh import make_production_mesh
     from repro.launch.steps import TrainSpec

@@ -530,6 +530,18 @@ class FedEngine:
         return {"local_loss": losses,                      # (K, T)
                 "mean_final_loss": float(jnp.mean(losses[:, -1]))}
 
+    def lower_round(self, client_batches: PyTree, weights=None):
+        """The default round program (unmasked, unguarded, fused) lowered
+        for these batches against the engine's current state, without
+        running it — for reading its compiled program."""
+        k_clients = jax.tree_util.tree_leaves(client_batches)[0].shape[0]
+        self._ensure_client_buffers(k_clients)
+        return self._round_jitted().lower(
+            self._client_state, self._client_opt, self.global_trainable,
+            self.frozen, self.synced_v,
+            jnp.asarray(self.round_idx, jnp.int32), client_batches,
+            self._normalize_weights(weights, k_clients))
+
     def run_rounds(self, round_batches: PyTree, weights=None, masks=None):
         """K rounds as ONE dispatch: ``lax.scan`` over the fused round.
 

@@ -33,9 +33,9 @@ stages. On CPU/GPU-jnp the cheap GEMM+Adam chain stays per leaf (reading each
 dense gradient exactly once beats a stack/unstack round-trip) and XLA fuses
 the projected-space elementwise chain. ``GaloreConfig.fused=False`` selects
 the original per-leaf reference loop, retained as the parity oracle;
-``GaloreConfig.use_pallas`` forces the kernel on/off (None = auto: TPU only —
-on CPU the kernel still runs, in interpret mode, when forced on, which is what
-the parity tests use).
+``GaloreConfig.use_pallas`` forces the kernel on/off (None = auto:
+``kernels.ops.use_kernels`` — on CPU the kernel still runs, in interpret
+mode, when forced on, which is what the parity tests use).
 """
 from __future__ import annotations
 
@@ -92,7 +92,8 @@ class GaloreConfig:
     bias_correction: bool = True
     # Fused/bucketed execution (see module docstring). fused=False restores
     # the per-leaf reference loop (the parity oracle). use_pallas: None = auto
-    # (TPU backend only); True forces the kernel (interpret mode off-TPU).
+    # (kernels.ops.use_kernels); True forces the kernel (interpret mode
+    # off-TPU).
     fused: bool = True
     use_pallas: Optional[bool] = None
     pallas_block_rows: int = 128
@@ -219,7 +220,7 @@ def _dense_update(cfg: GaloreConfig, g, st: DenseMoments, count):
 def _resolve_use_pallas(cfg: GaloreConfig) -> bool:
     if cfg.use_pallas is not None:
         return cfg.use_pallas
-    return jax.default_backend() == "tpu"
+    return kops.use_kernels()
 
 
 def _bucketed_update(cfg: GaloreConfig, use_pallas: bool, g_leaves,

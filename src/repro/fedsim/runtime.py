@@ -76,6 +76,7 @@ from ..configs.base import ArchConfig
 from ..core import galore as gal
 from ..core import population as pop_lib
 from ..launch import steps as steps_lib
+from ..sharding import rules as rules_lib
 
 PyTree = Any
 
@@ -107,7 +108,7 @@ class ShardedFederation:
                  pipeline_sync: bool = True):
         self.cfg = cfg
         self.spec = spec
-        self.mesh = mesh
+        self.mesh = rules_lib.auto_axes(mesh)
         self.n_clients = n_clients
         self.state_sync = state_sync
         self.factored_sync = factored_sync
@@ -269,7 +270,7 @@ class ShardedFederation:
             if mask is not None:
                 w = w * jnp.asarray(mask, w.dtype)
         extra = () if attack is None else (attack,)
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             new_global, out_states, losses, v_upload = round_fn(
                 self.global_trainable, self.frozen, self.opt_states,
                 batches, w, *extra)
@@ -439,7 +440,7 @@ class ShardedFederation:
                                                    donate_argnums=(0, 2))
             scan_fn = self._rounds_scan_masked
             w_arg = jnp.asarray(np.asarray(w)[None] * masks, w.dtype)
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             (self.global_trainable, self.opt_states), losses = \
                 scan_fn(self.global_trainable, self.frozen,
                         self.opt_states, batches, w_arg)

@@ -19,9 +19,10 @@ MXU-friendly matrix algebra (no scatters, no dynamic row updates):
     A ← Jᵀ A J,   V ← V J
 
 where P/Q are the step's static one-hot pair embeddings (n, n_pairs) and
-(c, s) come from the standard symmetric-Schur 2×2 solve on the current
-(app, aqq, apq) diagonals. Zero off-diagonals are pinned to θ = 0 so
-converged (and phantom) pairs are exact no-ops instead of π/2 swaps.
+(c, s) come from Rutishauser's symmetric-Schur 2×2 solve on the current
+(app, aqq, apq) diagonals, which needs only square roots and divides (the
+TPU kernel compiler has no ``atan2``). Zero off-diagonals are pinned to
+t = 0 so converged (and phantom) pairs are exact no-ops.
 
 Convergence: cyclic Jacobi is globally convergent and asymptotically
 quadratic; ``sweeps`` is a fixed compile-time count (default 12 — machine
@@ -31,7 +32,7 @@ columns — the ``jnp.linalg.eigh`` convention — so the kernel is a drop-in
 for the LAPACK path (eigenvector sign/rotation within degenerate clusters
 is implementation-defined in both).
 
-On the CPU container the kernel runs in ``interpret=True`` mode (property
+On the CPU the kernel runs in ``interpret=True`` mode (property
 tests force it through ``ops.batched_small_eigh(force="jacobi")``); the
 production CPU path stays on LAPACK via the ``ops`` wrapper.
 """
@@ -43,6 +44,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+
+from . import mxu
 
 MAX_JACOBI_DIM = 64
 
@@ -82,48 +85,61 @@ def _schedule_onehots(n: int):
     return p, q
 
 
-def _jacobi_sweeps(a, p_oh, q_oh, sweeps: int):
+# Batched (b, ·, ·) contractions in the two forms the TPU kernel compiler
+# lowers: A @ B and A @ Bᵀ, batch dim leading on both operands.
+_NN = (((2,), (1,)), ((0,), (0,)))
+_NT = (((2,), (2,)), ((0,), (0,)))
+
+
+def _jacobi_sweeps(a, p_ref, q_ref, sweeps: int):
     """Run ``sweeps`` full parallel-Jacobi sweeps on a (bb, n, n) symmetric
-    stack. Returns (diag, V) with A ≈ V diag(diag) Vᵀ, unsorted."""
+    stack. ``p_ref``/``q_ref`` hold the (n_steps, n, n_pairs) schedule.
+    Returns (diag, V) with A ≈ V diag(diag) Vᵀ, unsorted."""
     bb, n, _ = a.shape
-    n_steps = p_oh.shape[0]
-    eye = jnp.eye(n, dtype=jnp.float32)
-    v0 = jnp.broadcast_to(eye, (bb, n, n))
+    n_steps, _, n_pairs = p_ref.shape
+    eye = jnp.broadcast_to(jnp.eye(n, dtype=jnp.float32), (bb, n, n))
 
     def step(s, carry):
         a, v = carry
         idx = s % n_steps
-        pm = jax.lax.dynamic_index_in_dim(p_oh, idx, keepdims=False)
-        qm = jax.lax.dynamic_index_in_dim(q_oh, idx, keepdims=False)
-        app = jnp.einsum("nk,bnm,mk->bk", pm, a, pm)
-        aqq = jnp.einsum("nk,bnm,mk->bk", qm, a, qm)
-        apq = jnp.einsum("nk,bnm,mk->bk", pm, a, qm)
-        theta = 0.5 * jnp.arctan2(2.0 * apq, aqq - app)
-        # exact-zero off-diagonals (converged / phantom pairs) must rotate
-        # by 0, not the π/2 swap arctan2(0, negative) would produce
-        theta = jnp.where(apq == 0.0, 0.0, theta)
-        c = jnp.cos(theta)
-        s_ = jnp.sin(theta)
-        j = (eye[None]
-             + jnp.einsum("nk,bk,mk->bnm", pm, c - 1.0, pm)
-             + jnp.einsum("nk,bk,mk->bnm", qm, c - 1.0, qm)
-             + jnp.einsum("nk,bk,mk->bnm", pm, s_, qm)
-             - jnp.einsum("nk,bk,mk->bnm", qm, s_, pm))
-        aj = jnp.einsum("bnm,bml->bnl", a, j)
-        a = jnp.einsum("bmn,bml->bnl", j, aj)
-        a = 0.5 * (a + jnp.swapaxes(a, -1, -2))   # pin symmetry drift
-        v = jnp.einsum("bnm,bml->bnl", v, j)
+        pm = jnp.broadcast_to(p_ref[idx], (bb, n, n_pairs))
+        qm = jnp.broadcast_to(q_ref[idx], (bb, n, n_pairs))
+        # pair diagonals a_pp, a_qq, a_pq as one-hot masked reductions
+        ap = mxu.dot(a, pm, _NN)
+        aq = mxu.dot(a, qm, _NN)
+        app = jnp.sum(pm * ap, axis=1)
+        aqq = jnp.sum(qm * aq, axis=1)
+        apq = jnp.sum(pm * aq, axis=1)
+        # Rutishauser's symmetric 2×2 Schur rotation (the smaller angle).
+        # Exact-zero off-diagonals (converged / phantom pairs) rotate by 0.
+        zero = apq == 0.0
+        tau = (aqq - app) / (2.0 * jnp.where(zero, 1.0, apq))
+        t = jnp.where(tau >= 0.0, 1.0, -1.0) / (
+            jnp.abs(tau) + jnp.sqrt(1.0 + tau * tau))
+        t = jnp.where(zero, 0.0, t)
+        c = 1.0 / jnp.sqrt(1.0 + t * t)
+        s_ = t * c
+        # J = I + X Pᵀ + Y Qᵀ and Jᵀ = I + P Xᵀ + Q Yᵀ, with
+        # X = P diag(c-1) - Q diag(s), Y = Q diag(c-1) + P diag(s)
+        cm1 = (c - 1.0)[:, None, :]
+        s3 = s_[:, None, :]
+        x = pm * cm1 - qm * s3
+        y = qm * cm1 + pm * s3
+        j = eye + mxu.dot(x, pm, _NT) + mxu.dot(y, qm, _NT)
+        jt = eye + mxu.dot(pm, x, _NT) + mxu.dot(qm, y, _NT)
+        a = mxu.dot(jt, mxu.dot(a, j, _NN), _NN)
+        # pin symmetry drift; Aᵀ = I·Aᵀ is the A @ Bᵀ form
+        a = 0.5 * (a + mxu.dot(eye, a, _NT))
+        v = mxu.dot(v, j, _NN)
         return a, v
 
-    a, v = jax.lax.fori_loop(0, sweeps * n_steps, step,
-                             (a.astype(jnp.float32), v0))
-    diag = jnp.einsum("bnn->bn", a)
-    return diag, v
+    a, v = jax.lax.fori_loop(0, sweeps * n_steps, step, (a, eye))
+    return jnp.sum(a * eye, axis=-1), v
 
 
 def _kernel(a_ref, p_ref, q_ref, lam_out, vec_out, *, sweeps):
     a = a_ref[...].astype(jnp.float32)
-    diag, v = _jacobi_sweeps(a, p_ref[...], q_ref[...], sweeps)
+    diag, v = _jacobi_sweeps(a, p_ref, q_ref, sweeps)
     lam_out[...] = diag
     vec_out[...] = v
 

@@ -37,10 +37,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, scale, causal, window,
 
     def body(j, carry):
         acc, m_i, l_i = carry
-        k = pl.load(k_ref, (pl.ds(j * block_k, block_k), slice(None))
-                    ).astype(jnp.float32)             # (bk, D)
-        v = pl.load(v_ref, (pl.ds(j * block_k, block_k), slice(None))
-                    ).astype(jnp.float32)
+        rows = pl.ds(j * block_k, block_k)
+        k = k_ref[rows, :].astype(jnp.float32)        # (bk, D)
+        v = v_ref[rows, :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (bq, bk)
         k_pos = j * block_k + jax.lax.iota(jnp.int32, block_k)

@@ -46,45 +46,42 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import mxu
 
 RIGHT = "right"
 LEFT = "left"
 
 
-def _adam_update(gt, m_ref, v_ref, count_ref, b1, b2, eps, bias_correction):
-    """Shared Adam moment update + (optionally bias-corrected) direction."""
+def _adam_update(gt, m_ref, v_ref, corr_ref, b1, b2, eps):
+    """Shared Adam moment update + bias-corrected direction. ``corr_ref``
+    holds the two bias corrections (1-β₁ᵗ, 1-β₂ᵗ), computed outside the
+    kernel (ones when bias correction is off)."""
     m = b1 * m_ref[...] + (1.0 - b1) * gt
     v = b2 * v_ref[...] + (1.0 - b2) * gt * gt
-    if bias_correction:
-        c = count_ref[0, 0]
-        c1 = 1.0 - b1 ** c
-        c2 = 1.0 - b2 ** c
-    else:
-        c1 = c2 = 1.0
-    ut = (m / c1) / (jnp.sqrt(v / c2) + eps)
+    ut = (m / corr_ref[0]) / (jnp.sqrt(v / corr_ref[1]) + eps)
     return m, v, ut
 
 
 def _project(g, basis, side):
     if side == RIGHT:
-        return jnp.dot(g, basis, preferred_element_type=jnp.float32)
-    return jnp.dot(basis.T, g, preferred_element_type=jnp.float32)
+        return mxu.dot(g, basis)
+    return mxu.dot(basis.T, g)
 
 
 def _project_back(ut, basis, side):
     if side == RIGHT:
-        return jnp.dot(ut, basis.T, preferred_element_type=jnp.float32)
-    return jnp.dot(basis, ut, preferred_element_type=jnp.float32)
+        return mxu.dot(ut, basis.T)
+    return mxu.dot(basis, ut)
 
 
-def _step_kernel(count_ref, w_ref, g_ref, basis_ref, m_ref, v_ref,
-                 w_out, m_out, v_out, *, side, b1, b2, eps, lr, weight_decay,
-                 bias_correction):
+def _step_kernel(corr_ref, w_ref, g_ref, basis_ref, m_ref, v_ref,
+                 w_out, m_out, v_out, *, side, b1, b2, eps, lr, weight_decay):
     g = g_ref[...].astype(jnp.float32)
     basis = basis_ref[...].astype(jnp.float32)
     gt = _project(g, basis, side)
-    m, v, ut = _adam_update(gt, m_ref, v_ref, count_ref, b1, b2, eps,
-                            bias_correction)
+    m, v, ut = _adam_update(gt, m_ref, v_ref, corr_ref, b1, b2, eps)
     u = _project_back(ut, basis, side)
     w = w_ref[...].astype(jnp.float32)
     w_out[...] = (w - lr * u - lr * weight_decay * w).astype(w_out.dtype)
@@ -92,14 +89,13 @@ def _step_kernel(count_ref, w_ref, g_ref, basis_ref, m_ref, v_ref,
     v_out[...] = v
 
 
-def _precond_kernel(count_ref, g_ref, basis_ref, m_ref, v_ref,
+def _precond_kernel(corr_ref, g_ref, basis_ref, m_ref, v_ref,
                     u_out, m_out, v_out, *, side, b1, b2, eps,
-                    bias_correction, project_back=True):
+                    project_back=True):
     g = g_ref[...].astype(jnp.float32)
     basis = basis_ref[...].astype(jnp.float32)
     gt = _project(g, basis, side)
-    m, v, ut = _adam_update(gt, m_ref, v_ref, count_ref, b1, b2, eps,
-                            bias_correction)
+    m, v, ut = _adam_update(gt, m_ref, v_ref, corr_ref, b1, b2, eps)
     u_out[...] = _project_back(ut, basis, side) if project_back else ut
     m_out[...] = m
     v_out[...] = v
@@ -139,6 +135,19 @@ def _block_specs(side, mm, nn, r, block):
     return grid, wg, basis, mv
 
 
+def _bias_corrections(count, b1, b2, bias_correction):
+    """(1-β₁ᵗ, 1-β₂ᵗ) as a (2,) fp32 operand, the arithmetic of
+    ``core.galore._projected_adam``. Computed here because the TPU kernel
+    compiler has no ``pow`` on a traced exponent."""
+    if not bias_correction:
+        return jnp.ones((2,), jnp.float32)
+    c = jnp.asarray(count, jnp.float32)
+    return jnp.stack([1 - b1 ** c, 1 - b2 ** c])
+
+
+_SCALARS = pl.BlockSpec(memory_space=pltpu.SMEM)   # whole (2,) array in SMEM
+
+
 @functools.partial(jax.jit, static_argnames=("side", "b1", "b2", "eps", "lr",
                                              "weight_decay", "block_rows",
                                              "interpret", "bias_correction"))
@@ -165,21 +174,18 @@ def galore_adamw_step(w, g, basis, m, v, count, *, side=None, b1=0.9, b2=0.999,
     r = basis.shape[-1]
     grid, wg_spec, basis_spec, mv_spec = _block_specs(side, mm, nn, r,
                                                       block_rows)
-    count_arr = jnp.full((1, 1), count, jnp.float32)
     kernel = functools.partial(_step_kernel, side=side, b1=b1, b2=b2, eps=eps,
-                               lr=lr, weight_decay=weight_decay,
-                               bias_correction=bias_correction)
+                               lr=lr, weight_decay=weight_decay)
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),  # count (SMEM-like)
-                  wg_spec, wg_spec, basis_spec, mv_spec, mv_spec],
+        in_specs=[_SCALARS, wg_spec, wg_spec, basis_spec, mv_spec, mv_spec],
         out_specs=[wg_spec, mv_spec, mv_spec],
         out_shape=[jax.ShapeDtypeStruct(w.shape, w.dtype),
                    jax.ShapeDtypeStruct(m.shape, jnp.float32),
                    jax.ShapeDtypeStruct(v.shape, jnp.float32)],
         interpret=interpret,
-    )(count_arr, w, g, basis, m, v)
+    )(_bias_corrections(count, b1, b2, bias_correction), w, g, basis, m, v)
 
 
 @functools.partial(jax.jit, static_argnames=("side", "b1", "b2", "eps",
@@ -213,20 +219,17 @@ def galore_precond_step(g, basis, m, v, count, *, side=None, b1=0.9, b2=0.999,
     r = basis.shape[-1]
     grid, wg_spec, basis_spec, mv_spec = _block_specs(side, mm, nn, r,
                                                       block_rows)
-    count_arr = jnp.full((1, 1), count, jnp.float32)
     kernel = functools.partial(_precond_kernel, side=side, b1=b1, b2=b2,
-                               eps=eps, bias_correction=bias_correction,
-                               project_back=project_back)
+                               eps=eps, project_back=project_back)
     u_spec = wg_spec if project_back else mv_spec
     u_shape = g.shape if project_back else m.shape
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),
-                  wg_spec, basis_spec, mv_spec, mv_spec],
+        in_specs=[_SCALARS, wg_spec, basis_spec, mv_spec, mv_spec],
         out_specs=[u_spec, mv_spec, mv_spec],
         out_shape=[jax.ShapeDtypeStruct(u_shape, jnp.float32),
                    jax.ShapeDtypeStruct(m.shape, jnp.float32),
                    jax.ShapeDtypeStruct(v.shape, jnp.float32)],
         interpret=interpret,
-    )(count_arr, g, basis, m, v)
+    )(_bias_corrections(count, b1, b2, bias_correction), g, basis, m, v)

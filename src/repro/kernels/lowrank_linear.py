@@ -27,10 +27,11 @@ lives in ``models.layers.lowrank_apply``, which consumes this kernel via
 ``lowrank_linear_batched`` is the *serving* variant of the same apply: one
 decode batch where every row carries its own adapter — the S-LoRA/Punica
 shape. The base GEMM is shared across the batch; each grid program gathers
-its row's ``(basis_g, R̃_g, scale_g)`` blocks by the scalar-prefetched
-``(B,)`` adapter-id operand (the id indexes the BlockSpec ``index_map``, so
-only the selected adapter's factors are ever DMA'd — the ``(G, ·, r)``
-tables stay put no matter how many fine-tunes are resident). Ragged
+its row's ``(basis_g, R̃_g)`` blocks by the scalar-prefetched ``(B,)``
+adapter-id operand (the id indexes the BlockSpec ``index_map``, so only the
+selected adapter's factors are ever DMA'd — the ``(G, ·, r)`` tables stay
+put no matter how many fine-tunes are resident); the ``(G,)`` scales are
+gathered the same way, as a ``(G, 1, 1)`` array. Ragged
 per-adapter ranks are handled upstream by zero-padding factors to the
 table's r_max: zero basis/R̃ columns contribute exactly zero delta.
 """
@@ -42,6 +43,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import mxu
 
 RIGHT = "right"
 LEFT = "left"
@@ -60,21 +63,26 @@ def infer_side(w_shape, basis_shape, rt_shape) -> str:
                      f"basis {basis_shape}, rt {rt_shape}")
 
 
-def _kernel(scale_ref, x_ref, w_ref, basis_ref, rt_ref, y_out, *, side):
-    x = x_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
-    base = jnp.dot(x, w, preferred_element_type=jnp.float32)
-    basis = basis_ref[...].astype(jnp.float32)
-    rt = rt_ref[...].astype(jnp.float32)
+def _apply(x, w, basis, rt, scale, side):
+    """``scale·(x @ w) + split-matmul(x, basis, rt)`` on one row tile. The
+    base GEMM reads its operands in their stored dtype (bf16 products are
+    exact in the fp32 accumulator); the rank-r factors are fp32."""
+    base = mxu.dot(x, w)
+    basis = basis.astype(jnp.float32)
+    rt = rt.astype(jnp.float32)
     if side == RIGHT:
         # (bt, m) @ (m, r) @ (r, n)
-        delta = jnp.dot(jnp.dot(x, rt, preferred_element_type=jnp.float32),
-                        basis.T, preferred_element_type=jnp.float32)
+        delta = mxu.dot(mxu.dot(x, rt), basis.T)
     else:
         # (bt, m) @ (m, r) @ (r, n)
-        delta = jnp.dot(jnp.dot(x, basis, preferred_element_type=jnp.float32),
-                        rt, preferred_element_type=jnp.float32)
-    y_out[...] = (scale_ref[0, 0] * base + delta).astype(y_out.dtype)
+        delta = mxu.dot(mxu.dot(x, basis), rt)
+    return scale * base + delta
+
+
+def _kernel(scale_ref, x_ref, w_ref, basis_ref, rt_ref, y_out, *, side):
+    y = _apply(x_ref[...], w_ref[...], basis_ref[...], rt_ref[...],
+               scale_ref[0, 0], side)
+    y_out[...] = y.astype(y_out.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("side", "block_rows",
@@ -122,18 +130,9 @@ def _batched_kernel(ids_ref, x_ref, w_ref, basis_ref, rt_ref, scale_ref,
     index_maps consumed the scalar-prefetched ids, so block 0 here IS
     adapter ``ids[b]``'s block."""
     del ids_ref
-    x = x_ref[0].astype(jnp.float32)              # (bt, m)
-    w = w_ref[...].astype(jnp.float32)
-    base = jnp.dot(x, w, preferred_element_type=jnp.float32)
-    basis = basis_ref[0].astype(jnp.float32)
-    rt = rt_ref[0].astype(jnp.float32)
-    if side == RIGHT:
-        delta = jnp.dot(jnp.dot(x, rt, preferred_element_type=jnp.float32),
-                        basis.T, preferred_element_type=jnp.float32)
-    else:
-        delta = jnp.dot(jnp.dot(x, basis, preferred_element_type=jnp.float32),
-                        rt, preferred_element_type=jnp.float32)
-    y_out[0] = (scale_ref[0] * base + delta).astype(y_out.dtype)
+    y = _apply(x_ref[0], w_ref[...], basis_ref[0], rt_ref[0],
+               scale_ref[0, 0, 0], side)
+    y_out[0] = y.astype(y_out.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("side", "block_t", "interpret"))
@@ -167,7 +166,9 @@ def lowrank_linear_batched(x, w, bases, rts, scales, ids, *, side=None,
             pl.BlockSpec((mm, nn), lambda i, j, ids: (0, 0)),
             pl.BlockSpec(bshape, lambda i, j, ids: (ids[i], 0, 0)),
             pl.BlockSpec(rshape, lambda i, j, ids: (ids[i], 0, 0)),
-            pl.BlockSpec((1,), lambda i, j, ids: (ids[i],)),
+            # (G, 1, 1): a rank-3 block whose last two dims span the array,
+            # the layout the TPU compiler accepts for a per-adapter scalar
+            pl.BlockSpec((1, 1, 1), lambda i, j, ids: (ids[i], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, bt, nn), lambda i, j, ids: (i, j, 0)),
     )
@@ -177,6 +178,6 @@ def lowrank_linear_batched(x, w, bases, rts, scales, ids, *, side=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, t, nn), out_dtype),
         interpret=interpret,
-    )(jnp.asarray(ids, jnp.int32), x, w, bases,
-      rts, jnp.asarray(scales, jnp.float32))
+    )(jnp.asarray(ids, jnp.int32), x, w, bases, rts,
+      jnp.asarray(scales, jnp.float32).reshape(-1, 1, 1))
     return y[:, 0, :] if squeeze_t else y

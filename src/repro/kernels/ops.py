@@ -1,8 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On the CPU container the kernels run in ``interpret=True`` mode (the kernel
-body executes as traced Python — correctness only); on a real TPU backend
-they compile to Mosaic. ``interpret`` is auto-detected from the backend.
+On the CPU the kernels run in ``interpret=True`` mode (the kernel body
+executes as traced Python — correctness only); on a TPU backend they
+compile to Mosaic. ``interpret`` is auto-detected from the backend.
+:func:`use_kernels` is the one rule for when the model and optimizer paths
+call a kernel instead of its XLA formulation.
 """
 from __future__ import annotations
 
@@ -22,6 +24,19 @@ from .rwkv6_scan import rwkv6_scan as _rwkv6
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def use_kernels() -> bool:
+    """True on a TPU backend, unless the trace runs under an ambient mesh
+    (``jax.set_mesh``) of more than one device. The TPU compiler cannot
+    partition a Pallas call ("Mosaic kernels cannot be automatically
+    partitioned"), so a program that XLA partitions over several devices
+    keeps the XLA formulations of these ops; a one-device program, meshed
+    or not, uses the kernels."""
+    if jax.default_backend() != "tpu":
+        return False
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh.empty or mesh.size == 1
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
@@ -62,7 +77,8 @@ def batched_small_eigh(a, *, mask=None, force=None, sweeps=12, block_b=8):
     whole stack VMEM-resident (XLA's QDWH ``eigh`` is built for one large
     matrix, not (B, r, r) stacks); on CPU LAPACK's per-matrix ``syevd`` is
     already optimal, so the jnp path is the default — bit-identical to the
-    pre-kernel behavior. ``force`` pins a path for parity tests:
+    pre-kernel behavior; :func:`use_kernels` also keeps a multi-device
+    partitioned program on ``jnp``. ``force`` pins a path for parity tests:
     ``"jacobi"`` (interpret-mode on CPU) or ``"lapack"``.
 
     ``mask`` (bool, shaped like the batch dims ``a.shape[:-2]``) is the
@@ -78,7 +94,7 @@ def batched_small_eigh(a, *, mask=None, force=None, sweeps=12, block_b=8):
         sel = jnp.asarray(mask, bool)[..., None, None]
         a = jnp.where(sel, a, jnp.eye(n, dtype=a.dtype))
     use_jacobi = (force == "jacobi" or
-                  (force is None and not _interpret() and n <= MAX_JACOBI_DIM))
+                  (force is None and use_kernels() and n <= MAX_JACOBI_DIM))
     if force == "lapack":
         use_jacobi = False
     if use_jacobi:

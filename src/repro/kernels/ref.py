@@ -32,6 +32,27 @@ def galore_adamw_ref(w, g, basis, m, v, *, count, b1=0.9, b2=0.999, eps=1e-8,
     return w_new.astype(w.dtype), m_new, v_new
 
 
+def galore_precond_ref(g, basis, m, v, *, count, side, b1=0.9, b2=0.999,
+                       eps=1e-8, project_back=True):
+    """Preconditioning-only GaLore step for one block, either side.
+
+    right: basis (N, r), m, v (M, r); left: basis (M, r), m, v (r, N).
+    Returns (u, new_m, new_v): u is the ambient (M, N) direction, or the
+    projected ũ in the moment shape when ``project_back=False``.
+    """
+    g32 = g.astype(jnp.float32)
+    b32 = basis.astype(jnp.float32)
+    gt = g32 @ b32 if side == "right" else b32.T @ g32
+    m_new = b1 * m + (1 - b1) * gt
+    v_new = b2 * v + (1 - b2) * gt * gt
+    c = jnp.asarray(count, jnp.float32)
+    ut = (m_new / (1 - b1 ** c)) / (jnp.sqrt(v_new / (1 - b2 ** c)) + eps)
+    if not project_back:
+        return ut, m_new, v_new
+    u = ut @ b32.T if side == "right" else b32 @ ut
+    return u, m_new, v_new
+
+
 def lowrank_linear_ref(x, w, basis, rt, scale, *, side):
     """Lift-free low-rank linear apply for one factored block.
 

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                           " --xla_force_host_platform_device_count=512").strip()
-
 """Multi-pod dry-run: prove the distribution config is coherent.
 
 For every (architecture × input shape × mesh) combination this lowers and
@@ -21,6 +17,7 @@ Usage:
 
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -177,7 +174,7 @@ def lower_combination(arch: str, shape_name: str, mesh,
         )
         jitted = jax.jit(step, in_shardings=in_shardings,
                          donate_argnums=(0, 2) if donate else ())
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(trainable_c, frozen, opt_c, cbatch)
         return lowered
 
@@ -198,7 +195,7 @@ def lower_combination(arch: str, shape_name: str, mesh,
             in_sh = in_sh + (NamedSharding(mesh, rules.batch_spec(
                 batch["embeds"].shape)),)
         jitted = jax.jit(step, in_shardings=in_sh)
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(*args)
         return lowered
 
@@ -209,7 +206,7 @@ def lower_combination(arch: str, shape_name: str, mesh,
     tok_sh = NamedSharding(mesh, rules.batch_spec(specs["token"].shape))
     jitted = jax.jit(step, in_shardings=(p_shard, tok_sh, state_sh),
                      donate_argnums=(2,) if donate else ())
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(params, specs["token"], specs["state"])
     return lowered
 
@@ -261,7 +258,7 @@ def lower_fed_round(arch: str, mesh,
         NamedSharding(mesh, P()),
     )
     jitted = jax.jit(step, in_shardings=in_sh)
-    with mesh:
+    with jax.set_mesh(mesh):
         return jitted.lower(trainable, frozen, opt_c, cbatch, weights)
 
 
@@ -348,7 +345,17 @@ def analyze_combination(arch: str, shape_name: str, mesh, spec,
     return out
 
 
+def fake_host_devices(n: int = 512) -> None:
+    """Give the CPU backend ``n`` devices, the production mesh's size. Call
+    before the first JAX operation: the flag is read when the backend
+    starts."""
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "") +
+        f" --xla_force_host_platform_device_count={n}").strip()
+
+
 def main(argv=None):
+    fake_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None,

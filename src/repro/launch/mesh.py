@@ -12,19 +12,21 @@ from __future__ import annotations
 
 import jax
 
+from ..sharding.rules import auto_axes
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_axes(jax.make_mesh(shape, axes))
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Tiny mesh over whatever devices exist (CPU tests / examples)."""
     n = jax.device_count()
     assert n % model_parallel == 0
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return auto_axes(jax.make_mesh((n // model_parallel, model_parallel),
+                                   ("data", "model")))
 
 
 # TPU v5e hardware constants used by the roofline analysis.

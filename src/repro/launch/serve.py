@@ -33,7 +33,6 @@ import functools
 import json
 import os
 import time
-import warnings
 from typing import Any, Dict, List
 
 import numpy as np
@@ -41,15 +40,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-# Decode state is donated into the scan programs; on CPU some leaves can't
-# alias (dtype/layout mismatch) and jax warns per compile. Harmless here —
-# donation is for the TPU path — so keep serving logs clean.
-warnings.filterwarnings(
-    "ignore", message="Some donated buffers were not usable")
-
 from ..configs import get_config, smoke_variant
 from ..models import layers
 from ..models import model as model_lib
+from .cache import use_compile_cache
 
 PAD_ID = 0   # emitted by retired slots inside a segment; never surfaced
 
@@ -109,8 +103,9 @@ def _eager_step_fn(cfg):
 @functools.lru_cache(maxsize=None)
 def _scan_decode_fn(cfg, steps: int, temperature: float):
     """The fused decode loop: ``steps`` tokens after the prefill-sampled
-    one, as a single device program. State is donated — the KV ring
-    buffers alias in place instead of round-tripping per token."""
+    one, as a single device program. Returns ``(tokens (B, steps), final
+    state)``; the state is donated and returned, so the KV ring buffers
+    alias in place instead of being copied into the loop."""
     def run(params, tok0, state, key, ids):
         def body(carry, i):
             tok, st = carry
@@ -118,8 +113,9 @@ def _scan_decode_fn(cfg, steps: int, temperature: float):
                 logits, st = model_lib.decode_step(params, cfg, tok, st)
             nxt = _sample(logits, jax.random.fold_in(key, i), temperature)
             return (nxt, st), nxt
-        (_, _), toks = jax.lax.scan(body, (tok0, state), jnp.arange(steps))
-        return jnp.moveaxis(toks, 0, 1)            # (B, steps)
+        (_, state), toks = jax.lax.scan(body, (tok0, state),
+                                        jnp.arange(steps))
+        return jnp.moveaxis(toks, 0, 1), state     # (B, steps)
     return jax.jit(run, donate_argnums=(2,))
 
 
@@ -210,7 +206,7 @@ def generate_scan(params, cfg, prompts, new_tokens: int, cache_len: int,
     tok0 = _sample(logits, key, temperature)
     if new_tokens <= 1:
         return jnp.concatenate([prompts, tok0[:, None]], axis=1)
-    toks = _scan_decode_fn(cfg, new_tokens - 1, float(temperature))(
+    toks, _ = _scan_decode_fn(cfg, new_tokens - 1, float(temperature))(
         params, tok0, state, key, ids)
     return jnp.concatenate([prompts, tok0[:, None], toks], axis=1)
 
@@ -367,7 +363,11 @@ class SlotServer:
 # --------------------------------------------------------------------------
 
 def main(argv=None):
+    """Serve one seeded batch (or request stream) and print its summary.
+    Returns the summary plus ``outputs``: every request's generated token
+    ids (prompt excluded)."""
     _env_hygiene()
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="rwkv6-1.6b")
     ap.add_argument("--smoke", action="store_true")
@@ -437,7 +437,7 @@ def main(argv=None):
             if total > 0 else 0.0,
             "sample_row": out["outputs"][0]})
         print(json.dumps(res))
-        return
+        return {**res, "outputs": out["outputs"]}
 
     ids = row_ids
     pre = _prefill_fn(cfg)
@@ -456,7 +456,7 @@ def main(argv=None):
         tok0 = _sample(logits, key, args.temperature)
         if args.mode == "scan":
             if args.new_tokens > 1:
-                toks = dec(params, tok0, state, key, ids)
+                toks, _ = dec(params, tok0, state, key, ids)
                 jax.block_until_ready(toks)
                 out = jnp.concatenate([prompts, tok0[:, None], toks], axis=1)
             else:
@@ -493,6 +493,8 @@ def main(argv=None):
         if total > 0 else 0.0,
         "sample_row": out[0, -args.new_tokens:].tolist()})
     print(json.dumps(res))
+    new = np.asarray(out[:, -args.new_tokens:])
+    return {**res, "outputs": {i: new[i].tolist() for i in range(len(new))}}
 
 
 if __name__ == "__main__":

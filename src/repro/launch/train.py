@@ -12,21 +12,56 @@ the mesh is whatever devices exist.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..configs import get_config, smoke_variant
 from ..core.fed import FedConfig, FedEngine, METHODS
 from ..data import FederatedBatcher, seq_classification
 from ..models import model as model_lib
+from .cache import use_compile_cache
 from .steps import galore_target_fn
+
+EVAL_ROWS = 8   # rows per evaluation program: bounds its full-vocab logits
+
+
+@functools.lru_cache(maxsize=None)
+def _eval_fn(cfg):
+    """(loss, labelled positions, last-position hits) for one row chunk."""
+    def run(params, batch):
+        logits, _ = model_lib.forward(params, cfg, batch["tokens"],
+                                      batch.get("embeds"))
+        hits = jnp.sum(jnp.argmax(logits[:, -1], -1) == batch["labels"][:, -1])
+        return (model_lib.loss_fn(params, cfg, batch),
+                jnp.sum(batch["labels"] >= 0), hits)
+    return jax.jit(run)
+
+
+def evaluate(params, cfg, batch):
+    """Validation loss and last-position accuracy over ``batch``, run in
+    chunks of ``EVAL_ROWS`` rows: a whole batch's fp32 logits and their
+    log-softmax would not fit a chip at a 150k vocabulary. The loss is the
+    labelled-position-weighted mean of the chunk losses."""
+    n = len(batch["tokens"])
+    loss_sum = labelled = hits = 0.0
+    for i in range(0, n, EVAL_ROWS):
+        chunk = {k: v[i:i + EVAL_ROWS] for k, v in batch.items()}
+        loss, count, hit = _eval_fn(cfg)(params, chunk)
+        loss_sum += float(loss) * int(count)
+        labelled += int(count)
+        hits += int(hit)
+    return loss_sum / max(labelled, 1), hits / n
 
 
 def main(argv=None):
+    """Run the federated rounds and print one JSON line per round. Returns
+    ``{"history", "engine", "last_batches"}``: the per-round rows, the
+    engine with the final global state, and the last round's batches."""
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--smoke", action="store_true",
@@ -83,11 +118,7 @@ def main(argv=None):
         batches = {k: jnp.asarray(v) for k, v in batches.items()}
         metrics = engine.run_round(batches)
         gp = engine.global_params()
-        logits, _ = model_lib.forward(gp, cfg, eval_batch["tokens"],
-                                      eval_batch.get("embeds"))
-        lab = np.asarray(eval_batch["labels"][:, -1])
-        acc = float((np.asarray(logits[:, -1]).argmax(-1) == lab).mean())
-        val = float(model_lib.loss_fn(gp, cfg, eval_batch))
+        val, acc = evaluate(gp, cfg, eval_batch)
         row = {"round": rnd, "local_loss": metrics["mean_final_loss"],
                "val_loss": val, "val_acc": acc,
                "sec": round(time.time() - t0, 2)}
@@ -100,7 +131,7 @@ def main(argv=None):
     if args.out:
         with open(args.out, "w") as f:
             json.dump(history, f, indent=1)
-    return history
+    return {"history": history, "engine": engine, "last_batches": batches}
 
 
 if __name__ == "__main__":

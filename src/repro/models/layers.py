@@ -40,13 +40,14 @@ def dense_init(key, shape, scale: float = 0.02, dtype=jnp.float32):
 # five fields. LoRA / dense methods never construct LowRankDelta leaves, so
 # `dense(x, plain_array)` is exactly `x @ w` for them.
 
-_LOWRANK_PALLAS_OVERRIDE = [None]   # None = auto (TPU backend only)
+_LOWRANK_PALLAS_OVERRIDE = [None]   # None = auto (kops.use_kernels)
 
 
 class lowrank_pallas_override:
     """Force the fused ``lowrank_linear`` kernel on/off inside ``dense``
-    (None = auto: TPU only; tests force True to run the kernel in interpret
-    mode). Usable as a context manager around tracing."""
+    (None = auto: ``kernels.ops.use_kernels``; tests force True to run the
+    kernel in interpret mode). Usable as a context manager around
+    tracing."""
 
     def __init__(self, flag):
         self.flag = flag
@@ -64,7 +65,7 @@ def _use_lowrank_pallas() -> bool:
     flag = _LOWRANK_PALLAS_OVERRIDE[-1]
     if flag is not None:
         return flag
-    return jax.default_backend() == "tpu"
+    return kops.use_kernels()
 
 
 class LowRankDelta(NamedTuple):
@@ -380,29 +381,22 @@ def batch_axes_override(axes):
 
 
 def constrain(x: jnp.ndarray, *spec):
-    """Best-effort sharding constraint: 'batch' resolves to whichever of
-    (pod, data) exist on the ambient mesh; 'model' must exist; no-op when
-    tracing without a mesh (host-scale runs) or when a dim doesn't divide.
+    """Sharding constraint against the ambient mesh (``jax.set_mesh``):
+    'batch' resolves to whichever of (pod, data) exist on it; 'model' must
+    exist; an axis that does not divide its dim is dropped. A no-op when
+    tracing without a mesh that has a 'model' axis (host-scale runs).
 
     These hints pin the batch dimension of attention intermediates — without
     them SPMD can replicate the (L, L) score tensors across the data axis
     (§Perf iteration B measured a 16× bytes regression from exactly that).
     """
-    from jax.sharding import PartitionSpec
-    try:
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-        if mesh.empty:
-            return x
-        axis_names = mesh.axis_names
-    except Exception:  # noqa: BLE001
-        return x
-    if "model" not in axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return x
     if _BATCH_AXES_OVERRIDE[-1] is not None:
         batch_axes = tuple(_BATCH_AXES_OVERRIDE[-1])
     else:
-        batch_axes = tuple(n for n in ("pod", "data") if n in axis_names)
+        batch_axes = tuple(n for n in ("pod", "data") if n in mesh.axis_names)
     sizes = dict(mesh.shape)
     resolved = []
     for dim, s in zip(x.shape, spec):
@@ -416,10 +410,8 @@ def constrain(x: jnp.ndarray, *spec):
             if dim % total != 0:
                 s = None
         resolved.append(s)
-    try:
-        return jax.lax.with_sharding_constraint(x, PartitionSpec(*resolved))
-    except Exception:  # noqa: BLE001
-        return x
+    return jax.lax.with_sharding_constraint(
+        x, jax.sharding.PartitionSpec(*resolved))
 
 
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6):

@@ -26,7 +26,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 PyTree = Any
 
@@ -72,6 +72,18 @@ def _guard(shape, mesh: Mesh, spec_dims) -> P:
     return P(*out)
 
 
+def auto_axes(mesh):
+    """``mesh`` with every axis in Auto mode. The program places arrays with
+    these rules and steers propagation with sharding constraints, which is
+    Auto-mode sharding; on an Explicit mesh (``jax.make_mesh``'s default)
+    every op would instead type its own output sharding, and the embedding
+    gather alone asks for ``P('data', None, 'data')``."""
+    auto = (AxisType.Auto,) * len(mesh.axis_names)
+    if not isinstance(mesh, Mesh) or tuple(mesh.axis_types) == auto:
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names, axis_types=auto)
+
+
 def path_of(path) -> str:
     return "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
 
@@ -86,7 +98,7 @@ class ShardingRules:
 
     def __init__(self, mesh: Mesh, data_axis: str = "data",
                  model_axis: str = "model", fsdp: bool = True):
-        self.mesh = mesh
+        self.mesh = auto_axes(mesh)
         self.data_axis = data_axis
         self.model_axis = model_axis
         self.fsdp = fsdp

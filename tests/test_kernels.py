@@ -144,6 +144,63 @@ class TestGaloreKernel:
         assert jnp.allclose(lifted, u, atol=1e-5)
 
 
+    @pytest.mark.parametrize("project_back", [True, False])
+    @pytest.mark.parametrize("side,shape", [("right", (200, 128)),
+                                            ("left", (128, 200))])
+    def test_precond_matches_ref(self, side, shape, project_back):
+        """Both sides, ambient and projected output, against the two-sided
+        oracle the chip smoke check also uses."""
+        m, n = shape
+        r = 8
+        dim, mv_shape = (n, (m, r)) if side == "right" else (m, (r, n))
+        ks = jax.random.split(KEY, 4)
+        g = jax.random.normal(ks[0], (m, n))
+        basis = jnp.linalg.qr(jax.random.normal(ks[1], (dim, r)))[0]
+        mm = 0.1 * jax.random.normal(ks[2], mv_shape, jnp.float32)
+        vv = 0.01 * jnp.abs(jax.random.normal(ks[3], mv_shape, jnp.float32))
+        got = ops.galore_precond_step(g, basis, mm, vv, 5.0, block_rows=64,
+                                      project_back=project_back)
+        want = ref.galore_precond_ref(g, basis, mm, vv, count=5.0, side=side,
+                                      project_back=project_back)
+        for a, b in zip(got, want):
+            assert a.shape == b.shape
+            assert jnp.allclose(a, b, atol=1e-5)
+
+    def test_bias_correction_off(self):
+        """bias_correction=False divides by exact ones."""
+        m, n, r = 64, 128, 8
+        ks = jax.random.split(KEY, 4)
+        g = jax.random.normal(ks[0], (m, n))
+        basis = jnp.linalg.qr(jax.random.normal(ks[1], (n, r)))[0]
+        mm = 0.1 * jax.random.normal(ks[2], (m, r), jnp.float32)
+        vv = 0.01 * jnp.abs(jax.random.normal(ks[3], (m, r), jnp.float32))
+        ut, m_new, v_new = ops.galore_precond_step(
+            g, basis, mm, vv, 7.0, project_back=False, bias_correction=False)
+        assert jnp.allclose(ut, m_new / (jnp.sqrt(v_new) + 1e-8), atol=1e-6)
+
+
+class TestKernelDispatch:
+    """``ops.use_kernels``: the kernels replace their XLA formulations on a
+    TPU backend, except under an ambient mesh of several devices, whose
+    partitioned program cannot hold a Pallas call."""
+
+    def test_cpu_backend_keeps_xla(self):
+        assert not ops.use_kernels()
+
+    @pytest.mark.parametrize("mesh_shape,want", [(None, True),
+                                                 ((1, 1), True),
+                                                 ((4, 1), False),
+                                                 ((2, 2), False)])
+    def test_tpu_rule(self, monkeypatch, mesh_shape, want):
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        if mesh_shape is None:
+            assert ops.use_kernels() is want
+            return
+        mesh = jax.sharding.AbstractMesh(mesh_shape, ("data", "model"))
+        with jax.sharding.use_abstract_mesh(mesh):
+            assert ops.use_kernels() is want
+
+
 class TestFlashAttention:
     @pytest.mark.parametrize("lq,lk,h,hkv,d", [
         (128, 128, 4, 4, 64),      # MHA square

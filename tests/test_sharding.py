@@ -18,18 +18,9 @@ from repro.models import model as M
 from repro.sharding.rules import ShardingRules
 
 
-def _abstract_mesh(shape, names):
-    """AbstractMesh across jax versions: 0.4.x takes ((name, size), ...)
-    pairs; 0.5+ takes (shape, names). No devices needed either way."""
-    try:
-        return jax.sharding.AbstractMesh(shape, names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(names, shape)))
-
-
 @pytest.fixture(scope="module")
 def mesh():
-    return _abstract_mesh((4, 2), ("data", "model"))
+    return jax.sharding.AbstractMesh((4, 2), ("data", "model"))
 
 
 def test_param_rules(mesh):
@@ -103,7 +94,7 @@ mesh = jax.make_mesh((4, 2), ("data", "model"))
 rules = ShardingRules(mesh)
 p_sh = jax.device_put(params, rules.params_shardings(params))
 t_sh = jax.device_put(toks, rules.data_shardings(toks))
-with mesh:
+with jax.set_mesh(rules.mesh):
     out, _ = jax.jit(lambda p, t: M.forward(p, cfg, t))(p_sh, t_sh)
 err = float(jnp.max(jnp.abs(ref - out)))
 assert err < 2e-2, (arch, err)
@@ -113,10 +104,7 @@ print(arch, "ok", err)
 
 @pytest.mark.parametrize("arch", [
     "qwen1.5-0.5b",
-    pytest.param("granite-moe-1b-a400m", marks=pytest.mark.xfail(
-        reason="pre-existing: sharded MoE forward diverges (~0.9 max err) "
-               "under expert sharding on the 8-fake-device CPU mesh; "
-               "tracked in ROADMAP")),
+    "granite-moe-1b-a400m",
     "rwkv6-1.6b",
 ])
 def test_sharded_forward_matches_single_device(arch):
@@ -129,3 +117,30 @@ def test_sharded_forward_matches_single_device(arch):
     r = subprocess.run([sys.executable, "-c", _SUBPROC_SCRIPT, arch], env=env,
                        capture_output=True, text=True, timeout=540)
     assert r.returncode == 0, r.stdout + r.stderr
+
+
+_ROUND_SCRIPT = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+sys.path.insert(0, sys.argv[1])
+import chip_smoke
+from repro.configs import get_config, smoke_variant
+chip_smoke.peak_bytes = lambda device: 0      # the CPU reports no stats
+chip_smoke.sharded_phase(jax.devices(),
+                         smoke_variant(get_config("qwen1.5-0.5b")), seq=32)
+"""
+
+
+def test_sharded_round_matches_one_device():
+    """chip_smoke.py --chips 4's comparison on 4 fake CPU devices: a
+    ShardedFederation round on a (data=4, model=1) mesh matches the same
+    round on one device within its bf16 tolerance."""
+    root = os.path.join(os.path.dirname(__file__), "..")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(root, "src"), env.get("PYTHONPATH", "")])
+    r = subprocess.run([sys.executable, "-c", _ROUND_SCRIPT, root], env=env,
+                       capture_output=True, text=True, timeout=540)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "sharded vs one-device global param max|Δ|" in r.stdout

@@ -56,26 +56,73 @@ def test_fedgalore_learns_noniid():
     assert acc > 0.2, acc
 
 
-def test_train_launcher_cli(tmp_path):
+@pytest.fixture
+def no_cache_dir(monkeypatch, tmp_path):
+    """A launcher's main() points JAX's persistent compilation cache at the
+    checkout unless JAX_COMPILATION_CACHE_DIR is set; setting it keeps the
+    test process's config untouched."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_train_launcher_cli(tmp_path, no_cache_dir):
     out = tmp_path / "hist.json"
     from repro.launch import train as train_mod
-    hist = train_mod.main([
+    res = train_mod.main([
         "--arch", "qwen1.5-0.5b", "--smoke", "--method", "fedgalore",
         "--rounds", "2", "--clients", "3", "--local-steps", "2",
         "--batch", "4", "--seq", "16", "--examples", "256",
         "--alpha", "0.5", "--out", str(out)])
+    hist = res["history"]
     assert len(hist) == 2
     assert all(np.isfinite(h["val_loss"]) for h in hist)
-    assert json.loads(out.read_text())
+    assert json.loads(out.read_text()) == hist
+    lowered = res["engine"].lower_round(res["last_batches"])
+    assert "func.func public @main" in lowered.as_text()
 
 
-def test_serve_launcher_cli(capsys):
+def test_chunked_evaluation_matches_whole_batch():
+    """launch.train.evaluate runs the batch in EVAL_ROWS-row chunks (the
+    full-vocab logits of a whole batch do not fit a chip); its loss and
+    accuracy equal the whole-batch computation."""
+    from repro.launch import train as train_mod
+    cfg = smoke_variant(get_config("qwen1.5-0.5b"))
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    task = seq_classification(64, 4, 16, cfg.vocab_size, seed=0)
+    n = 3 * train_mod.EVAL_ROWS + 5                   # a ragged last chunk
+    batch = {"tokens": jnp.asarray(task.tokens[:n]),
+             "labels": jnp.asarray(task.labels[:n])}
+    loss, acc = train_mod.evaluate(params, cfg, batch)
+    logits, _ = M.forward(params, cfg, batch["tokens"])
+    want_acc = float(jnp.mean(jnp.argmax(logits[:, -1], -1) ==
+                              batch["labels"][:, -1]))
+    assert loss == pytest.approx(float(M.loss_fn(params, cfg, batch)),
+                                 rel=1e-5)
+    assert acc == want_acc
+
+
+def test_chip_smoke_refuses_a_host_without_tpu():
+    """chip_smoke.py fails (SystemExit) when JAX's device is not a TPU."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(__file__), "..", "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    with pytest.raises(SystemExit, match="no TPU"):
+        smoke.device_check(1)
+
+
+def test_serve_launcher_cli(capsys, no_cache_dir):
     from repro.launch import serve as serve_mod
-    serve_mod.main(["--arch", "rwkv6-1.6b", "--smoke", "--batch", "2",
-                    "--prompt-len", "8", "--new-tokens", "4"])
+    res = serve_mod.main(["--arch", "rwkv6-1.6b", "--smoke", "--batch", "2",
+                          "--prompt-len", "8", "--new-tokens", "4"])
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert out["tokens_per_sec"] > 0
     assert len(out["sample_row"]) == 4
+    assert sorted(res["outputs"]) == [0, 1]
+    assert res["outputs"][0] == out["sample_row"]
 
 
 def test_generate_deterministic_greedy():

@@ -1,0 +1,115 @@
+"""Ahead-of-time compiles of the main-path Pallas kernels for a TPU v5e.
+
+The TPU compiler ships with the installed jaxlib and compiles for a chip that
+is described, not attached, so these tests catch what interpret mode cannot:
+ops the kernel compiler cannot lower, block shapes it refuses, and more VMEM
+than a kernel may use. Shapes are the qwen1.5-0.5b widths the round and the
+server run at (d=1024, d_ff=2816, rank 8, bf16 activations). Nothing runs;
+a compile that passes says nothing about results or times.
+
+The topology is described inside a module fixture, never at import, so every
+test worker collects the same tests and only the one that runs this file
+loads the TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.batched_eigh import jacobi_eigh
+from repro.kernels.galore_adamw import galore_precond_step
+from repro.kernels.lowrank_linear import lowrank_linear, lowrank_linear_batched
+
+D, FF, R = 1024, 2816, 8
+TOKENS = 2 * 256          # one client's local batch: 2 sequences x 256
+G = 16                    # adapters resident in the serving table
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    """A described-device compile is written to the persistent cache but
+    cannot be read back without the chip; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+f32, bf16 = jnp.float32, jnp.bfloat16
+
+# (name, shape of g) — right: m >= n (w_down), left: m < n (w_gate / w_up).
+# The leading 24 is the stacked layer dim the bucketed step vmaps over.
+_GALORE = {
+    "right": ((24, FF, D), (24, D, R), (24, FF, R), True),
+    "left": ((24, D, FF), (24, D, R), (24, R, FF), True),
+    "projected": ((24, FF, D), (24, D, R), (24, FF, R), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_GALORE))
+def test_galore_precond_step_compiles(one_chip, case):
+    g, basis, mv, project_back = _GALORE[case]
+
+    def step(g, basis, m, v, count):
+        return galore_precond_step(
+            g, basis, m, v, count, project_back=project_back)
+
+    _compile(step, one_chip, (g, f32), (basis, f32), (mv, f32), (mv, f32),
+             ((), f32))
+
+
+_LOWRANK = {
+    "right": ((TOKENS, FF), (FF, D), (D, R), (FF, R)),
+    "left": ((TOKENS, D), (D, FF), (D, R), (R, FF)),
+}
+
+
+@pytest.mark.parametrize("side", sorted(_LOWRANK))
+def test_lowrank_linear_compiles(one_chip, side):
+    x, w, basis, rt = _LOWRANK[side]
+    _compile(lowrank_linear, one_chip, (x, bf16), (w, bf16),
+             (basis, f32), (rt, f32), ((), f32))
+
+
+@pytest.mark.parametrize("x_shape", [(G, D), (G, 128, D)],
+                         ids=["decode", "prefill"])
+def test_lowrank_linear_batched_compiles(one_chip, x_shape):
+    def apply(x, w, bases, rts, scales, ids):
+        return lowrank_linear_batched(x, w, bases, rts, scales, ids)
+
+    _compile(apply, one_chip, (x_shape, bf16), ((D, FF), bf16),
+             ((G, D, R), f32), ((G, R, FF), f32), ((G,), f32),
+             ((G,), jnp.int32))
+
+
+@pytest.mark.parametrize("n", [8, 32])
+def test_jacobi_eigh_compiles(one_chip, n):
+    _compile(jacobi_eigh, one_chip, ((64, n, n), f32))
