@@ -1,0 +1,8 @@
+"""head_ms.round: device self time of the ``model.head`` scope (final norm,
+the vocabulary-wide logits, log-softmax and loss, forward and backward) per
+traced round, in ms (bench/trace_scopes.py)."""
+import trace_scopes
+
+
+def read(summary, ctx):
+    return trace_scopes.ms_per_round(ctx, "model.head")

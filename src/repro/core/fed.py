@@ -78,6 +78,12 @@ from .population import ParticipationConfig
 from .. import optim as optim_lib
 from ..optim.base import apply_updates
 
+# Host spans of a round in the profiler's trace (``fed.round`` and its
+# ``prepare`` / ``dispatch`` / ``readback`` children); the round program's
+# own stages are ``jax.named_scope``s, which reach every instruction's
+# ``op_name`` in the compiled program.
+_span = jax.profiler.TraceAnnotation
+
 PyTree = Any
 
 
@@ -379,6 +385,7 @@ class FedEngine:
             step, (trainable, opt_state), batches)
         return trainable, opt_state, losses
 
+    @jax.named_scope("fed.init_state")
     def _init_state0(self, round_idx, synced_v, global_trainable):
         """One client's round-start InitState (Eq. 5): fresh moments, install
         the synced ṽ, refresh the projector for the new round (seeded
@@ -402,6 +409,7 @@ class FedEngine:
         st = jax.eval_shape(lambda: self.tx.init(self.global_trainable))
         return gal.client_opt_axes(st)
 
+    @jax.named_scope("fed.init_state")
     def _stack_opt_state(self, st, n_clients: int):
         """Broadcast one InitState along the client axis, honoring the
         unbatched-count/seed layout of :meth:`_client_opt_axes`."""
@@ -487,48 +495,52 @@ class FedEngine:
         exclusion-aware 𝒮. An honest cohort through the guarded program is
         bit-identical to the unguarded one.
         """
-        k_clients = jax.tree_util.tree_leaves(client_batches)[0].shape[0]
-        mask = self._canon_mask(mask, k_clients)
-        attack = self._canon_attack(attack, k_clients)
-        guarded = self._guard_cfg or attack is not None
-        if not (self.cfg.fused_round and self.cfg.factored_sync):
-            if guarded:
-                raise ValueError(
-                    "quarantine/robust_agg/attack injection require the "
-                    "fused factored round (fused_round + factored_sync)")
-            w = (self._normalize_weights(weights, k_clients) if mask is None
-                 else self._masked_weights(weights, mask, k_clients))
-            return self._run_round_eager(client_batches, w, k_clients)
-
-        extra = ()
-        if guarded:
-            w = (self._normalize_weights(weights, k_clients) if mask is None
-                 else self._masked_weights(weights, mask, k_clients))
-            round_fn = self._round_guard_jitted()
-            a = (np.ones((k_clients,), np.float32) if attack is None
-                 else attack)
-            extra = (jnp.asarray(a, jnp.float32),)
-        elif mask is None:
-            w = self._normalize_weights(weights, k_clients)
-            round_fn = self._round_jitted()
-        else:
-            w = self._masked_weights(weights, mask, k_clients)
-            round_fn = self._round_masked_jitted()
-        self._ensure_client_buffers(k_clients)
-        out = round_fn(
-            self._client_state, self._client_opt, self.global_trainable,
-            self.frozen, self.synced_v,
-            jnp.asarray(self.round_idx, jnp.int32), client_batches, w,
-            *extra)
-        if self._frozen_mutates():
-            (self._client_state, self._client_opt, self.global_trainable,
-             self.frozen, self.synced_v, losses) = out
-        else:
-            (self._client_state, self._client_opt, self.global_trainable,
-             self.synced_v, losses) = out
-        self.round_idx += 1
+        with _span("fed.round", round=self.round_idx):
+            with _span("fed.round.prepare"):
+                k_clients = jax.tree_util.tree_leaves(
+                    client_batches)[0].shape[0]
+                mask = self._canon_mask(mask, k_clients)
+                attack = self._canon_attack(attack, k_clients)
+                guarded = self._guard_cfg or attack is not None
+                fused = self.cfg.fused_round and self.cfg.factored_sync
+                if guarded and not fused:
+                    raise ValueError(
+                        "quarantine/robust_agg/attack injection require the "
+                        "fused factored round (fused_round + factored_sync)")
+                w = (self._normalize_weights(weights, k_clients)
+                     if mask is None
+                     else self._masked_weights(weights, mask, k_clients))
+                if fused:
+                    extra = ()
+                    if guarded:
+                        round_fn = self._round_guard_jitted()
+                        a = (np.ones((k_clients,), np.float32)
+                             if attack is None else attack)
+                        extra = (jnp.asarray(a, jnp.float32),)
+                    elif mask is None:
+                        round_fn = self._round_jitted()
+                    else:
+                        round_fn = self._round_masked_jitted()
+                    self._ensure_client_buffers(k_clients)
+                    round_idx = jnp.asarray(self.round_idx, jnp.int32)
+            if not fused:
+                return self._run_round_eager(client_batches, w, k_clients)
+            with _span("fed.round.dispatch"):
+                out = round_fn(
+                    self._client_state, self._client_opt,
+                    self.global_trainable, self.frozen, self.synced_v,
+                    round_idx, client_batches, w, *extra)
+            if self._frozen_mutates():
+                (self._client_state, self._client_opt, self.global_trainable,
+                 self.frozen, self.synced_v, losses) = out
+            else:
+                (self._client_state, self._client_opt, self.global_trainable,
+                 self.synced_v, losses) = out
+            self.round_idx += 1
+            with _span("fed.round.readback"):
+                mean_final = float(jnp.mean(losses[:, -1]))
         return {"local_loss": losses,                      # (K, T)
-                "mean_final_loss": float(jnp.mean(losses[:, -1]))}
+                "mean_final_loss": mean_final}
 
     def lower_round(self, client_batches: PyTree, weights=None):
         """The default round program (unmasked, unguarded, fused) lowered
@@ -588,38 +600,47 @@ class FedEngine:
         # PopulationRunner, which drives sequential rounds anyway) — the
         # guarded scan exists so a quarantine/robust_agg config still gets
         # the one-dispatch sweep, guarding every round with a unit attack.
-        if masks is None and not self._guard_cfg:
-            w = self._normalize_weights(weights, k_clients)
-            scan_fn = self._rounds_scan_jitted()
-        else:
-            # Per-round effective weights as scan xs; exclusion-aware 𝒮.
-            if masks is None:
-                w_one = self._normalize_weights(weights, k_clients)
-                w = jnp.tile(w_one[None], (int(k_rounds), 1))
+        with _span("fed.round", round=self.round_idx, rounds=int(k_rounds)):
+            with _span("fed.round.prepare"):
+                if masks is None and not self._guard_cfg:
+                    w = self._normalize_weights(weights, k_clients)
+                    scan_fn = self._rounds_scan_jitted()
+                else:
+                    # Per-round effective weights as scan xs; exclusion-aware
+                    # 𝒮.
+                    if masks is None:
+                        w_one = self._normalize_weights(weights, k_clients)
+                        w = jnp.tile(w_one[None], (int(k_rounds), 1))
+                    else:
+                        w = jnp.stack([
+                            self._masked_weights(weights, m, k_clients)
+                            for m in masks])
+                    scan_fn = (self._rounds_scan_guard_jitted()
+                               if self._guard_cfg
+                               else self._rounds_scan_masked_jitted())
+                synced_v = self.synced_v
+                if synced_v is None and self._method_syncs():
+                    # Uniform scan carry: a zero synced ṽ is bit-identical to
+                    # "no synced state" (fresh moments are zero and the
+                    # install clamps at zero), so round 0 inside the scan
+                    # matches run_round.
+                    synced_v = self._zero_synced_template()
+                round_idx = jnp.asarray(self.round_idx, jnp.int32)
+            with _span("fed.round.dispatch"):
+                carry, losses = scan_fn(
+                    self.global_trainable, self.frozen, synced_v, round_idx,
+                    round_batches, w)
+            if self._frozen_mutates():
+                self.global_trainable, self.frozen, new_synced, _ = carry
             else:
-                w = jnp.stack([self._masked_weights(weights, m, k_clients)
-                               for m in masks])
-            scan_fn = (self._rounds_scan_guard_jitted() if self._guard_cfg
-                       else self._rounds_scan_masked_jitted())
-
-        synced_v = self.synced_v
-        if synced_v is None and self._method_syncs():
-            # Uniform scan carry: a zero synced ṽ is bit-identical to "no
-            # synced state" (fresh moments are zero and the install clamps
-            # at zero), so round 0 inside the scan matches run_round.
-            synced_v = self._zero_synced_template()
-        carry, losses = scan_fn(
-            self.global_trainable, self.frozen, synced_v,
-            jnp.asarray(self.round_idx, jnp.int32), round_batches, w)
-        if self._frozen_mutates():
-            self.global_trainable, self.frozen, new_synced, _ = carry
-        else:
-            self.global_trainable, new_synced, _ = carry
-        if self._method_syncs():
-            self.synced_v = new_synced
-        self.round_idx += int(k_rounds)
+                self.global_trainable, new_synced, _ = carry
+            if self._method_syncs():
+                self.synced_v = new_synced
+            self.round_idx += int(k_rounds)
+            with _span("fed.round.readback"):
+                mean_final = float(jnp.mean(losses[-1, :, -1]))
         return {"local_loss": losses,                      # (K, C, T)
-                "mean_final_loss": float(jnp.mean(losses[-1, :, -1]))}
+                "mean_final_loss": mean_final}
 
     def _build_rounds_scan(self, exclude_zero: bool, guard: bool = False,
                            pipelined: bool = False):
@@ -967,6 +988,7 @@ class FedEngine:
         return (self.galore_cfg.adaptive_steps > 0
                 and self.galore_cfg.refresh_mode != "random")
 
+    @jax.named_scope("fed.aggregate")
     def _aggregate_factored(self, global_trainable, out_deltas, out_opt,
                             base_scales, w, round_idx, robust: str = "none"):
         """𝒜 for factored clients: ``(Σᵢ wᵢ sᵢ)·W + Σᵢ wᵢ lift(Rᵢ, Bᵢ)`` per
@@ -1010,6 +1032,7 @@ class FedEngine:
         return jax.tree_util.tree_map(one, global_trainable, out_deltas,
                                       bases)
 
+    @jax.named_scope("fed.guard")
     def _apply_guard(self, out_d, out_opt, scales, w, attack):
         """The in-round defense gate, between the local phase and 𝒜/𝒮.
 
@@ -1096,6 +1119,7 @@ class FedEngine:
         st0 = self._init_state0(round_idx, synced_v, global_trainable)
         opt0 = self._stack_opt_state(st0, b)
 
+        @jax.named_scope("fed.local")
         def stream(local_fn, batches):
             """Run the B-client vmapped local phase over the cohort: directly
             for a single chunk, as a lax.scan over C/B chunks otherwise, and
@@ -1161,8 +1185,10 @@ class FedEngine:
                                                     robust=robust)
             return out_d, out_opt, new_global, frozen, new_synced, losses
 
-        stacked = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x, (b,) + x.shape), global_trainable)
+        with jax.named_scope("fed.init_state"):
+            stacked = jax.tree_util.tree_map(
+                lambda x: jnp.broadcast_to(x, (b,) + x.shape),
+                global_trainable)
 
         def local_fn(batch_c):
             return jax.vmap(
@@ -1181,6 +1207,7 @@ class FedEngine:
                                                 exclude_zero)
         return out_tr, out_opt, new_global, new_frozen, new_synced, losses
 
+    @jax.named_scope("fed.init_state")
     def _stack_deltas0(self, st0, n: int):
         """Zero round-start factored accumulators for n clients."""
         d0 = gal.zero_client_deltas(gal.galore_state_of(st0))
@@ -1268,6 +1295,7 @@ class FedEngine:
                 "mean_final_loss": float(jnp.mean(losses[:, -1]))}
 
     # -------------------------------------------------------------- 𝒜 -------
+    @jax.named_scope("fed.aggregate")
     def _aggregate_pure(self, stacked, w, frozen, round_idx):
         """Aggregation 𝒜 as a pure function of the client-stacked trainables:
         returns (new_global_trainable, new_frozen)."""
@@ -1368,6 +1396,7 @@ class FedEngine:
         defers the slim uplink."""
         return self.spec.state_sync in ("avg", "avg_svd")
 
+    @jax.named_scope("fed.sync")
     def _slim_payload(self, stacked_opt_states, w, round_idx,
                       exclude_zero: bool, robust: str = "none"):
         """The ``skip_sync`` pending payload for one round: the fully
@@ -1394,6 +1423,7 @@ class FedEngine:
                 b, is_leaf=lambda x: x is None)
         return self._basis_template_tree
 
+    @jax.named_scope("fed.sync")
     def _sync_pending(self, v_tree, w, exclude_zero: bool = False,
                       robust: str = "none"):
         """Drain one slim pending payload (see :meth:`_slim_payload`):
@@ -1425,6 +1455,7 @@ class FedEngine:
         synced = sync_lib.map_sync_leaves(leaf_fn, vs, bs, bucketed=bucketed)
         return jax.tree_util.tree_unflatten(treedef, synced)
 
+    @jax.named_scope("fed.sync")
     def _sync_states_pure(self, stacked_opt_states, w, round_idx,
                           exclude_zero: bool = False, robust: str = "none"):
         """Factored 𝒮 for the fused round: shared-basis rounds synchronize on
@@ -1440,6 +1471,7 @@ class FedEngine:
         return self._sync_states_from_uplink(v_tree, b_tree, w, round_idx,
                                              exclude_zero, robust=robust)
 
+    @jax.named_scope("fed.sync")
     def _sync_states_from_uplink(self, v_stack_tree, basis_tree, w, round_idx,
                                  exclude_zero: bool = False,
                                  shared_only: bool = False,

@@ -334,6 +334,7 @@ def _bucketed_update(cfg: GaloreConfig, use_pallas: bool, g_leaves,
     return updates, new_blocks
 
 
+@jax.named_scope("galore.update")
 def galore_transform_update(cfg: GaloreConfig, grads, state: GaloreState,
                             project_back: bool = True,
                             projected: bool = False):
@@ -560,6 +561,7 @@ def manual_refresh(cfg: GaloreConfig, state: GaloreState, refresh_idx,
                        blocks=jax.tree_util.tree_unflatten(treedef, out))
 
 
+@jax.named_scope("galore.refresh")
 def maybe_refresh_instep(cfg: GaloreConfig, state: GaloreState) -> GaloreState:
     """Hoisted in-step refresh for the lift-free local step.
 
@@ -690,6 +692,7 @@ def liftfree_value_and_grad(loss_of_params, base: PyTree, deltas: PyTree,
     return loss, LiftFreeGrads(proj=gt, nsq=nsq)
 
 
+@jax.named_scope("galore.update")
 def factored_adamw_step(cfg: GaloreConfig, grads, opt_state, deltas,
                         base_scale, *, lr, weight_decay: float = 0.0,
                         clip_norm: Optional[float] = None):

@@ -48,6 +48,7 @@ def causal_mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray,
     return mask
 
 
+@jax.named_scope("model.attention")
 def attend(q, k, v, mask) -> jnp.ndarray:
     """q (B,Lq,H,hd), k/v (B,Lk,Hkv,hd) with H % Hkv == 0; mask (B|1,Lq,Lk).
 
@@ -71,6 +72,7 @@ def attend(q, k, v, mask) -> jnp.ndarray:
     return ctx.reshape(b, lq, h, hd).astype(q.dtype)
 
 
+@jax.named_scope("model.attention")
 def blockwise_attend(q, k, v, *, window=0, chunk_q=2048, chunk_k=2048,
                      q_start=0) -> jnp.ndarray:
     """Flash-style blockwise causal attention in pure XLA (§Perf iteration B).

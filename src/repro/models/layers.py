@@ -246,7 +246,8 @@ def _lowrank_bwd(side, use_pallas, res, dy):
     # Exact ‖xᵀ∂y‖²_F via token Grams: O(t²(m+n)) flops with t = tokens, no
     # m×n object, transients bounded by the token tile. DCE'd entirely when
     # the caller never reads the probe cotangent (clip_norm=None).
-    dnsq = _sqnorm_gram(x2, dy2)
+    with jax.named_scope("lowrank.norm_probe"):
+        dnsq = _sqnorm_gram(x2, dy2)
     # w / basis / scale are never differentiated by the lift-free step; the
     # zero cotangents exist only to satisfy the VJP signature and are dead
     # code after DCE (asserted GEMM-free by the shape-probe test).
@@ -349,8 +350,11 @@ def dense(x, w):
     ambient :func:`adapter_ids` context). Model projections route through
     this so ``loss_fn(params, batch)`` signatures never change."""
     if isinstance(w, LowRankDelta):
-        return lowrank_apply(w.side, _use_lowrank_pallas(), x, w.w, w.basis,
-                             w.rt, w.nsq, w.scale)
+        # The scope also names the custom-VJP backward's ops (cotangents
+        # and the norm probe), which are traced under the forward's name.
+        with jax.named_scope("lowrank.apply"):
+            return lowrank_apply(w.side, _use_lowrank_pallas(), x, w.w,
+                                 w.basis, w.rt, w.nsq, w.scale)
     if isinstance(w, MultiAdapterDelta):
         ids = _ADAPTER_IDS[-1]
         if ids is None:

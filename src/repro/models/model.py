@@ -171,6 +171,7 @@ def _embed(params, cfg: ArchConfig, tokens, embeds):
     return h
 
 
+@jax.named_scope("model.head")
 def _logits(params, cfg: ArchConfig, h):
     h = apply_norm(h, params["final_norm"], cfg.norm)
     w = (params["embed"]["w"].T if cfg.tie_embeddings
@@ -211,11 +212,12 @@ def loss_fn(params: PyTree, cfg: ArchConfig, batch: Dict,
     n_front = logits.shape[1] - labels.shape[1]
     if n_front:
         logits = logits[:, n_front:]
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    mask = labels >= 0
-    safe = jnp.where(mask, labels, 0)
-    nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
-    ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
+    with jax.named_scope("model.head"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        mask = labels >= 0
+        safe = jnp.where(mask, labels, 0)
+        nll = -jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
+        ce = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
     return ce + aux_coef * aux
 
 
