@@ -15,6 +15,33 @@ GEMM, with the dense ``m×n`` lifted weight never existing. One VMEM-resident
 pass per row tile of ``x``: the base GEMM, both split GEMMs, and the scaled
 add all happen before the tile's output leaves VMEM.
 
+Bf16 activations (the round's case) take the split-word path. The factors
+are fp32 and ``x`` is bf16; an fp32 dot would run as a multi-pass bf16
+emulation (``Precision.HIGHEST``) on r-wide operands padded to the MXU's
+128, which costs more than the base GEMM it corrects. Instead every fp32
+factor is split into three bf16 words, ``a = hi + mid + lo`` exactly
+(3 × 8 significand bits = fp32's 24), and the words are packed into one
+bf16 dot with fp32 accumulation:
+
+  ``u = x @ F₁``: ``x`` is exact in one bf16 word, so ``x @ [F₁ʰⁱ|F₁ᵐⁱᵈ|F₁ˡᵒ]``
+      is one (bt, m) × (m, 3r) pass whose three r-wide blocks, summed in
+      fp32, are ``x @ F₁`` to fp32 rounding.
+  ``u @ F₂``: both sides fp32; ``u`` is split per tile, and the nine
+      (word of u, word of F₂) cross products are lined up along K —
+      ``[uʰⁱ uʰⁱ uʰⁱ uᵐⁱᵈ … uˡᵒ] @ [F₂ʰⁱ; F₂ᵐⁱᵈ; F₂ˡᵒ; F₂ʰⁱ; …]`` — one pass
+      of K = 9r ≤ 128 for r ≤ 14 (above, groups of ⌊128/r⌋ pairs, one
+      pass each); at least as exact as ``HIGHEST``'s six products.
+
+(F₁, F₂) is (R̃, Bᵀ) on the right side and (B, R̃) on the left. The factor
+blocks have a constant ``index_map``, so they stay in VMEM across a
+client's row tiles: they are split (and Bᵀ transposed) once, into VMEM
+scratch, at each client's first row tile (the row-tile axis runs in order,
+``arbitrary``; a vmapped client axis is a grid axis of its own). Each
+product of bf16 words is exact in fp32, so the result differs from the
+fp32 reference by fp32 accumulation rounding only. Fp32 activations keep
+the fp32 ``HIGHEST`` dots (``_apply``): splitting the factors alone would
+not make an fp32 ``x`` exact. The dtype of ``x`` is the only switch.
+
 Grid handling mirrors ``galore_adamw.py``: the tile count is
 ``ceil(t / block)`` (``pl.cdiv``) with the trailing partial tile masked by
 Pallas block clipping — no divisibility requirement on the token dim.
@@ -24,7 +51,9 @@ projected-cotangent VJP — grad wrt R̃ arrives already in rank-r coordinates)
 lives in ``models.layers.lowrank_apply``, which consumes this kernel via
 ``ops.lowrank_linear`` on TPU.
 
-``lowrank_linear_batched`` is the *serving* variant of the same apply: one
+``lowrank_linear_batched`` is the *serving* variant of the same apply (it
+keeps the fp32 dots of ``_apply``: each grid program gathers another
+adapter, so a once-per-client split does not carry over): one
 decode batch where every row carries its own adapter — the S-LoRA/Punica
 shape. The base GEMM is shared across the batch; each grid program gathers
 its row's ``(basis_g, R̃_g)`` blocks by the scalar-prefetched ``(B,)``
@@ -85,6 +114,61 @@ def _kernel(scale_ref, x_ref, w_ref, basis_ref, rt_ref, y_out, *, side):
     y_out[...] = y.astype(y_out.dtype)
 
 
+def _split3(a):
+    """Three bf16 words whose fp32 sum is ``a`` (fp32) exactly."""
+    hi = a.astype(jnp.bfloat16)
+    rest = a - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def _pair_chunks(r):
+    """The nine (word of u, word of F₂) cross products of two 3-word splits,
+    in groups whose packed depth (pairs × r) is at most the MXU's 128 (one
+    pair per group once r > 64); one group for r ≤ 14."""
+    pairs = [(i, j) for i in range(3) for j in range(3)]
+    per = max(1, 128 // r)
+    return [pairs[i:i + per] for i in range(0, len(pairs), per)]
+
+
+def _packed_kernel(scale_ref, x_ref, w_ref, basis_ref, rt_ref, y_out, f1_ref,
+                   *f2_refs, side):
+    """Bf16-activation body: ``scale·(x @ w) + (x @ F₁) @ F₂`` with both
+    rank-r products as single bf16 MXU passes over split words (module
+    docstring). ``f1_ref`` (3r, m) holds F₁ᵀ's words stacked; each
+    ``f2_refs`` entry holds one pair group's F₂ words stacked along K. The
+    rank-r intermediate is kept transposed, (r, bt): its three word blocks
+    and its split are then whole sublane groups, not lane shuffles."""
+    r = f1_ref.shape[0] // 3
+    chunks = _pair_chunks(r)
+    nt, tn = (((1,), (1,)), ((), ())), (((0,), (0,)), ((), ()))
+
+    @pl.when(pl.program_id(0) == 0)
+    def _split_factors():
+        basis = basis_ref[...].astype(jnp.float32)
+        rt = rt_ref[...].astype(jnp.float32)
+        f1, f2 = (rt, basis.T) if side == RIGHT else (basis, rt)
+        f1_ref[...] = jnp.concatenate(_split3(f1.T), axis=0)
+        # stacked as fp32 (exact) and cast once: r-row bf16 blocks would
+        # not fall on bf16's 16-row sublane tiles
+        words = [a.astype(jnp.float32) for a in _split3(f2)]
+        for ref, chunk in zip(f2_refs, chunks):
+            ref[...] = jnp.concatenate([words[j] for _, j in chunk],
+                                       axis=0).astype(jnp.bfloat16)
+
+    x = x_ref[...]
+    base = mxu.dot(x, w_ref[...])
+    u3 = mxu.dot(f1_ref[...], x, nt)                         # (3r, bt)
+    u = u3[:r] + u3[r:2 * r] + u3[2 * r:]
+    words = [a.astype(jnp.float32) for a in _split3(u)]
+    delta = functools.reduce(jnp.add, [
+        mxu.dot(jnp.concatenate([words[i] for i, _ in chunk],
+                                axis=0).astype(jnp.bfloat16), ref[...], tn)
+        for ref, chunk in zip(f2_refs, chunks)])
+    y_out[...] = (scale_ref[0, 0] * base + delta).astype(y_out.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("side", "block_rows",
                                              "interpret"))
 def lowrank_linear(x, w, basis, rt, scale, *, side=None, block_rows=128,
@@ -94,7 +178,8 @@ def lowrank_linear(x, w, basis, rt, scale, *, side=None, block_rows=128,
     x (..., t, m); w (m, n); right side: basis (n, r), rt (m, r); left side:
     basis (m, r), rt (r, n). ``scale`` is the scalar base multiplier
     (``base_scale = (1-ηλ)^t``). Returns y (..., t, n) in the base-GEMM
-    result dtype; fp32 accumulation throughout.
+    result dtype; fp32 accumulation throughout. Bf16 ``x`` takes the
+    split-word body, any other dtype the fp32 dots (module docstring).
     """
     side = side or infer_side(w.shape, basis.shape, rt.shape)
     lead = x.shape[:-1]
@@ -106,8 +191,15 @@ def lowrank_linear(x, w, basis, rt, scale, *, side=None, block_rows=128,
     r = basis.shape[-1]
     bshape = (nn, r) if side == RIGHT else (mm, r)
     rshape = (mm, r) if side == RIGHT else (r, nn)
+    if x.dtype == jnp.bfloat16:
+        kernel = _packed_kernel
+        scratch = [pltpu.VMEM((3 * r, mm), jnp.bfloat16)] + [
+            pltpu.VMEM((len(chunk) * r, nn), jnp.bfloat16)
+            for chunk in _pair_chunks(r)]
+    else:
+        kernel, scratch = _kernel, []
     y = pl.pallas_call(
-        functools.partial(_kernel, side=side),
+        functools.partial(kernel, side=side),
         grid=(pl.cdiv(t, bt),),
         in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)),   # scale (SMEM-like)
                   pl.BlockSpec((bt, mm), lambda i: (i, 0)),
@@ -116,6 +208,10 @@ def lowrank_linear(x, w, basis, rt, scale, *, side=None, block_rows=128,
                   pl.BlockSpec(rshape, lambda i: (0, 0))],
         out_specs=pl.BlockSpec((bt, nn), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, nn), out_dtype),
+        scratch_shapes=scratch,
+        # the split factors are written at row tile 0: tiles run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(jnp.full((1, 1), scale, jnp.float32), x2, w, basis, rt)
     return y.reshape(lead + (nn,))

@@ -55,6 +55,88 @@ def test_lowrank_linear_kernel_leading_dims_and_side_inference():
     assert jnp.allclose(got, want, atol=1e-5)
 
 
+def _rel_err(got, want):
+    return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("side,r,tokens,clients", [
+    ("right", 3, 32, None), ("left", 3, 32, None),
+    ("right", 8, 32, None), ("left", 8, 32, None),
+    ("right", 16, 32, None), ("left", 16, 32, None),   # 9r > 128: 2 passes
+    ("right", 8, 37, None), ("left", 8, 37, None),     # masked tail tile
+    ("right", 8, 37, 3), ("left", 16, 37, 3),           # vmapped clients
+])
+def test_lowrank_linear_bf16_split_words_exact(side, r, tokens, clients):
+    """Bf16 activations take the split-word path: it matches the fp32
+    reference on fp32-upcast operands, and its delta is at least 100× closer
+    to it than the same apply with the factors rounded to bf16 — the words
+    carry the whole fp32 factor, not a bf16 truncation. The base weight is
+    fp32 only so that the kernel returns fp32 (the result dtype is the base
+    GEMM's); ``scale = 0`` leaves the delta alone in the output."""
+    m, n = (48, 24) if side == "right" else (24, 48)
+    c = clients or 1
+    ks = jax.random.split(jax.random.fold_in(KEY, r), 4)
+    x = jax.random.normal(ks[0], (c, tokens, m)).astype(jnp.bfloat16)
+    w = 0.05 * jax.random.normal(ks[1], (m, n))
+    bdim = n if side == "right" else m
+    basis = jnp.linalg.qr(jax.random.normal(ks[2], (c, bdim, r)))[0]
+    # a round's delta: |R̃| ~ 1e-3 of |w|
+    rt = 5e-5 * jax.random.normal(ks[3], (c,) + ((m, r) if side == "right"
+                                                  else (r, n)))
+
+    def each(fn):
+        if clients is None:
+            return lambda b, rt, s: fn(x[0], b[0], rt[0], s)
+        return lambda b, rt, s: jax.vmap(fn, in_axes=(0, 0, 0, None))(
+            x, b, rt, s)
+
+    kernel = each(lambda x, b, rt, s: kops.lowrank_linear(
+        x, w, b, rt, s, side=side, block_rows=16))
+    ref = each(lambda x, b, rt, s: lowrank_linear_ref(
+        x.astype(jnp.float32), w, b, rt, s, side=side))
+    assert _rel_err(kernel(basis, rt, 0.97), ref(basis, rt, 0.97)) <= 1e-5
+
+    delta = ref(basis, rt, 0.0)
+    err = _rel_err(kernel(basis, rt, 0.0), delta)
+    q = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)
+    err_bf16 = _rel_err(ref(q(basis), q(rt), 0.0), delta)
+    assert err <= 1e-5 and err <= err_bf16 / 100, (err, err_bf16)
+
+
+def _dot_generals(jaxpr):
+    """Every ``dot_general`` equation in ``jaxpr`` and the jaxprs nested in
+    its equations' parameters (jit, pallas_call, cond branches)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _dot_generals(sub)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_lowrank_linear_path_follows_x_dtype(dtype):
+    """The dtype of ``x`` is the only switch: the kernel traced for bf16
+    activations holds no fp32 dot and no ``HIGHEST`` contraction; the one
+    traced for fp32 activations keeps them."""
+    x = jnp.zeros((2, 40, 32), dtype)
+    w = jnp.zeros((32, 16), dtype)
+    basis, rt = jnp.zeros((16, 8)), jnp.zeros((32, 8))
+    jaxpr = jax.make_jaxpr(lambda x, w, b, rt: kops.lowrank_linear(
+        x, w, b, rt, 1.0, side="right", block_rows=16))(x, w, basis, rt)
+    dots = list(_dot_generals(jaxpr.jaxpr))
+    fp32 = [e for e in dots
+            if any(v.aval.dtype == jnp.float32 for v in e.invars)
+            or jax.lax.Precision.HIGHEST in (e.params["precision"] or ())]
+    if dtype == jnp.bfloat16:
+        assert len(dots) >= 3 and not fp32, [e.params for e in fp32]
+    else:
+        assert fp32
+
+
 def test_lowrank_linear_ref_equals_materialized_weight():
     """The split matmul IS x @ (scale·W + lift) — per side."""
     for side, (m, n) in (("right", (10, 6)), ("left", (6, 10))):

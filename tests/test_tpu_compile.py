@@ -86,17 +86,44 @@ def test_galore_precond_step_compiles(one_chip, case):
              ((), f32))
 
 
+CLIENTS, CELL_TOKENS = 4, 2 * 512   # the round's cohort chunk, vmapped
+
+# (x, w, basis, rt) of one call; the *-cell cases are the fed-round cell's
+# calls: CLIENTS clients vmapped over one shared base, CELL_TOKENS each.
 _LOWRANK = {
     "right": ((TOKENS, FF), (FF, D), (D, R), (FF, R)),
     "left": ((TOKENS, D), (D, FF), (D, R), (R, FF)),
+    "wq-cell": ((CLIENTS, CELL_TOKENS, D), (D, D), (CLIENTS, D, R),
+                (CLIENTS, D, R)),
+    "w_gate-cell": ((CLIENTS, CELL_TOKENS, D), (D, FF), (CLIENTS, D, R),
+                    (CLIENTS, R, FF)),
+    "w_down-cell": ((CLIENTS, CELL_TOKENS, FF), (FF, D), (CLIENTS, D, R),
+                    (CLIENTS, FF, R)),
 }
 
 
-@pytest.mark.parametrize("side", sorted(_LOWRANK))
-def test_lowrank_linear_compiles(one_chip, side):
-    x, w, basis, rt = _LOWRANK[side]
-    _compile(lowrank_linear, one_chip, (x, bf16), (w, bf16),
-             (basis, f32), (rt, f32), ((), f32))
+@pytest.mark.parametrize("case", sorted(_LOWRANK))
+def test_lowrank_linear_compiles(one_chip, case):
+    x, w, basis, rt = _LOWRANK[case]
+    if len(x) == 2:
+        _compile(lowrank_linear, one_chip, (x, bf16), (w, bf16),
+                 (basis, f32), (rt, f32), ((), f32))
+        return
+
+    def clients(x, w, basis, rt, scale):
+        return jax.vmap(lowrank_linear, in_axes=(0, None, 0, 0, 0))(
+            x, w, basis, rt, scale)
+
+    text = _compile(clients, one_chip, (x, bf16), (w, bf16), (basis, f32),
+                    (rt, f32), ((CLIENTS,), f32)).as_text()
+    # the operands the roofline reader counts: (scale, x, w, basis, rt)
+    call = next(l for l in text.splitlines() if "tpu_custom_call" in l)
+    operands = call.split("operand_layout_constraints={", 1)[1]
+    want = [f"f32[{CLIENTS},1,1]", f"bf16[{','.join(map(str, x))}]",
+            f"bf16[{','.join(map(str, w))}]",
+            f"f32[{','.join(map(str, basis))}]",
+            f"f32[{','.join(map(str, rt))}]"]
+    assert [o.split("{")[0] for o in operands.split("}, ")[:5]] == want
 
 
 @pytest.mark.parametrize("x_shape", [(G, D), (G, 128, D)],
