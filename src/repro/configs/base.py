@@ -26,7 +26,7 @@ class ArchConfig:
     vocab_size: int
     head_dim: Optional[int] = None
     qkv_bias: bool = False
-    pos_emb: str = "rope"          # rope | sinusoidal | none
+    pos_emb: str = "rope"          # rope | sinusoidal | learned | none
     rope_theta: float = 1e4
     sliding_window: int = 0        # 0 = full attention
     # Blockwise (flash-style) attention chunk for train/prefill when
@@ -58,6 +58,18 @@ class ArchConfig:
     mamba_d_conv: int = 4
     # --- SSM (RWKV6) ---
     rwkv: bool = False
+    # --- encoder (RoBERTa): every default keeps the causal decoder ---
+    causal: bool = True            # False: bidirectional attention, no decode
+    post_norm: bool = False        # h = LN(h + f(h)) in each block, no final norm
+    max_positions: int = 0         # rows of the learned table (pos_emb="learned")
+    pos_offset: int = 0            # id of the first position (RoBERTa: pad id + 1)
+    type_vocab_size: int = 0       # token-type rows; every token takes type 0
+    embed_norm: bool = False       # norm over the summed embeddings
+    proj_bias: bool = False        # biases on wo and both MLP projections
+    n_classes: int = 0             # classification head at row 0; 0 = LM head
+    # Attention and MLP projection matrices stored in fp32 (master weights,
+    # as a published fp32 checkpoint holds them) and read in ``dtype``.
+    fp32_weights: bool = False
     # --- modality frontend (stub) ---
     frontend: str = "none"         # none | vision | audio
     frontend_tokens: int = 0       # patch/frame embeddings prepended
@@ -115,7 +127,11 @@ class ArchConfig:
     def param_count(self) -> int:
         """Analytic parameter count (used for 6·N·D model FLOPs)."""
         d = self.d_model
-        total = self.vocab_size * d * (1 if self.tie_embeddings else 2)
+        lm_heads = 0 if (self.tie_embeddings or self.n_classes) else 1
+        total = self.vocab_size * d * (1 + lm_heads)
+        total += (self.max_positions + self.type_vocab_size) * d
+        if self.n_classes:
+            total += d * d + d * self.n_classes          # dense, out_proj
         for mix, ffn in self.layer_kinds():
             if mix == "attn":
                 total += d * self.n_heads * self.hd * 2          # wq, wo
@@ -190,7 +206,7 @@ def _load_all():
     from . import (granite_moe_1b_a400m, deepseek_v2_236b, command_r_35b,  # noqa
                    mistral_nemo_12b, qwen1_5_0_5b, pixtral_12b,
                    jamba_1_5_large_398b, starcoder2_7b, musicgen_medium,
-                   rwkv6_1_6b, paper_roberta_like, paper_vit_like,
+                   rwkv6_1_6b, roberta_base, paper_vit_like,
                    paper_llama_like)
 
 

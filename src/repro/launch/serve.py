@@ -391,6 +391,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
+    model_lib.require_decoder(cfg)
     if args.smoke:
         cfg = smoke_variant(cfg)
     key = jax.random.PRNGKey(args.seed)

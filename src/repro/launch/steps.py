@@ -67,7 +67,9 @@ PyTree = Any
 def galore_target_fn(cfg: ArchConfig) -> Callable:
     """The paper's target modules, adapted per family (DESIGN.md §4):
     attention + dense-MLP projections; Mamba in/out projections; RWKV6
-    time-mix/channel-mix matrices. Experts, routers, embeddings frozen."""
+    time-mix/channel-mix matrices. Experts, routers, embeddings and the
+    output head (LM or classifier) frozen: RoBERTa-base trains its 72
+    projections (q, k, v, o, up, down x 12), its biases ride frozen."""
 
     def fn(path: str, leaf) -> bool:
         if leaf.ndim < 2:

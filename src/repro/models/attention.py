@@ -1,4 +1,5 @@
-"""Attention: GQA (full / sliding-window causal) and MLA (DeepSeek-V2).
+"""Attention: GQA (full / sliding-window causal, or bidirectional for an
+encoder) and MLA (DeepSeek-V2).
 
 Decode uses a ring-buffer KV cache (size = window for sliding-window archs,
 so long_500k decode keeps O(window) memory). MLA decode uses the *absorbed*
@@ -20,7 +21,7 @@ NEG_INF = -1e30
 # ------------------------------------------------------------------- GQA ----
 
 def gqa_init(key, d_model: int, n_heads: int, n_kv: int, head_dim: int,
-             qkv_bias: bool = False, dtype=jnp.float32):
+             qkv_bias: bool = False, dtype=jnp.float32, out_bias: bool = False):
     ks = jax.random.split(key, 4)
     p = {"wq": dense_init(ks[0], (d_model, n_heads * head_dim), dtype=dtype),
          "wk": dense_init(ks[1], (d_model, n_kv * head_dim), dtype=dtype),
@@ -30,6 +31,8 @@ def gqa_init(key, d_model: int, n_heads: int, n_kv: int, head_dim: int,
         p["bq"] = jnp.zeros((n_heads * head_dim,), dtype)
         p["bk"] = jnp.zeros((n_kv * head_dim,), dtype)
         p["bv"] = jnp.zeros((n_kv * head_dim,), dtype)
+    if out_bias:
+        p["bo"] = jnp.zeros((d_model,), dtype)
     return p
 
 
@@ -50,7 +53,8 @@ def causal_mask(q_pos: jnp.ndarray, k_pos: jnp.ndarray,
 
 @jax.named_scope("model.attention")
 def attend(q, k, v, mask) -> jnp.ndarray:
-    """q (B,Lq,H,hd), k/v (B,Lk,Hkv,hd) with H % Hkv == 0; mask (B|1,Lq,Lk).
+    """q (B,Lq,H,hd), k/v (B,Lk,Hkv,hd) with H % Hkv == 0; mask (B|1,Lq,Lk),
+    or None for bidirectional attention (every query sees every key).
 
     Matmuls take bf16 operands with fp32 accumulation
     (``preferred_element_type``) — no materialized fp32 copy of K/V, which
@@ -65,7 +69,8 @@ def attend(q, k, v, mask) -> jnp.ndarray:
     scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, k,
                         preferred_element_type=jnp.float32)
     scores = scores / jnp.sqrt(hd).astype(jnp.float32)
-    scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
+    if mask is not None:
+        scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
     ctx = jnp.einsum("bkgqs,bskh->bqkgh", w.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
@@ -74,13 +79,14 @@ def attend(q, k, v, mask) -> jnp.ndarray:
 
 @jax.named_scope("model.attention")
 def blockwise_attend(q, k, v, *, window=0, chunk_q=2048, chunk_k=2048,
-                     q_start=0) -> jnp.ndarray:
+                     q_start=0, causal=True) -> jnp.ndarray:
     """Flash-style blockwise causal attention in pure XLA (§Perf iteration B).
 
     Both the query and key sequences are chunked; (q-chunk, k-chunk) pairs
     that are *entirely* masked — future blocks under causality, stale blocks
     under a sliding window — are skipped STATICALLY, so the saved FLOPs and
     bytes are real in the compiled HLO (≈2× for causal, window/L for SWA).
+    ``causal=False`` (an encoder) keeps every pair and masks none.
     Per-pair online-softmax statistics keep the working set at
     (B, H, chunk_q, chunk_k); the full (L, L) score tensor never exists.
     The Pallas kernel (kernels/flash_attention.py) is the TPU-native twin of
@@ -93,6 +99,9 @@ def blockwise_attend(q, k, v, *, window=0, chunk_q=2048, chunk_k=2048,
     assert lq % cq == 0 and lk % ck == 0
     scale = 1.0 / (hd ** 0.5)
     qg = q.reshape(b, lq, hkv, g, hd)
+    if not causal and window:
+        raise ValueError("a sliding window is causal: bidirectional "
+                         "attention takes window=0")
 
     outs = []
     for qi in range(lq // cq):
@@ -104,7 +113,7 @@ def blockwise_attend(q, k, v, *, window=0, chunk_q=2048, chunk_k=2048,
         acc = jnp.zeros((b, hkv, g, cq, hd), jnp.float32)
         for ki in range(lk // ck):
             k_lo, k_hi = ki * ck, ki * ck + ck - 1
-            if k_lo > q_hi:
+            if causal and k_lo > q_hi:
                 continue                      # fully in the future
             if window and k_hi < q_lo - window + 1:
                 continue                      # fully outside the window
@@ -112,7 +121,7 @@ def blockwise_attend(q, k, v, *, window=0, chunk_q=2048, chunk_k=2048,
             v_blk = v[:, k_lo:k_lo + ck]
             s = jnp.einsum("bqkgh,bskh->bkgqs", q_blk, k_blk,
                            preferred_element_type=jnp.float32) * scale
-            crosses_causal = k_hi > q_lo
+            crosses_causal = causal and k_hi > q_lo
             crosses_window = window and k_lo < q_hi - window + 1
             if crosses_causal or crosses_window:
                 qp = q_lo + jnp.arange(cq)
@@ -134,8 +143,10 @@ def blockwise_attend(q, k, v, *, window=0, chunk_q=2048, chunk_k=2048,
 
 
 def gqa_forward(p, x, positions, *, n_heads, n_kv, head_dim, rope=True,
-                rope_theta=1e4, window=0, attn_chunk=0):
-    """Training/prefill attention over a full sequence. x (B,L,D)."""
+                rope_theta=1e4, window=0, attn_chunk=0, causal=True):
+    """Training/prefill attention over a full sequence. x (B,L,D).
+    ``causal=False`` is an encoder's: no mask is built and no block is
+    skipped."""
     b, l, _ = x.shape
     q = dense(x, p["wq"]) + p.get("bq", 0)
     k = dense(x, p["wk"]) + p.get("bk", 0)
@@ -151,14 +162,20 @@ def gqa_forward(p, x, positions, *, n_heads, n_kv, head_dim, rope=True,
         k = apply_rope(k, positions, rope_theta)
     if attn_chunk and l >= attn_chunk:
         c = min(attn_chunk, l // 2)
-        ctx = blockwise_attend(q, k, v, window=window, chunk_q=c, chunk_k=c)
-    else:
+        ctx = blockwise_attend(q, k, v, window=window, chunk_q=c, chunk_k=c,
+                               causal=causal)
+    elif causal:
         mask = causal_mask(positions, positions, window)
         if mask.ndim == 2:
             mask = mask[None]
         ctx = attend(q, k, v, mask)
+    else:
+        ctx = attend(q, k, v, None)
     ctx = constrain(ctx, "batch", None, "model", None)
-    return dense(ctx.reshape(b, l, n_heads * head_dim), p["wo"]), (k, v)
+    out = dense(ctx.reshape(b, l, n_heads * head_dim), p["wo"])
+    if "bo" in p:
+        out = out + p["bo"]
+    return out, (k, v)
 
 
 class KVCache(NamedTuple):

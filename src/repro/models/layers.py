@@ -449,7 +449,8 @@ def norm_init(d: int, kind: str, dtype=jnp.float32):
 
 ACTS = {
     "silu": jax.nn.silu,
-    "gelu": jax.nn.gelu,
+    "gelu": jax.nn.gelu,                 # tanh form (starcoder2, musicgen)
+    "gelu_exact": functools.partial(jax.nn.gelu, approximate=False),  # erf
     "relu": jax.nn.relu,
 }
 
@@ -467,15 +468,28 @@ def glu_mlp(p, x, act: str = "silu"):
     return dense(gate * dense(x, p["w_up"]), p["w_down"])
 
 
-def mlp_init(key, d_model: int, d_ff: int, dtype=jnp.float32):
+def mlp_init(key, d_model: int, d_ff: int, dtype=jnp.float32,
+             bias: bool = False):
     k1, k2 = jax.random.split(key)
-    return {"w_up": dense_init(k1, (d_model, d_ff), dtype=dtype),
-            "w_down": dense_init(k2, (d_ff, d_model), dtype=dtype)}
+    p = {"w_up": dense_init(k1, (d_model, d_ff), dtype=dtype),
+         "w_down": dense_init(k2, (d_ff, d_model), dtype=dtype)}
+    if bias:
+        p["b_up"] = jnp.zeros((d_ff,), dtype)
+        p["b_down"] = jnp.zeros((d_model,), dtype)
+    return p
 
 
 def mlp(p, x, act: str = "gelu"):
-    """Plain 2-layer MLP (starcoder2 / musicgen style)."""
-    return dense(ACTS[act](dense(x, p["w_up"])), p["w_down"])
+    """Plain 2-layer MLP (starcoder2 / musicgen style; RoBERTa's with the
+    biases). A bias is a frozen leaf added to the ``dense`` output, so a
+    lift-free target keeps its kernel and the bias rides outside it."""
+    up = dense(x, p["w_up"])
+    if "b_up" in p:
+        up = up + p["b_up"]
+    out = dense(ACTS[act](up), p["w_down"])
+    if "b_down" in p:
+        out = out + p["b_down"]
+    return out
 
 
 # ------------------------------------------------------------------ RoPE ----

@@ -1,6 +1,8 @@
-"""Config-driven decoder model: one implementation covering all ten assigned
-architectures (dense GQA, MoE, MLA+MoE, Mamba/attention hybrid, RWKV6,
-VLM/audio backbones).
+"""Config-driven model: one implementation covering all ten assigned
+decoder architectures (dense GQA, MoE, MLA+MoE, Mamba/attention hybrid,
+RWKV6, VLM/audio backbones) and the RoBERTa encoder (bidirectional
+attention, post-LN blocks, learned positions, a classification head read at
+the ``<s>`` row; static choices on ``ArchConfig``).
 
 Layers are grouped into the config's repeating block (``block_period``) and
 executed with ``lax.scan`` over stacked block parameters — compile time stays
@@ -8,10 +10,13 @@ flat in depth (72-layer Jamba lowers as one scanned block of 8), and
 activation rematerialization wraps the scanned body.
 
 Three entry points, matching the input-shape matrix:
-  * ``loss_fn``      — next-token CE training step objective (train_4k)
+  * ``loss_fn``      — next-token CE training step objective (train_4k);
+                       an encoder's class CE at the ``<s>`` row
   * ``prefill``      — full-sequence forward that fills decode caches (prefill_32k)
   * ``decode_step``  — one token with KV cache / recurrent state
                        (decode_32k, long_500k)
+
+An encoder has no decode: ``init_decode_state`` refuses it.
 """
 from __future__ import annotations
 
@@ -25,8 +30,9 @@ from . import attention as attn_lib
 from . import mamba as mamba_lib
 from . import moe as moe_lib
 from . import rwkv as rwkv_lib
-from .layers import (apply_norm, constrain, dense_init, glu_mlp, glu_mlp_init,
-                     mlp, mlp_init, norm_init, sinusoidal_positions)
+from .layers import (LowRankDelta, apply_norm, constrain, dense_init, glu_mlp,
+                     glu_mlp_init, mlp, mlp_init, norm_init,
+                     sinusoidal_positions)
 from ..configs.base import ArchConfig
 
 PyTree = Any
@@ -41,7 +47,7 @@ def _init_layer(key, cfg: ArchConfig, mix: str, ffn: str) -> Dict:
     if mix == "attn":
         p["attn"] = attn_lib.gqa_init(ks[0], cfg.d_model, cfg.n_heads,
                                       cfg.n_kv_heads, cfg.hd, cfg.qkv_bias,
-                                      dtype)
+                                      dtype, out_bias=cfg.proj_bias)
     elif mix == "mla":
         p["attn"] = attn_lib.mla_init(
             ks[0], cfg.d_model, cfg.n_heads, q_lora=cfg.q_lora_rank,
@@ -66,7 +72,13 @@ def _init_layer(key, cfg: ArchConfig, mix: str, ffn: str) -> Dict:
     elif cfg.mlp_kind == "glu":
         p["mlp"] = glu_mlp_init(ks[1], cfg.d_model, cfg.d_ff, dtype)
     else:
-        p["mlp"] = mlp_init(ks[1], cfg.d_model, cfg.d_ff, dtype)
+        p["mlp"] = mlp_init(ks[1], cfg.d_model, cfg.d_ff, dtype,
+                            bias=cfg.proj_bias)
+    if cfg.fp32_weights:
+        for g in ("attn", "mlp"):
+            if g in p:
+                p[g] = {k: v.astype(jnp.float32) if v.ndim == 2 else v
+                        for k, v in p[g].items()}
     return p
 
 
@@ -83,15 +95,29 @@ def init_params(key: jax.Array, cfg: ArchConfig) -> PyTree:
         stacked = jax.vmap(lambda kk: _init_layer(kk, cfg, mix, ffn))(keys)
         blocks.append(stacked)
 
-    params = {
-        "embed": {"w": dense_init(k_embed, (cfg.vocab_size, cfg.d_model),
-                                  dtype=dtype)},
-        "blocks": blocks,
-        "final_norm": norm_init(cfg.d_model, cfg.norm),
-    }
-    if not cfg.tie_embeddings:
-        params["lm_head"] = {"w": dense_init(k_head,
-                                             (cfg.d_model, cfg.vocab_size),
+    d = cfg.d_model
+    embed = {"w": dense_init(k_embed, (cfg.vocab_size, d), dtype=dtype)}
+    if cfg.pos_emb == "learned":
+        embed["pos"] = dense_init(jax.random.fold_in(k_embed, 1),
+                                  (cfg.max_positions, d), dtype=dtype)
+    if cfg.type_vocab_size:
+        embed["type"] = dense_init(jax.random.fold_in(k_embed, 2),
+                                   (cfg.type_vocab_size, d), dtype=dtype)
+    if cfg.embed_norm:
+        embed["norm"] = norm_init(d, cfg.norm)
+    params = {"embed": embed, "blocks": blocks}
+    if not cfg.post_norm:
+        params["final_norm"] = norm_init(d, cfg.norm)
+    if cfg.n_classes:
+        k_dense, k_out = jax.random.split(k_head)
+        params["cls_head"] = {
+            "dense": {"w": dense_init(k_dense, (d, d), dtype=dtype),
+                      "b": jnp.zeros((d,), dtype)},
+            "out_proj": {"w": dense_init(k_out, (d, cfg.n_classes),
+                                         dtype=dtype),
+                         "b": jnp.zeros((cfg.n_classes,), dtype)}}
+    elif not cfg.tie_embeddings:
+        params["lm_head"] = {"w": dense_init(k_head, (d, cfg.vocab_size),
                                              dtype=dtype)}
     return params
 
@@ -116,14 +142,41 @@ def _scan_blocks(cfg: ArchConfig, body, carry, xs):
 
 # --------------------------------------------------------------- forward ----
 
+def _compute_view(lp, cfg: ArchConfig):
+    """A layer's weights as the forward reads them: fp32 master projection
+    matrices (``cfg.fp32_weights``), plain or as the base of a lift-free
+    ``LowRankDelta``, in the compute dtype; everything else as stored."""
+    if not cfg.fp32_weights:
+        return lp
+    dt = cfg.param_dtype
+
+    def read(x):
+        if isinstance(x, LowRankDelta):
+            return x._replace(w=x.w.astype(dt))
+        return x.astype(dt) if x.ndim >= 2 else x
+
+    return jax.tree_util.tree_map(
+        read, lp, is_leaf=lambda x: isinstance(x, LowRankDelta))
+
+
+def _residual(h, out, norm_p, cfg: ArchConfig):
+    """Pre-norm ``h + out``; post-norm (RoBERTa) ``norm(h + out)``, the sum
+    and the norm in fp32 under the ``model.post_norm`` scope."""
+    if not cfg.post_norm:
+        return h + out
+    with jax.named_scope("model.post_norm"):
+        s = h.astype(jnp.float32) + out.astype(jnp.float32)
+        return apply_norm(s, norm_p, cfg.norm).astype(h.dtype)
+
+
 def _apply_mixer(lp, cfg: ArchConfig, mix: str, h, positions):
-    x = apply_norm(h, lp["norm1"], cfg.norm)
+    x = h if cfg.post_norm else apply_norm(h, lp["norm1"], cfg.norm)
     if mix == "attn":
         out, _ = attn_lib.gqa_forward(
             lp["attn"], x, positions, n_heads=cfg.n_heads,
             n_kv=cfg.n_kv_heads, head_dim=cfg.hd, rope=(cfg.pos_emb == "rope"),
             rope_theta=cfg.rope_theta, window=cfg.sliding_window,
-            attn_chunk=cfg.attn_chunk)
+            attn_chunk=cfg.attn_chunk, causal=cfg.causal)
     elif mix == "mla":
         out, _ = attn_lib.mla_forward(
             lp["attn"], x, positions, n_heads=cfg.n_heads,
@@ -138,11 +191,11 @@ def _apply_mixer(lp, cfg: ArchConfig, mix: str, h, positions):
     else:  # rwkv
         st = rwkv_lib.rwkv_state_init(x.shape[0], cfg.d_model)
         out = rwkv_lib.time_mix_forward(lp["tmix"], x, st, cfg.d_model)
-    return h + out
+    return _residual(h, out, lp["norm1"], cfg)
 
 
 def _apply_ffn(lp, cfg: ArchConfig, ffn: str, h):
-    x = apply_norm(h, lp["norm2"], cfg.norm)
+    x = h if cfg.post_norm else apply_norm(h, lp["norm2"], cfg.norm)
     aux = jnp.zeros([], jnp.float32)
     if ffn == "moe":
         out, aux = moe_lib.moe_forward(lp["moe"], x,
@@ -155,25 +208,39 @@ def _apply_ffn(lp, cfg: ArchConfig, ffn: str, h):
         out = glu_mlp(lp["mlp"], x, cfg.act)
     else:
         out = mlp(lp["mlp"], x, cfg.act)
-    return h + out, aux
+    return _residual(h, out, lp["norm2"], cfg), aux
 
 
 def _embed(params, cfg: ArchConfig, tokens, embeds):
     # Anchor the activation sharding right after the table gather — gathers
     # from a (model, data)-sharded table are where SPMD otherwise loses the
     # batch/client partitioning (§Perf iteration A).
-    h = constrain(params["embed"]["w"][tokens], "batch", None, None)
+    emb = params["embed"]
+    h = constrain(emb["w"][tokens], "batch", None, None)
     if embeds is not None:
         h = jnp.concatenate([embeds.astype(h.dtype), h], axis=1)
     if cfg.pos_emb == "sinusoidal":
         pos = jnp.arange(h.shape[1])
         h = h + sinusoidal_positions(pos, cfg.d_model)[None].astype(h.dtype)
+    if cfg.pos_emb == "learned" or cfg.type_vocab_size or cfg.embed_norm:
+        # RoBERTa: word + position (from pos_offset) + token type 0, then
+        # the embedding norm, summed in fp32.
+        s = h.astype(jnp.float32)
+        if cfg.pos_emb == "learned":
+            pos = cfg.pos_offset + jnp.arange(h.shape[1])
+            s = s + emb["pos"][pos][None].astype(jnp.float32)
+        if cfg.type_vocab_size:
+            s = s + emb["type"][0].astype(jnp.float32)
+        if cfg.embed_norm:
+            s = apply_norm(s, emb["norm"], cfg.norm)
+        h = s.astype(h.dtype)
     return h
 
 
 @jax.named_scope("model.head")
 def _logits(params, cfg: ArchConfig, h):
-    h = apply_norm(h, params["final_norm"], cfg.norm)
+    if not cfg.post_norm:           # a post-LN stack ends normalized
+        h = apply_norm(h, params["final_norm"], cfg.norm)
     w = (params["embed"]["w"].T if cfg.tie_embeddings
          else params["lm_head"]["w"])
     # vocab-sharded logits, batch/client pinned (under the fed-train vmap the
@@ -181,9 +248,23 @@ def _logits(params, cfg: ArchConfig, h):
     return constrain((h @ w).astype(jnp.float32), "batch", None, "model")
 
 
+@jax.named_scope("model.head")
+def _classify(params, cfg: ArchConfig, h):
+    """RobertaForSequenceClassification's head at the ``<s>`` row: dense,
+    tanh, ``out_proj``. (B, n_classes) fp32 logits."""
+    hp = params["cls_head"]
+    x = h[:, 0].astype(jnp.float32)
+    y = jnp.tanh(x @ hp["dense"]["w"].astype(jnp.float32)
+                 + hp["dense"]["b"].astype(jnp.float32))
+    return (y @ hp["out_proj"]["w"].astype(jnp.float32)
+            + hp["out_proj"]["b"].astype(jnp.float32))
+
+
 def forward(params: PyTree, cfg: ArchConfig, tokens: jnp.ndarray,
             embeds: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Full-sequence causal forward. Returns (logits fp32, moe aux loss)."""
+    """Full-sequence forward (causal, or bidirectional for an encoder).
+    Returns (logits fp32, moe aux loss): (B, L, vocab) logits, or an
+    encoder's (B, n_classes) class logits."""
     kinds = cfg.layer_kinds()[: cfg.block_period()]
     h = _embed(params, cfg, tokens, embeds)
     positions = jnp.arange(h.shape[1])
@@ -191,7 +272,7 @@ def forward(params: PyTree, cfg: ArchConfig, tokens: jnp.ndarray,
     def block_body(carry, block_params):
         h, aux = carry
         for j, (mix, ffn) in enumerate(kinds):
-            lp = block_params[j]
+            lp = _compute_view(block_params[j], cfg)
             h = _apply_mixer(lp, cfg, mix, h, positions)
             h, a = _apply_ffn(lp, cfg, ffn, h)
             aux = aux + a
@@ -200,14 +281,23 @@ def forward(params: PyTree, cfg: ArchConfig, tokens: jnp.ndarray,
     body = jax.checkpoint(block_body) if cfg.remat else block_body
     (h, aux), _ = _scan_blocks(cfg, body, (h, jnp.zeros([], jnp.float32)),
                                params["blocks"])
+    if cfg.n_classes:
+        return _classify(params, cfg, h), aux
     return _logits(params, cfg, h), aux
 
 
 def loss_fn(params: PyTree, cfg: ArchConfig, batch: Dict,
             aux_coef: float = 0.01) -> jnp.ndarray:
     """Next-token cross-entropy; labels == -1 are masked (e.g. frontend
-    positions in VLM batches)."""
+    positions in VLM batches). An encoder's (``n_classes``): cross-entropy
+    of the (B,) class ids in ``labels`` at the ``<s>`` row."""
     logits, aux = forward(params, cfg, batch["tokens"], batch.get("embeds"))
+    if cfg.n_classes:
+        with jax.named_scope("model.head"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, batch["labels"][:, None],
+                                       axis=-1)[:, 0]
+            return jnp.mean(nll) + aux_coef * aux
     labels = batch["labels"]
     n_front = logits.shape[1] - labels.shape[1]
     if n_front:
@@ -242,12 +332,23 @@ def _layer_state_init(cfg: ArchConfig, mix: str, batch: int, cache_len: int):
     return rwkv_lib.rwkv_state_init(batch, cfg.d_model)
 
 
+def require_decoder(cfg: ArchConfig) -> None:
+    """Refuse a configuration that has no decode: a bidirectional encoder
+    (or any model with a classification head instead of an LM head)."""
+    if not cfg.causal or cfg.n_classes:
+        raise ValueError(
+            f"{cfg.name} is a bidirectional encoder with a classification "
+            "head: it has no decode, so it cannot be served token by token "
+            "(train or classify it through loss_fn / forward)")
+
+
 def init_decode_state(cfg: ArchConfig, batch: int, cache_len: int,
                       per_slot: bool = False) -> DecodeState:
     """cache_len: KV slots. For sliding-window archs pass the window size —
     the ring buffer keeps memory O(window) at any context length.
     ``per_slot`` starts ``t`` as a (B,) vector — each batch row advances at
     its own depth (the continuous-batching slot layout)."""
+    require_decoder(cfg)
     kinds = cfg.layer_kinds()[: cfg.block_period()]
     n_blocks = cfg.n_blocks()
     layers = []
@@ -315,7 +416,7 @@ def decode_step(params: PyTree, cfg: ArchConfig, token: jnp.ndarray,
         block_params, block_state = xs
         new_states = []
         for j, (mix, ffn) in enumerate(kinds):
-            lp, st = block_params[j], block_state[j]
+            lp, st = _compute_view(block_params[j], cfg), block_state[j]
             h, st = _mixer_decode(lp, st, cfg, mix, h, state.t)
             h, st = _ffn_decode(lp, st, cfg, ffn, h)
             new_states.append(st)
@@ -345,7 +446,7 @@ def prefill(params: PyTree, cfg: ArchConfig, tokens: jnp.ndarray,
         block_params, block_state = xs
         new_states = []
         for j, (mix, ffn) in enumerate(kinds):
-            lp, st = block_params[j], block_state[j]
+            lp, st = _compute_view(block_params[j], cfg), block_state[j]
             x = apply_norm(h, lp["norm1"], cfg.norm)
             if mix == "attn":
                 out, (k, v) = attn_lib.gqa_forward(

@@ -4,7 +4,8 @@ The TPU compiler ships with the installed jaxlib and compiles for a chip that
 is described, not attached, so these tests catch what interpret mode cannot:
 ops the kernel compiler cannot lower, block shapes it refuses, and more VMEM
 than a kernel may use. Shapes are the qwen1.5-0.5b widths the round and the
-server run at (d=1024, d_ff=2816, rank 8, bf16 activations). Nothing runs;
+server run at (d=1024, d_ff=2816, rank 8, bf16 activations), and the
+roberta-base widths of the encoder round (d=768, d_ff=3072). Nothing runs;
 a compile that passes says nothing about results or times.
 
 The topology is described inside a module fixture, never at import, so every
@@ -87,6 +88,8 @@ def test_galore_precond_step_compiles(one_chip, case):
 
 
 CLIENTS, CELL_TOKENS = 4, 2 * 512   # the round's cohort chunk, vmapped
+# roberta-base cell: a chunk of 16 clients, 8 x 128 tokens each
+ENC_D, ENC_FF, ENC_CLIENTS, ENC_TOKENS = 768, 3072, 16, 8 * 128
 
 # (x, w, basis, rt) of one call; the *-cell cases are the fed-round cell's
 # calls: CLIENTS clients vmapped over one shared base, CELL_TOKENS each.
@@ -99,6 +102,13 @@ _LOWRANK = {
                     (CLIENTS, R, FF)),
     "w_down-cell": ((CLIENTS, CELL_TOKENS, FF), (FF, D), (CLIENTS, D, R),
                     (CLIENTS, FF, R)),
+    "encoder-wq-cell": ((ENC_CLIENTS, ENC_TOKENS, ENC_D), (ENC_D, ENC_D),
+                        (ENC_CLIENTS, ENC_D, R), (ENC_CLIENTS, ENC_D, R)),
+    "encoder-w_up-cell": ((ENC_CLIENTS, ENC_TOKENS, ENC_D), (ENC_D, ENC_FF),
+                          (ENC_CLIENTS, ENC_D, R), (ENC_CLIENTS, R, ENC_FF)),
+    "encoder-w_down-cell": ((ENC_CLIENTS, ENC_TOKENS, ENC_FF),
+                            (ENC_FF, ENC_D), (ENC_CLIENTS, ENC_D, R),
+                            (ENC_CLIENTS, ENC_FF, R)),
 }
 
 
@@ -114,12 +124,13 @@ def test_lowrank_linear_compiles(one_chip, case):
         return jax.vmap(lowrank_linear, in_axes=(0, None, 0, 0, 0))(
             x, w, basis, rt, scale)
 
+    n = x[0]
     text = _compile(clients, one_chip, (x, bf16), (w, bf16), (basis, f32),
-                    (rt, f32), ((CLIENTS,), f32)).as_text()
+                    (rt, f32), ((n,), f32)).as_text()
     # the operands the roofline reader counts: (scale, x, w, basis, rt)
     call = next(l for l in text.splitlines() if "tpu_custom_call" in l)
     operands = call.split("operand_layout_constraints={", 1)[1]
-    want = [f"f32[{CLIENTS},1,1]", f"bf16[{','.join(map(str, x))}]",
+    want = [f"f32[{n},1,1]", f"bf16[{','.join(map(str, x))}]",
             f"bf16[{','.join(map(str, w))}]",
             f"f32[{','.join(map(str, basis))}]",
             f"f32[{','.join(map(str, rt))}]"]
