@@ -2,7 +2,8 @@
 
 * ``galore_adamw`` — the paper's optimizer step, fused project → moment
   update → precondition → project-back in one VMEM-resident pass.
-* ``flash_attention`` — blockwise GQA attention (train/prefill hot-spot).
+* ``flash_attention`` — blockwise GQA attention, forward and custom-VJP
+  backward (the training path's attention below ``attn_chunk``).
 * ``rwkv6_scan`` — chunked WKV recurrence with VMEM-persistent state.
 * ``lowrank_linear`` — lift-free factored weight read: one fused pass for
   ``scale·(x@W) + split-matmul rank-r delta`` (the federated client forward).

@@ -40,7 +40,7 @@ def use_kernels() -> bool:
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, scale=None,
-                    block_q=128, block_k=128):
+                    block_q=512, block_k=256):
     return _flash(q, k, v, causal=causal, window=window, scale=scale,
                   block_q=block_q, block_k=block_k, interpret=_interpret())
 

@@ -13,6 +13,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..kernels import ops as kops
 from .layers import apply_rope, constrain, dense, dense_init
 
 NEG_INF = -1e30
@@ -142,11 +143,33 @@ def blockwise_attend(q, k, v, *, window=0, chunk_q=2048, chunk_k=2048,
     return full.transpose(0, 3, 1, 2, 4).reshape(b, lq, h, hd).astype(q.dtype)
 
 
+def flash_route(lq: int, lk: int, window: int, attn_chunk: int) -> bool:
+    """Whether full-sequence attention over ``lq`` queries and ``lk`` keys
+    takes the Pallas flash kernel (``kernels.flash_attention``) instead of
+    :func:`attend`: the kernels are on (``kernels.ops.use_kernels``: a TPU,
+    one device), the sequence is square and a whole number of 128-row
+    tiles, shorter than ``attn_chunk`` (from there ``blockwise_attend``
+    takes it), and the mask is the plain causal one or none (no window)."""
+    return (lq == lk and lq % 128 == 0 and not window
+            and not (attn_chunk and lq >= attn_chunk)
+            and kops.use_kernels())
+
+
+@jax.named_scope("model.attention")
+def flash_attend(q, k, v, *, causal=True) -> jnp.ndarray:
+    """:func:`attend` as the fused flash kernel: the same shapes, masks and
+    precision, forward and custom-VJP backward, with no (L, L) tensor in
+    HBM. The scope holds the kernel's backward too."""
+    return kops.flash_attention(q, k, v, causal=causal)
+
+
 def gqa_forward(p, x, positions, *, n_heads, n_kv, head_dim, rope=True,
                 rope_theta=1e4, window=0, attn_chunk=0, causal=True):
     """Training/prefill attention over a full sequence. x (B,L,D).
     ``causal=False`` is an encoder's: no mask is built and no block is
-    skipped."""
+    skipped. ``positions`` are consecutive (every caller passes
+    ``arange(L)``), so the causal mask is the index order the flash kernel
+    applies (:func:`flash_route`)."""
     b, l, _ = x.shape
     q = dense(x, p["wq"]) + p.get("bq", 0)
     k = dense(x, p["wk"]) + p.get("bk", 0)
@@ -164,6 +187,8 @@ def gqa_forward(p, x, positions, *, n_heads, n_kv, head_dim, rope=True,
         c = min(attn_chunk, l // 2)
         ctx = blockwise_attend(q, k, v, window=window, chunk_q=c, chunk_k=c,
                                causal=causal)
+    elif flash_route(l, l, window, attn_chunk):
+        ctx = flash_attend(q, k, v, causal=causal)
     elif causal:
         mask = causal_mask(positions, positions, window)
         if mask.ndim == 2:

@@ -8,6 +8,18 @@ from repro.kernels import ops, ref
 KEY = jax.random.PRNGKey(0)
 
 
+def _close(got, want, tol):
+    """Largest difference within ``tol`` of the largest |want|."""
+    got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+    scale = jnp.maximum(jnp.max(jnp.abs(want)), 1e-6)
+    assert float(jnp.max(jnp.abs(got - want)) / scale) < tol
+
+
+def _value_and_vjp(fn, q, k, v, do):
+    o, vjp = jax.vjp(fn, q, k, v)
+    return (o,) + vjp(do)
+
+
 class TestGaloreKernel:
     @pytest.mark.parametrize("m,n,r", [(128, 128, 8), (256, 128, 32),
                                        (128, 256, 16), (512, 128, 64)])
@@ -241,6 +253,129 @@ class TestFlashAttention:
         o_model = attend(q, k, v, mask)
         o_kernel = ops.flash_attention(q, k, v, block_q=64, block_k=64)
         assert jnp.allclose(o_model, o_kernel, atol=2e-5)
+
+    @staticmethod
+    def _qkv(b, l, h, hkv, d, dtype, n=4, lead=()):
+        ks = jax.random.split(jax.random.PRNGKey(7), n)
+        shapes = [lead + (b, l, h, d), lead + (b, l, hkv, d),
+                  lead + (b, l, hkv, d), lead + (b, l, h, d)]
+        return [jax.random.normal(kk, s, jnp.float32).astype(dtype)
+                for kk, s in zip(ks, shapes)]
+
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                           (jnp.bfloat16, 3e-2)],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("causal", [True, False],
+                             ids=["causal", "bidirectional"])
+    @pytest.mark.parametrize("h,hkv", [(2, 2), (4, 2)], ids=["mha", "gqa"])
+    @pytest.mark.parametrize("l", [128, 256])
+    def test_value_and_grad_match_attend(self, l, h, hkv, causal, dtype,
+                                         tol):
+        """Forward and the gradient in q, k and v against the model's
+        ``attend`` (same masks, bf16 operands with fp32 accumulation)."""
+        from repro.models.attention import attend, causal_mask
+        q, k, v, do = self._qkv(1, l, h, hkv, 64, dtype)
+        mask = causal_mask(jnp.arange(l), jnp.arange(l))[None] if causal \
+            else None
+
+        got = jax.jit(lambda: _value_and_vjp(
+            lambda q, k, v: ops.flash_attention(q, k, v, causal=causal),
+            q, k, v, do))()
+        want = jax.jit(lambda: _value_and_vjp(
+            lambda q, k, v: attend(q, k, v, mask), q, k, v, do))()
+        for g, w in zip(got, want):
+            _close(g, w, tol)
+
+    def test_grad_under_vmap_and_checkpoint(self):
+        """A vmapped client axis and a remat block around the kernel, as
+        the federated round runs it."""
+        from repro.models.attention import attend, causal_mask
+        q, k, v, do = self._qkv(1, 256, 4, 2, 64, jnp.float32, lead=(2,))
+        mask = causal_mask(jnp.arange(256), jnp.arange(256))[None]
+
+        def grads(fn):
+            def loss(q, k, v):
+                return jnp.sum(jax.checkpoint(jax.vmap(fn))(q, k, v) * do)
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+
+        got = grads(lambda q, k, v: ops.flash_attention(q, k, v))
+        want = grads(lambda q, k, v: attend(q, k, v, mask))
+        for g, w in zip(got, want):
+            _close(g, w, 1e-5)
+
+    @pytest.mark.parametrize("lq,lk,window", [(256, 256, 64),
+                                              (128, 384, 200)])
+    def test_window_grad_matches_ref(self, lq, lk, window):
+        """Sliding windows and suffix queries, forward and backward, against
+        the fp32 reference; the tiles are skipped and masked per edge."""
+        ks = jax.random.split(KEY, 4)
+        q = jax.random.normal(ks[0], (1, lq, 4, 64))
+        k = jax.random.normal(ks[1], (1, lk, 2, 64))
+        v = jax.random.normal(ks[2], (1, lk, 2, 64))
+        do = jax.random.normal(ks[3], (1, lq, 4, 64))
+
+        got = jax.jit(lambda: _value_and_vjp(
+            lambda q, k, v: ops.flash_attention(q, k, v, window=window),
+            q, k, v, do))()
+        want = _value_and_vjp(lambda q, k, v: ref.flash_attention_ref(
+            q, k, v, window=window), q, k, v, do)
+        for g, w in zip(got, want):
+            _close(g, w, 1e-5)
+
+
+class TestFlashRoute:
+    """``models.attention.flash_route``: which full-sequence shapes take
+    the flash kernel on the chip, and that ``gqa_forward`` follows it."""
+
+    @pytest.mark.parametrize("lq,lk,window,chunk,mesh_shape,want", [
+        (512, 512, 0, 4096, None, True),      # the qwen cell
+        (512, 512, 0, 0, None, True),         # no blockwise chunk
+        (128, 128, 0, 4096, None, True),      # one tile
+        (1, 512, 0, 4096, None, False),       # decode: one query
+        (384, 512, 0, 4096, None, False),     # not square
+        (200, 200, 0, 4096, None, False),     # not whole 128-row tiles
+        (512, 512, 64, 4096, None, False),    # sliding window
+        (4096, 4096, 0, 4096, None, False),   # blockwise_attend from here
+        (512, 512, 0, 4096, (4, 1), False),   # partitioned over a mesh
+        (512, 512, 0, 4096, (1, 1), True),    # a one-device mesh
+    ])
+    def test_rule(self, monkeypatch, lq, lk, window, chunk, mesh_shape,
+                  want):
+        from repro.models.attention import flash_route
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        if mesh_shape is None:
+            assert flash_route(lq, lk, window, chunk) is want
+            return
+        mesh = jax.sharding.AbstractMesh(mesh_shape, ("data", "model"))
+        with jax.sharding.use_abstract_mesh(mesh):
+            assert flash_route(lq, lk, window, chunk) is want
+
+    def test_cpu_keeps_attend(self):
+        from repro.models.attention import flash_route
+        assert not flash_route(512, 512, 0, 4096)
+
+    @pytest.mark.parametrize("causal", [True, False],
+                             ids=["causal", "bidirectional"])
+    def test_gqa_forward_on_the_route(self, monkeypatch, causal):
+        """``gqa_forward`` with the kernels forced on (interpret mode here)
+        gives the loss and parameter gradients of the ``attend`` path."""
+        from repro.models import attention
+        p = attention.gqa_init(KEY, 128, 4, 2, 32, qkv_bias=True)
+        x = jax.random.normal(jax.random.PRNGKey(3), (2, 128, 128))
+
+        def loss(p):
+            out, _ = attention.gqa_forward(
+                p, x, jnp.arange(128), n_heads=4, n_kv=2, head_dim=32,
+                attn_chunk=4096, causal=causal)
+            return jnp.sum(out ** 2)
+
+        want = jax.value_and_grad(loss)(p)
+        monkeypatch.setattr(ops, "use_kernels", lambda: True)
+        assert attention.flash_route(128, 128, 0, 4096)
+        got = jax.value_and_grad(loss)(p)
+        assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+        for name in want[1]:
+            _close(got[1][name], want[1][name], 1e-4)
 
 
 class TestRwkv6Kernel:

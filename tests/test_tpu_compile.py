@@ -20,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.batched_eigh import jacobi_eigh
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.galore_adamw import galore_precond_step
 from repro.kernels.lowrank_linear import lowrank_linear, lowrank_linear_batched
 
@@ -151,3 +152,61 @@ def test_lowrank_linear_batched_compiles(one_chip, x_shape):
 @pytest.mark.parametrize("n", [8, 32])
 def test_jacobi_eigh_compiles(one_chip, n):
     _compile(jacobi_eigh, one_chip, ((64, n, n), f32))
+
+
+# the qwen cell's attention: a chunk of CLIENTS clients vmapped, each a
+# (2, 512) batch of 16 heads of 64
+ATTN = (CLIENTS, 2, 512, 16, 64)
+
+
+def _flash_cell(q, k, v):
+    return jax.vmap(lambda q, k, v: flash_attention(q, k, v))(q, k, v)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_flash_attention_compiles(one_chip, direction):
+    if direction == "forward":
+        fn = _flash_cell
+    else:
+        def fn(q, k, v):
+            o, vjp = jax.vjp(_flash_cell, q, k, v)
+            return vjp(o)
+    text = _compile(fn, one_chip, (ATTN, bf16), (ATTN, bf16),
+                    (ATTN, bf16)).as_text()
+    kinds = {"forward": ["fwd"], "backward": ["fwd", "dq", "dkv"]}[direction]
+    for kind in kinds:
+        assert f"%flash_attention_{kind}_causal" in text
+
+
+def test_flash_route_keeps_the_attention_scope(one_chip, monkeypatch):
+    """``gqa_forward`` on the kernel route, differentiated: the forward and
+    both backward kernels carry ``model.attention`` in their ``op_name``,
+    so ``attention_ms.round`` still reads all of attention."""
+    import re
+
+    from repro.kernels import ops
+    from repro.models import attention
+    monkeypatch.setattr(ops, "use_kernels", lambda: True)
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    c, b, l, h, d = ATTN
+    p = jax.eval_shape(lambda: attention.gqa_init(
+        jax.random.PRNGKey(0), h * d, h, h, d, qkv_bias=True, dtype=bf16))
+
+    def loss(p, x):
+        out, _ = attention.gqa_forward(p, x, jnp.arange(l), n_heads=h,
+                                       n_kv=h, head_dim=d, attn_chunk=4096)
+        return jnp.sum(out.astype(f32) ** 2)
+
+    args = [jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), p),
+        jax.ShapeDtypeStruct((b, l, h * d), bf16, sharding=one_chip)]
+    text = jax.jit(jax.value_and_grad(loss)).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "flash_attention_" in line]
+    names = {re.search(r"%(flash_attention_\w+?)\.\d+ = ", c).group(1):
+             re.search(r'op_name="([^"]*)"', c).group(1) for c in calls}
+    assert sorted(names) == ["flash_attention_dkv_causal",
+                             "flash_attention_dq_causal",
+                             "flash_attention_fwd_causal"]
+    for op_name in names.values():
+        assert "model.attention" in op_name
