@@ -50,8 +50,12 @@ def client_models(kind: str, rounds=10, seed=0):
                batcher.round_batches(6 * rounds).items()}
     stacked = jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x, (2,) + x.shape), eng.global_trainable)
-    opt = eng._init_client_opt_states(2)
-    out_tr, _, _ = eng._local_train(stacked, opt, batches, eng.frozen)
+    opt = eng._stack_opt_state(
+        eng._init_state0(0, None, eng.global_trainable), 2)
+    local = jax.vmap(eng._local_train_one,
+                     in_axes=(0, eng._opt_axes, 0, None),
+                     out_axes=(0, eng._opt_axes, 0))
+    out_tr, _, _ = jax.jit(local)(stacked, opt, batches, eng.frozen)
 
     def client_params(i):
         tr = jax.tree_util.tree_map(lambda x: x[i], out_tr)

@@ -14,8 +14,7 @@ The runtime leg drives the same seeded adversary schedule through the SPMD
 (``refresh_mode='random'``) and once with diverged bases
 (``refresh_mode='svd'``), where the robust modes re-base every client's
 factored stack onto the reference client's basis through the r×r transfer
-Grams before the coordinate-wise vote. It also times the quarantined
-``run_rounds`` scan pipelined vs sequential at C ∈ {8, 64}.
+Grams before the coordinate-wise vote.
 
 Acceptance keys (gated by ``scripts/ci.sh --robust-smoke``):
   honest_bit_identity          the all-honest guarded run is EXACTLY the
@@ -39,10 +38,6 @@ Acceptance keys (gated by ``scripts/ci.sh --robust-smoke``):
                                more than its shared-basis defended twin
                                does — the re-based robust vote does not
                                give back the defense on diverged bases
-  quarantine_pipelined_ge_sequential
-                               the quarantined run_rounds scan pipelines:
-                               pipelined wall-clock ≤ sequential ×
-                               ``pipe_noise_tol`` at every timed cohort
 """
 from __future__ import annotations
 
@@ -192,42 +187,8 @@ def _runtime_honest_identity(rounds, n_clients, seed, local_steps=2):
     return curves[0] == curves[1], curves[0]
 
 
-def _pipeline_timing(clients=(8, 64), k_rounds=4, local_steps=1,
-                     pipe_noise_tol=1.25, seed=0, reps=2):
-    """Quarantined run_rounds, pipelined vs sequential wall-clock. The
-    quarantined scan now pipelines one round deep (the raw round core
-    returns post-screen effective weights for the deferred 𝒮) — the gate is
-    that it is never slower than the sequential oracle beyond timing
-    noise."""
-    out = {}
-    for c in clients:
-        per = {}
-        for label, pipe in (("pipelined", True), ("sequential", False)):
-            cfg, fed = _make_runtime(
-                c, "random", seed, local_steps=local_steps,
-                quarantine=True, pipeline_sync=pipe)
-            rb = _runtime_batches(cfg, seed, c, local_steps,
-                                  k_rounds=k_rounds, b=1)
-            for _ in range(2):              # compile + steady-state buffers
-                fed.run_rounds(rb)
-
-            def loop(fed=fed, rb=rb):
-                t0 = time.perf_counter()
-                fed.run_rounds(rb)
-                return (time.perf_counter() - t0) / k_rounds
-            per[f"{label}_s"] = min(loop() for _ in range(reps))
-        per["speedup"] = per["sequential_s"] / per["pipelined_s"]
-        per["ok"] = bool(per["pipelined_s"]
-                         <= per["sequential_s"] * pipe_noise_tol)
-        out[str(c)] = per
-        emit(f"robust/quar_pipe_c{c}", per["pipelined_s"] * 1e6,
-             f"speedup={per['speedup']:.2f}x")
-    return out
-
-
 def main(smoke=False, rounds=None, n_clients=4, seed=0, out=None,
-         corrupt_rate=0.2, degradation_bound=1.0, hetero_bound=0.02,
-         pipe_clients=(8, 64), pipe_noise_tol=1.25):
+         corrupt_rate=0.2, degradation_bound=1.0, hetero_bound=0.02):
     rounds = rounds or (4 if smoke else 8)
     t0 = time.perf_counter()
 
@@ -274,7 +235,7 @@ def main(smoke=False, rounds=None, n_clients=4, seed=0, out=None,
         best = min(degradation[a][d] for d in DEFENDED)
         undefended = degradation[a]["none"]
         bounded[a] = bool(best <= degradation_bound and undefended > best)
-    # -- SPMD runtime: attack parity, hetero re-basing, pipelined quarantine
+    # -- SPMD runtime: attack parity, hetero re-basing
     rt_cells, rt_landed = _runtime_attack_grid(
         rounds, n_clients, seed, corrupt_rate)
     rt_identity, rt_honest_curve = _runtime_honest_identity(
@@ -296,9 +257,6 @@ def main(smoke=False, rounds=None, n_clients=4, seed=0, out=None,
             hetero_rel[defense] = float("inf")
     hetero_parity = bool(rt_landed > 0 and all(
         r <= hetero_bound for r in hetero_rel.values()))
-    pipe = _pipeline_timing(clients=pipe_clients,
-                            k_rounds=(4 if smoke else 6),
-                            pipe_noise_tol=pipe_noise_tol, seed=seed)
 
     acceptance = {
         "honest_bit_identity": bool(bit_identity),
@@ -317,10 +275,6 @@ def main(smoke=False, rounds=None, n_clients=4, seed=0, out=None,
         "hetero_parity_rel": {d: (None if math.isinf(v) else float(v))
                               for d, v in hetero_rel.items()},
         "hetero_attack_parity": hetero_parity,
-        "pipe_noise_tol": float(pipe_noise_tol),
-        "quarantine_pipeline": pipe,
-        "quarantine_pipelined_ge_sequential": bool(
-            all(p["ok"] for p in pipe.values())),
     }
     dt = time.perf_counter() - t0
     result = {"config": {"rounds": rounds, "n_clients": n_clients,
@@ -342,9 +296,7 @@ def main(smoke=False, rounds=None, n_clients=4, seed=0, out=None,
           f"nan_ok={int(acceptance['nan_quarantined'])};"
           f"scale_best_deg={best_scale:.3f};"
           f"bounded={int(acceptance['attack_degradation_bounded'])};"
-          f"hetero_parity={int(acceptance['hetero_attack_parity'])};"
-          f"quar_pipe={int(acceptance['quarantine_pipelined_ge_sequential'])}"
-          ))
+          f"hetero_parity={int(acceptance['hetero_attack_parity'])}"))
     if out:
         dump_json(out, result)
     return result

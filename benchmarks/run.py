@@ -1,7 +1,6 @@
 """Run every paper-table benchmark. Prints ``name,us_per_call,derived`` CSV.
 
 One benchmark per paper artifact:
-  §5/App. A.1   -> bench_galore_fused     (fused vs dense hot paths)
   Tables 3/4/5  -> bench_fed_methods      (IID vs Dirichlet-0.5 across methods)
   Table 6/Fig3ab-> bench_landscape        (kinetic-trap basin fractions)
   Fig 3c        -> bench_interpolation    (client-model loss barriers)
@@ -11,7 +10,6 @@ One benchmark per paper artifact:
   Table 7       -> bench_ajive_latency
   Table 2       -> bench_comm
   §Roofline     -> roofline (reads dryrun_single.json when present)
-  Round fusion  -> bench_round_e2e (eager vs fused vs scan-over-rounds)
   Serving       -> bench_serve (scan decode, hetero adapters, slot batching)
 """
 from __future__ import annotations
@@ -46,15 +44,12 @@ def _env_hygiene() -> None:
 def main() -> None:
     _env_hygiene()
     from . import (bench_ajive_latency, bench_ajive_recovery, bench_comm,
-                   bench_fed_methods, bench_galore_fused, bench_interpolation,
-                   bench_landscape, bench_participation,
-                   bench_projector_schedule, bench_round_e2e, bench_serve,
-                   bench_state_mismatch)
+                   bench_fed_methods, bench_interpolation, bench_landscape,
+                   bench_participation, bench_projector_schedule,
+                   bench_serve, bench_state_mismatch)
 
     print("name,us_per_call,derived")
     suites = [
-        ("galore_fused", bench_galore_fused.main),
-        ("round_e2e", bench_round_e2e.main),
         ("serve", bench_serve.main),
         ("ajive_latency", bench_ajive_latency.main),
         ("ajive_recovery", bench_ajive_recovery.main),

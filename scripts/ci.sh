@@ -2,10 +2,6 @@
 # Tier-1 verify entrypoint (the exact command from ROADMAP.md).
 #
 # Usage: scripts/ci.sh [extra pytest args]
-#        scripts/ci.sh --bench-smoke   # round-fusion perf smoke: runs
-#                                      # bench_round_e2e at tiny shapes and
-#                                      # writes BENCH_round_e2e.json at the
-#                                      # repo root (perf trajectory tracking)
 #        scripts/ci.sh --participation-smoke
 #                                      # fault-injection sweep: dropout x
 #                                      # staleness across fedgalore vs the
@@ -21,16 +17,13 @@
 #                                      # BENCH_robust.json and gates on
 #                                      # honest bit-identity (both drivers),
 #                                      # NaN containment, bounded attack
-#                                      # degradation, hetero-basis attack
-#                                      # parity, and pipelined-quarantine
-#                                      # throughput
+#                                      # degradation and hetero-basis
+#                                      # attack parity
 #        scripts/ci.sh --sync-smoke    # batched-bucket 𝒮 + pipelined-scan
 #                                      # leg: runs the sync parity suites
 #                                      # (with a coverage floor on
 #                                      # state_sync/ajive when pytest-cov is
-#                                      # installed), then gates the 𝒮-stage
-#                                      # budget and pipelined ≥ sequential
-#                                      # keys on BENCH_round_e2e.json
+#                                      # installed)
 #        scripts/ci.sh --serve-smoke   # multi-tenant serving leg: runs the
 #                                      # serving suite (batched hetero-adapter
 #                                      # kernel, scan≡eager decode parity,
@@ -74,29 +67,6 @@ if [[ "${REPRO_STEP_MARKERS:-0}" == "1" ]]; then
     export XLA_FLAGS="${XLA_FLAGS:-} --xla_step_marker_location=1"
 fi
 
-if [[ "${1:-}" == "--bench-smoke" ]]; then
-    shift
-    PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m \
-        benchmarks.bench_round_e2e --smoke --out BENCH_round_e2e.json "$@"
-    python - <<'EOF'
-import json
-acc = json.load(open("BENCH_round_e2e.json"))["acceptance"]
-print("round_e2e acceptance:", json.dumps(acc, indent=1))
-# Perf gates (not just recordings): the headline C=512 factored round must
-# stay within the recorded budget, and the lift-free delta-context round
-# must be no slower than the transient-lift oracle at the compute-bound
-# cohort shape.
-assert acc["cohort_cmax_within_budget"], (
-    f"C={acc['cohort_cmax']} factored round regressed: "
-    f"{acc['cohort_cmax_round_s']:.2f}s > "
-    f"budget {acc['cohort_cmax_round_s_budget']:.2f}s")
-assert acc["liftfree_speedup_cmax"] >= 1.0, (
-    f"lift-free round slower than transient-lift at C={acc['cohort_cmax']}: "
-    f"{acc['liftfree_speedup_cmax']:.2f}x")
-EOF
-    exit 0
-fi
-
 if [[ "${1:-}" == "--sync-smoke" ]]; then
     shift
     # Sync parity subset: bucketed 𝒮 ≡ per-leaf, pipelined ≡ sequential,
@@ -113,31 +83,6 @@ if [[ "${1:-}" == "--sync-smoke" ]]; then
     PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m pytest -x -q \
         ${COV_ARGS[@]+"${COV_ARGS[@]}"} \
         tests/test_sync_batched.py tests/test_batched_eigh.py
-    PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m \
-        benchmarks.bench_round_e2e --smoke --no-runtime \
-        --out BENCH_round_e2e.json "$@"
-    python - <<'EOF'
-import json
-acc = json.load(open("BENCH_round_e2e.json"))["acceptance"]
-keys = {k: acc[k] for k in ("sync_stage_clients", "sync_stage_s",
-                            "sync_stage_budget_s",
-                            "sync_stage_within_budget",
-                            "pipeline_speedup_by_clients",
-                            "pipelined_ge_sequential")}
-print("sync acceptance:", json.dumps(keys, indent=1))
-# Perf gates: the batched-bucket 𝒮 stage stays within its budget at the
-# breakdown cohort, and the pipelined K-round scan is no slower than the
-# sequential oracle (up to the recorded scheduler-noise tolerance) at
-# every cohort size.
-assert acc["sync_stage_within_budget"], (
-    f"S stage at C={acc['sync_stage_clients']} over budget: "
-    f"{acc['sync_stage_s'] * 1e3:.2f}ms > "
-    f"{acc['sync_stage_budget_s'] * 1e3:.0f}ms")
-assert acc["pipelined_ge_sequential"], (
-    "pipelined scan slower than sequential beyond the "
-    f"{acc['pipeline_noise_tol']:.2f}x noise tolerance: "
-    f"{json.dumps(acc['pipeline_speedup_by_clients'])}")
-EOF
     exit 0
 fi
 
@@ -192,9 +137,8 @@ print("robust acceptance:", json.dumps(acc, indent=1))
 # to the unguarded round (engine AND sharded runtime), every NaN-adversary
 # run under a defense must stay finite end-to-end, for each attack the best
 # defended cell must stay within the degradation bound while the undefended
-# cell degrades strictly more (or diverges), the hetero-basis (svd-refresh)
-# defended runs must track their shared-basis twins, and the quarantined
-# pipelined scan must be no slower than the sequential oracle.
+# cell degrades strictly more (or diverges), and the hetero-basis
+# (svd-refresh) defended runs must track their shared-basis twins.
 assert acc["attacks_landed"], "adversary plans drew zero corrupted clients"
 assert acc["honest_bit_identity"], "honest guarded round != unguarded round"
 assert acc["nan_quarantined"], "NaN adversary leaked past the quarantine"
@@ -207,10 +151,6 @@ assert acc["hetero_attack_parity"], (
     "hetero-basis defended runs diverged from shared-basis twins: "
     f"{json.dumps(acc['hetero_parity_rel'])} vs bound "
     f"{acc['hetero_bound']}")
-assert acc["quarantine_pipelined_ge_sequential"], (
-    "quarantined pipelined scan slower than sequential beyond the "
-    f"{acc['pipe_noise_tol']:.2f}x noise tolerance: "
-    f"{json.dumps(acc['quarantine_pipeline'])}")
 EOF
     exit 0
 fi

@@ -1,6 +1,6 @@
 """Federated fine-tuning engine — 𝒯 / 𝒜 / 𝒮 composition (paper §3, Alg. 1).
 
-This is the *reference* engine used by tests and the paper-table benchmarks:
+The one-host engine the benchmark cells and the paper-table benchmarks run:
 clients are vectorized with ``jax.vmap`` over a leading client axis (the same
 mapping the production runtime realizes as a mesh axis), local steps run under
 ``jax.lax.scan``, and each method is a (trainable-kind, optimizer,
@@ -42,23 +42,24 @@ coordinates: shared-basis rounds run the factored protocols, and the adaptive
 round-0 diverged-basis case runs the heterogeneous-basis factored sync (r×r
 transfer Grams — no dense ``(C, m, n)`` lift anywhere).
 :meth:`FedEngine.run_rounds` additionally drives K rounds as a single
-``lax.scan`` dispatch for benchmark sweeps. ``FedConfig.factored_clients=
-False`` keeps the fused round on dense per-client weight stacks;
-``fused_round=False`` (or ``factored_sync=False``) restores the eager
-stage-by-stage reference round — the dense-buffer parity oracle.
+``lax.scan`` dispatch for benchmark sweeps. Methods whose trainable is not
+all GaLore target blocks (the LoRA and dense baselines) run the same fused
+round on dense per-client weight stacks. The eager stage-by-stage round with
+dense per-client weights and the dense-lift 𝒮 is not a mode of this engine:
+it is ``core.reference.run_round_eager``, which the tests hold this engine
+to.
 
-Memory model of the default factored round: **lift-free end to end**
-(``FedConfig.lift_free``). The local step never reads a dense per-leaf
-weight: target leaves enter the loss as ``models.layers.LowRankDelta`` nodes
+Memory model of the factored round: **lift-free end to end**. The local
+step never reads a dense per-leaf weight: target leaves enter the loss as ``models.layers.LowRankDelta`` nodes
 whose delta-aware matmul computes ``base_scale·(x@W) + split-matmul(R_i)``
 (O(t·r·(m+n)) on top of the base GEMM), and the custom VJP returns the
 cotangent for ``R_i`` already in rank-r coordinates — so the factored round
 executes **zero** O(m·n·r) lift GEMMs and **zero** dense m×n gradient
 cotangents for GaLore target leaves. Global-norm clipping stays exact via
-the VJP's dense-norm probes. The transient-lift read (``lift_free=False`` —
-materialize ``base_scale·W + lift(R_i)`` per leaf per step, dense AD, then
-re-project) survives as the parity oracle, and is still what the adaptive
-round 0 runs (a ``lax.cond``): its data-driven RSVD refresh needs the dense
+the VJP's dense-norm probes. The transient-lift read (materialize
+``base_scale·W + lift(R_i)`` per leaf per step, dense AD, then re-project;
+:meth:`FedEngine._local_train_factored_one`) is what the adaptive round 0
+runs (a ``lax.cond``): its data-driven RSVD refresh needs the dense
 per-client gradient that the lift-free path never builds."""
 from __future__ import annotations
 
@@ -130,34 +131,16 @@ class FedConfig:
     eps: float = 1e-8
     seed: int = 0
     reset_opt_each_round: bool = True  # 𝒮 'none' => reinit each round
-    # Fast paths (see galore / state_sync module docstrings). factored_sync
-    # synchronizes in projected coordinates — shared-basis rounds via the
-    # seeded-broadcast invariant, the adaptive round 0 via the heterogeneous-
-    # basis r×r transfer Grams; False restores the dense per-client lift
-    # (the parity oracle). fused_round compiles InitState + T local steps +
-    # 𝒜 + 𝒮 as one buffer-donated program per round; False runs the eager
-    # stage-by-stage reference round (requires factored_sync=False to also
-    # exercise the dense 𝒮 oracle).
-    fused: bool = True
+    # The round's reference forms (eager stages, dense per-client weights,
+    # dense-lift 𝒮) are functions in core.reference that tests call; no
+    # field here selects them.
+    # The GaLore step's kernel route (core.galore): None = auto
+    # (kernels.ops.use_kernels), True/False force the Pallas kernel on/off.
     use_pallas: Optional[bool] = None
-    factored_sync: bool = True
-    fused_round: bool = True
-    # Client memory model of the fused round (module docstring). With
-    # factored_clients (GaLore methods only) clients persist rank-r
-    # accumulators instead of dense weight copies; False keeps the dense
-    # stacked round (the in-fused-path oracle). client_chunk=B streams the
-    # cohort through the round in C/B chunks (B must divide C; None = one
-    # chunk), bounding the dense transient working set by B clients.
-    factored_clients: bool = True
+    # client_chunk=B streams the cohort through the fused round in C/B chunks
+    # (B must divide C; None = one chunk), bounding the dense transient
+    # working set by B clients (module docstring).
     client_chunk: Optional[int] = None
-    # Lift-free factored local steps (module docstring): the delta-aware
-    # forward + projected-cotangent backward replace the per-leaf transient
-    # lift and the dense gradient. Effective when the factored client model
-    # is active (all trainable leaves are target blocks); the adaptive
-    # round 0 stays on the transient-lift read via a lax.cond (its RSVD
-    # refresh needs dense gradients). False keeps PR 4's transient-lift
-    # read everywhere — the lift-free parity oracle.
-    lift_free: bool = True
     # Planet-scale participation (core.population module docstring): seeded
     # per-round cohort sampling out of a large virtual client population,
     # plus per-client dropout and straggler-delay fault injection. The
@@ -199,20 +182,6 @@ class FedConfig:
     robust_trim: float = 0.2
     robust_iters: int = 8
     robust_tol: float = 1e-6
-    # 𝒮 execution shape (state_sync / ajive module docstrings). bucketed_sync
-    # groups shape-identical leaves into one vmapped sync program per bucket
-    # (batched r×r eigh, kernel-routed on TPU); False keeps the per-leaf loop
-    # as the parity oracle. pipeline_sync makes the scan-over-rounds drivers
-    # one-round-deep software pipelines: round k's 𝒮 is deferred into round
-    # k+1's body (where it only gates the first optimizer-moment read, so it
-    # overlaps the gradient work of the next local phase) with an epilogue
-    # sync after the scan — numerically the SAME program as the sequential
-    # schedule (each round still consumes exactly round k-1's synced
-    # moments), re-associated for overlap; False keeps the strictly
-    # sequential scan body as the timing/parity oracle. Single-round
-    # :meth:`FedEngine.run_round` dispatches are always sequential.
-    bucketed_sync: bool = True
-    pipeline_sync: bool = True
 
 
 # ------------------------------------------------------------ trainables ----
@@ -281,8 +250,7 @@ class FedEngine:
         self.galore_cfg = gal.GaloreConfig(
             rank=cfg.rank, refresh_every=10 ** 9,   # engine refreshes manually
             adaptive_steps=cfg.adaptive_refreshes, b1=cfg.b1, b2=cfg.b2,
-            eps=cfg.eps, refresh_mode="auto", fused=cfg.fused,
-            use_pallas=cfg.use_pallas)
+            eps=cfg.eps, refresh_mode="auto", use_pallas=cfg.use_pallas)
         self.tx = self._make_tx()
         # Client axes for the optimizer state: moments/bases are per-client
         # (axis 0); the GaLore step counter and round seed stay UNBATCHED —
@@ -291,24 +259,21 @@ class FedEngine:
         # under vmap (a batched predicate would lower to a select that
         # computes the RSVD branch every local step).
         self._opt_axes = self._client_opt_axes()
-        self._local_train = jax.jit(jax.vmap(
-            self._local_train_one, in_axes=(0, self._opt_axes, 0, None),
-            out_axes=(0, self._opt_axes, 0)))
         self.round_idx = 0
         self.synced_v = None   # lifted+projected ṽ init from 𝒮
         # Factored-delta clients (module docstring): GaLore methods whose
         # trainable is entirely target blocks carry rank-r accumulators
         # instead of dense per-client weight copies in the fused round.
         self._factored = False
-        if cfg.factored_clients and self.spec.optimizer == "galore_adamw":
+        if self.spec.optimizer == "galore_adamw":
             st_shape = jax.eval_shape(
                 lambda: self.tx.init(self.global_trainable))
             self._factored = gal.all_blocks_projected(
                 gal.galore_state_of(st_shape))
-        # Lift-free delta-context local steps: default on whenever the
-        # factored client model is (all blocks projected); lift_free=False
-        # keeps the transient-lift read as the parity oracle.
-        self._lift_free = bool(cfg.lift_free) and self._factored
+        # Lift-free delta-context local steps whenever the factored client
+        # model is (all blocks projected); the adaptive round 0 still takes
+        # the transient-lift read inside the round (see _round_core).
+        self._lift_free = self._factored
         # Whole-round fused program state: the persistent client buffers —
         # factored (C, ·, r) accumulators or dense (C, m, n) stacks — are
         # donated back into every round call (their memory is reused for
@@ -337,8 +302,9 @@ class FedEngine:
         if self._guard_cfg and not self._factored:
             raise ValueError(
                 "quarantine/robust_agg need the factored client model "
-                "(GaLore methods with factored_clients=True) — the screen "
-                "and the robust reductions run on rank-r factored stacks")
+                "(GaLore methods whose trainables are all target blocks) — "
+                "the screen and the robust reductions run on rank-r "
+                "factored stacks")
         self._round_guard_jit = None
         self._rounds_scan_guard_jit = None
         # Lazy zero (dim, r) basis-shape donor for the pipelined scans'
@@ -415,15 +381,6 @@ class FedEngine:
         unbatched-count/seed layout of :meth:`_client_opt_axes`."""
         return gal.stack_opt_state(st, n_clients)
 
-    def _init_client_opt_states(self, n_clients: int):
-        """Round-start InitState for all clients. States are identical by
-        construction (the round-boundary refresh is the seeded broadcast), so
-        one state is built — with the bucketed ``manual_refresh``, one vmapped
-        refresh per shape bucket — and broadcast along the client axis."""
-        st = self._init_state0(self.round_idx, self.synced_v,
-                               self.global_trainable)
-        return self._stack_opt_state(st, n_clients)
-
     # ------------------------------------------------------------ a round ---
     def _normalize_weights(self, weights, k_clients):
         return sync_lib.normalize_weights(weights, k_clients)
@@ -470,19 +427,17 @@ class FedEngine:
                   attack=None):
         """client_batches: pytree with leading axes (K clients, T steps, ...).
 
-        Returns dict of metrics. Mutates engine global state. Default: the
-        whole-round fused program (one dispatch, donated client buffers);
-        ``fused_round=False`` or ``factored_sync=False`` runs the eager
-        stage-by-stage reference round.
+        Returns dict of metrics. Mutates engine global state. The round is
+        the whole-round fused program (one dispatch, donated client
+        buffers); its stage-by-stage reference is
+        ``core.reference.run_round_eager``.
 
         ``mask`` (optional bool (K,)) marks this round's on-time
         participants: masked-out clients still occupy their compiled cohort
         slot (shapes never change) but carry zero effective weight in 𝒜 and
         are excluded from the AJIVE joint basis in 𝒮. A full-participation
         mask short-circuits onto the unmasked program — bit-identical to
-        calling without a mask. The eager reference round applies the
-        weight masking only (no score exclusion — it predates the
-        participation layer and stays the unmasked oracle).
+        calling without a mask.
 
         ``attack`` (optional float (K,)) injects per-client uplink
         corruption INSIDE the compiled round: each client's factored
@@ -502,29 +457,21 @@ class FedEngine:
                 mask = self._canon_mask(mask, k_clients)
                 attack = self._canon_attack(attack, k_clients)
                 guarded = self._guard_cfg or attack is not None
-                fused = self.cfg.fused_round and self.cfg.factored_sync
-                if guarded and not fused:
-                    raise ValueError(
-                        "quarantine/robust_agg/attack injection require the "
-                        "fused factored round (fused_round + factored_sync)")
                 w = (self._normalize_weights(weights, k_clients)
                      if mask is None
                      else self._masked_weights(weights, mask, k_clients))
-                if fused:
-                    extra = ()
-                    if guarded:
-                        round_fn = self._round_guard_jitted()
-                        a = (np.ones((k_clients,), np.float32)
-                             if attack is None else attack)
-                        extra = (jnp.asarray(a, jnp.float32),)
-                    elif mask is None:
-                        round_fn = self._round_jitted()
-                    else:
-                        round_fn = self._round_masked_jitted()
-                    self._ensure_client_buffers(k_clients)
-                    round_idx = jnp.asarray(self.round_idx, jnp.int32)
-            if not fused:
-                return self._run_round_eager(client_batches, w, k_clients)
+                extra = ()
+                if guarded:
+                    round_fn = self._round_guard_jitted()
+                    a = (np.ones((k_clients,), np.float32)
+                         if attack is None else attack)
+                    extra = (jnp.asarray(a, jnp.float32),)
+                elif mask is None:
+                    round_fn = self._round_jitted()
+                else:
+                    round_fn = self._round_masked_jitted()
+                self._ensure_client_buffers(k_clients)
+                round_idx = jnp.asarray(self.round_idx, jnp.int32)
             with _span("fed.round.dispatch"):
                 out = round_fn(
                     self._client_state, self._client_opt,
@@ -559,9 +506,7 @@ class FedEngine:
 
         round_batches: pytree with leading (K rounds, C clients, T steps, ...)
         axes. Returns dict with ``local_loss`` of shape (K, C, T). Mutates
-        engine global state exactly as K successive :meth:`run_round` calls
-        (modulo the eager round-0 dense-𝒮 oracle, replaced by the
-        heterogeneous-basis factored sync).
+        engine global state exactly as K successive :meth:`run_round` calls.
 
         ``masks`` (optional bool (K rounds, C)) applies a per-round
         participation mask: the per-round effective weights are renormalized
@@ -582,19 +527,6 @@ class FedEngine:
                                  f"({k_rounds}, {k_clients})")
             if masks.all():
                 masks = None
-        if not (self.cfg.fused_round and self.cfg.factored_sync):
-            # Honor the eager/oracle configuration: K sequential reference
-            # rounds (keeps dense-𝒮 oracle comparisons driven through
-            # run_rounds honest instead of silently going factored).
-            losses = jnp.stack([
-                self.run_round(
-                    jax.tree_util.tree_map(lambda x, r=r: x[r],
-                                           round_batches),
-                    weights,
-                    None if masks is None else masks[r])["local_loss"]
-                for r in range(int(k_rounds))])
-            return {"local_loss": losses,
-                    "mean_final_loss": float(jnp.mean(losses[-1, :, -1]))}
         # Attack injection is not expressible inside the scan driver (a
         # per-round attack would ride the xs, but corruption plans come from
         # PopulationRunner, which drives sequential rounds anyway) — the
@@ -651,7 +583,7 @@ class FedEngine:
         every round through the quarantine/robust-𝒜 program (unit attack —
         per-round injected attacks don't ride the scan).
 
-        ``pipelined`` (``FedConfig.pipeline_sync`` with a syncing method) is
+        ``pipelined`` (every method that syncs, :meth:`_method_syncs`) is
         the one-round-deep software pipeline: every round *defers* its 𝒮
         install by returning the slim pending payload ``(tree, w_eff)``
         (:meth:`_slim_payload` — protocol-aware: the weighted-mean
@@ -670,9 +602,8 @@ class FedEngine:
         and parks the small synced tree in a carried slot instead. Both
         schedules run as one uniform scan of the same length (splitting
         rounds across scans of different lengths changes XLA's loop
-        compilation and costs bit-parity with the oracle). The sequential
-        body survives under ``pipeline_sync=False`` as the timing/parity
-        oracle."""
+        compilation). Methods without 𝒮 take the sequential body. Tests
+        hold both schedules to K successive :meth:`run_round` calls."""
         frozen_mutates = self._frozen_mutates()
         # Robust-𝒮 rides the guarded program only: the deferred 𝒮 drains
         # (and the hetero0 inline sync) must reduce the projected-moment
@@ -724,9 +655,9 @@ class FedEngine:
 
             # One uniform scan for the pipelined schedule too: the bodies
             # differ from seq_body only around 𝒮, so the local phases
-            # compile in-loop exactly as the sequential oracle's do
+            # compile in-loop exactly as the sequential body's do
             # (splitting rounds across scans of different lengths changes
-            # XLA's loop compilation and costs bit-parity).
+            # XLA's loop compilation).
             # hetero0: the call's first round may hold heterogeneous bases
             # (adaptive refresh) AND the payload defers per-client stacks
             # whose drain is shared-basis-only — that round must sync
@@ -847,28 +778,23 @@ class FedEngine:
                     v, is_leaf=lambda x: x is None),
                 w0)
 
-    def _pipeline_rounds(self) -> bool:
-        """Pipelined scan drivers apply when the method syncs at all and the
-        config keeps the (default) pipelined schedule."""
-        return self.cfg.pipeline_sync and self._method_syncs()
-
     def _rounds_scan_jitted(self):
         if self._rounds_scan_jit is None:
             self._rounds_scan_jit = self._build_rounds_scan(
-                exclude_zero=False, pipelined=self._pipeline_rounds())
+                exclude_zero=False, pipelined=self._method_syncs())
         return self._rounds_scan_jit
 
     def _rounds_scan_masked_jitted(self):
         if self._rounds_scan_masked_jit is None:
             self._rounds_scan_masked_jit = self._build_rounds_scan(
-                exclude_zero=True, pipelined=self._pipeline_rounds())
+                exclude_zero=True, pipelined=self._method_syncs())
         return self._rounds_scan_masked_jit
 
     def _rounds_scan_guard_jitted(self):
         if self._rounds_scan_guard_jit is None:
             self._rounds_scan_guard_jit = self._build_rounds_scan(
                 exclude_zero=True, guard=True,
-                pipelined=self._pipeline_rounds())
+                pipelined=self._method_syncs())
         return self._rounds_scan_guard_jit
 
     # ------------------------------------------------- fused round program --
@@ -887,8 +813,8 @@ class FedEngine:
         """Allocate the persistent client buffers once; every fused round
         donates them back and adopts the round's outputs. Factored clients
         persist the rank-r (C, ·, r) accumulator stacks (O(C·r(m+n)) bytes);
-        the dense (C, m, n) weight stacks survive only under
-        ``factored_clients=False``."""
+        the LoRA and dense baselines persist their (C, ·) trainable
+        stacks."""
         have = (self._client_state is not None
                 and jax.tree_util.tree_leaves(
                     self._client_state)[0].shape[0] == k_clients)
@@ -960,8 +886,8 @@ class FedEngine:
         (galore.factored_adamw_step on a LiftFreeGrads bundle). The in-step
         refresh is hoisted before the forward (galore.maybe_refresh_instep)
         so cotangents arrive on the refreshed basis — seeded-random only,
-        which is why the adaptive round 0 runs the transient oracle
-        instead."""
+        which is why the adaptive round 0 runs the transient-lift read
+        (:meth:`_local_train_factored_one`) instead."""
         c = self.cfg
 
         def step(carry, batch):
@@ -984,7 +910,7 @@ class FedEngine:
     def _round0_adaptive(self) -> bool:
         """Whether round 0's in-step refresh is data-driven (RSVD of each
         client's own dense gradient) — the one case the lift-free read
-        cannot serve and the transient-lift oracle handles via lax.cond."""
+        cannot serve and the transient-lift read handles via lax.cond."""
         return (self.galore_cfg.adaptive_steps > 0
                 and self.galore_cfg.refresh_mode != "random")
 
@@ -1155,9 +1081,7 @@ class FedEngine:
                 return vmapped(self._local_train_liftfree_one)(
                     deltas0, opt0, batch_c, frozen, global_trainable)
 
-            if not self._lift_free:
-                local_fn = transient_fn
-            elif self._round0_adaptive():
+            if self._round0_adaptive():
                 # Round 0's data-driven refresh needs dense gradients; every
                 # later round runs lift-free. Same output pytree both ways.
                 def local_fn(batch_c):
@@ -1276,24 +1200,6 @@ class FedEngine:
                 exclude_zero=True, guard=True)
         return self._round_guard_jit
 
-    def _run_round_eager(self, client_batches, w, k_clients):
-        """Stage-by-stage reference round (the parity oracle): separately
-        dispatched InitState, jitted local training, eager 𝒜 and 𝒮."""
-        stacked_trainable = jax.tree_util.tree_map(
-            lambda x: jnp.broadcast_to(x, (k_clients,) + x.shape),
-            self.global_trainable)
-        opt_states = self._init_client_opt_states(k_clients)
-
-        out_trainable, out_opt, losses = self._local_train(
-            stacked_trainable, opt_states, client_batches, self.frozen)
-
-        self.global_trainable, self.frozen = self._aggregate_pure(
-            out_trainable, w, self.frozen, self.round_idx)
-        self.synced_v = self._sync_states_eager(out_opt, w)
-        self.round_idx += 1
-        return {"local_loss": losses,                      # (K, T)
-                "mean_final_loss": float(jnp.mean(losses[:, -1]))}
-
     # -------------------------------------------------------------- 𝒜 -------
     @jax.named_scope("fed.aggregate")
     def _aggregate_pure(self, stacked, w, frozen, round_idx):
@@ -1344,23 +1250,6 @@ class FedEngine:
                                        self.cfg.rank)
 
     # -------------------------------------------------------------- 𝒮 -------
-    def _bases_shared(self) -> bool:
-        """Whether every client ended the round on the identical basis.
-
-        The only in-step refresh the engine permits fires at count == 0
-        (round 0, refresh_every is effectively ∞); with adaptive refreshes
-        enabled that refresh is data-driven from each client's *own* gradient,
-        so round-0 bases are client-specific and 𝒮 must account for the
-        per-client basis (heterogeneous factored sync; dense per-client lift
-        in the eager oracle). From round 1 on, every refresh is the seeded-
-        random broadcast (manual_refresh with grads=None) — bases are
-        bit-identical across clients and the shared factored path applies.
-        """
-        round0_adaptive = (self.round_idx == 0
-                           and self.galore_cfg.adaptive_steps > 0
-                           and self.galore_cfg.refresh_mode != "random")
-        return not round0_adaptive
-
     def _sync_uplink(self, stacked_opt_states):
         """The 𝒮 input payload of a round: (projected-ṽ tree, basis tree)
         extracted from the client-stacked optimizer states — O(C·r·dim),
@@ -1438,11 +1327,10 @@ class FedEngine:
             v_tree, self._basis_template(), w, None, exclude_zero,
             shared_only=True, robust=robust)
 
-    def _sync_blocks(self, v_stack_tree, basis_tree, block_fn,
-                     bucketed: bool = False):
+    def _sync_blocks(self, v_stack_tree, basis_tree, block_fn):
         """Map ``block_fn(v_stack, b_stack, side, rank)`` over the adapted
-        blocks; ``bucketed`` groups shape-identical leaves into one vmapped
-        program per bucket (`state_sync.map_sync_leaves`)."""
+        blocks, shape-identical leaves as one vmapped program per bucket
+        (`state_sync.map_sync_leaves`)."""
         vs, treedef = jax.tree_util.tree_flatten(v_stack_tree,
                                                  is_leaf=lambda x: x is None)
         bs = jax.tree_util.tree_leaves(basis_tree, is_leaf=lambda x: x is None)
@@ -1452,7 +1340,7 @@ class FedEngine:
             side = proj.RIGHT if v_stack.shape[-1] == rank else proj.LEFT
             return block_fn(v_stack, b_stack, side, rank)
 
-        synced = sync_lib.map_sync_leaves(leaf_fn, vs, bs, bucketed=bucketed)
+        synced = sync_lib.map_sync_leaves(leaf_fn, vs, bs)
         return jax.tree_util.tree_unflatten(treedef, synced)
 
     @jax.named_scope("fed.sync")
@@ -1514,46 +1402,7 @@ class FedEngine:
                 return shared(None)
             return jax.lax.cond(round_idx == 0, hetero, shared, operand=None)
 
-        return self._sync_blocks(v_stack_tree, basis_tree, sync_block,
-                                 bucketed=self.cfg.bucketed_sync)
-
-    def _sync_states_eager(self, stacked_opt_states, w):
-        """Reference 𝒮 for the eager round: the factored shared-basis path
-        when it applies, otherwise (adaptive round 0, or factored_sync=False)
-        the dense per-client lift — the retained parity oracle for the
-        heterogeneous factored sync."""
-        if not self._method_syncs():
-            return None
-        protocol = self.spec.state_sync
-        use_factored = self.cfg.factored_sync and self._bases_shared()
-
-        def sync_block(v_stack, b_stack, side, rank):
-            if use_factored:
-                return sync_lib.sync_block_synced_factored(
-                    protocol, v_stack, side, w, rank)
-
-            def sync_one(v_cl, b_cl):
-                # v_cl (K, m, r)|(K, r, n); b_cl (K, dim, r). Lift each
-                # client's ṽ with its *own* basis (identical across clients
-                # in the seeded-random phase), synchronize, re-project onto
-                # the shared (client-0) end-of-round basis.
-                if side == proj.RIGHT:
-                    views = jnp.einsum("kmr,knr->kmn",
-                                       v_cl.astype(jnp.float32),
-                                       b_cl.astype(jnp.float32))
-                else:
-                    views = jnp.einsum("kmr,krn->kmn",
-                                       b_cl.astype(jnp.float32),
-                                       v_cl.astype(jnp.float32))
-                lifted = sync_lib.sync_lifted_views(protocol, views, w, rank)
-                return sync_lib.project_state(lifted, b_cl[0], side)
-
-            if v_stack.ndim == 4:        # stacked scan blocks (K, nb, ., r)
-                return jax.vmap(sync_one, in_axes=(1, 1))(v_stack, b_stack)
-            return sync_one(v_stack, b_stack)
-
-        v_tree, b_tree = self._sync_uplink(stacked_opt_states)
-        return self._sync_blocks(v_tree, b_tree, sync_block)
+        return self._sync_blocks(v_stack_tree, basis_tree, sync_block)
 
     # ------------------------------------------------------------- helpers --
     def global_params(self) -> PyTree:

@@ -31,11 +31,14 @@ to the fused Pallas kernel (``kernels.galore_adamw.galore_precond_step``) —
 one VMEM-resident pass with no dense HBM round-trips between optimizer
 stages. On CPU/GPU-jnp the cheap GEMM+Adam chain stays per leaf (reading each
 dense gradient exactly once beats a stack/unstack round-trip) and XLA fuses
-the projected-space elementwise chain. ``GaloreConfig.fused=False`` selects
-the original per-leaf reference loop, retained as the parity oracle;
-``GaloreConfig.use_pallas`` forces the kernel on/off (None = auto:
-``kernels.ops.use_kernels`` — on CPU the kernel still runs, in interpret
-mode, when forced on, which is what the parity tests use).
+the projected-space elementwise chain. ``GaloreConfig.use_pallas`` forces
+the kernel on/off (None = auto: ``kernels.ops.use_kernels`` — on CPU the
+kernel still runs, in interpret mode, when forced on, which is what the
+parity tests use).
+
+The per-leaf loops the bucketed paths replaced stay as plain reference
+functions that tests call: :func:`galore_transform_update_per_leaf` (the
+step) and :func:`manual_refresh_per_leaf` (the round-boundary refresh).
 """
 from __future__ import annotations
 
@@ -90,11 +93,9 @@ class GaloreConfig:
     # 'svd': only the data-driven branch (warmup-phase step function)
     refresh_mode: str = "auto"
     bias_correction: bool = True
-    # Fused/bucketed execution (see module docstring). fused=False restores
-    # the per-leaf reference loop (the parity oracle). use_pallas: None = auto
+    # The bucketed step's kernel route (module docstring): None = auto
     # (kernels.ops.use_kernels); True forces the kernel (interpret mode
     # off-TPU).
-    fused: bool = True
     use_pallas: Optional[bool] = None
     pallas_block_rows: int = 128
 
@@ -180,7 +181,7 @@ def _refresh_basis(cfg: GaloreConfig, g32, old: GaloreBlockState,
 def _projected_adam(cfg: GaloreConfig, gt, m, v, count):
     """The shared projected-space Adam chain: moment EMAs + (optionally
     bias-corrected) update direction. Single source of truth for both the
-    per-leaf reference loop and the bucketed fused path."""
+    bucketed step and its per-leaf reference."""
     m = cfg.b1 * m + (1 - cfg.b1) * gt
     v = cfg.b2 * v + (1 - cfg.b2) * gt * gt
     if cfg.bias_correction:
@@ -226,14 +227,14 @@ def _resolve_use_pallas(cfg: GaloreConfig) -> bool:
 def _bucketed_update(cfg: GaloreConfig, use_pallas: bool, g_leaves,
                      blk_leaves, count, refresh_idx, do_refresh, seed,
                      project_back: bool = True):
-    """Shape-bucketed batched GaLore step (the fused default).
+    """Shape-bucketed batched GaLore step.
 
     Target blocks with identical (shape, rank) share one stacked state bucket:
     the refresh (QR/RSVD + mode cond — the dominant trace cost) is emitted
     once per bucket, vmapped, and the Pallas kernel path consumes the whole
     bucket in one batched call. Per-block seeded keys fold in the *original*
-    leaf index, so every basis is bit-identical to the per-leaf reference loop
-    (the server-broadcast-a-seed protocol is unaffected by bucketing).
+    leaf index, so every basis is the one :func:`galore_transform_update_per_leaf`
+    draws (the server-broadcast-a-seed protocol is unaffected by bucketing).
     ``project_back=False`` keeps the update in projected coordinates (ũ,
     shaped like the moments) — the factored-delta client path, where the
     ambient lift is deferred to the weight read.
@@ -334,18 +335,43 @@ def _bucketed_update(cfg: GaloreConfig, use_pallas: bool, g_leaves,
     return updates, new_blocks
 
 
+def _is_block(x) -> bool:
+    return isinstance(x, (GaloreBlockState, DenseMoments))
+
+
+def _step_operands(cfg: GaloreConfig, grads, state: GaloreState):
+    """The shared head of one GaLore step: the advanced count, the in-step
+    ``count % τ`` refresh predicate and index, and the flattened gradient
+    and block-state leaves."""
+    count = state.count + 1
+    refresh_idx = state.count // cfg.refresh_every
+    do_refresh = (state.count % cfg.refresh_every) == 0
+    leaves = jax.tree_util.tree_flatten_with_path(grads)[0]
+    treedef = jax.tree_util.tree_structure(grads)
+    blk_leaves = jax.tree_util.tree_leaves(state.blocks, is_leaf=_is_block)
+    return count, refresh_idx, do_refresh, leaves, treedef, blk_leaves
+
+
+def _step_result(state: GaloreState, count, treedef, updates, new_blocks):
+    return (jax.tree_util.tree_unflatten(treedef, updates),
+            GaloreState(count=count, seed=state.seed,
+                        blocks=jax.tree_util.tree_unflatten(treedef,
+                                                            new_blocks)))
+
+
 @jax.named_scope("galore.update")
 def galore_transform_update(cfg: GaloreConfig, grads, state: GaloreState,
                             project_back: bool = True,
                             projected: bool = False):
     """One GaLore preconditioning step as a pure function (the
     ``scale_by_galore`` update body): in-step ``count % τ`` refresh, projected
-    Adam moments, update direction. With the default ``project_back=True``
-    target-block updates are lifted back to ambient shape (the dense chain
-    API). ``project_back=False`` returns them as the *projected* ũ (shaped
-    like the moments) — the factored-delta client path, which keeps the whole
-    local step in rank-r coordinates and defers the lift to the weight read.
-    Non-target (``DenseMoments``) leaves are plain Adam either way.
+    Adam moments, update direction, shape-bucketed (module docstring). With
+    the default ``project_back=True`` target-block updates are lifted back to
+    ambient shape (the dense chain API). ``project_back=False`` returns them
+    as the *projected* ũ (shaped like the moments) — the factored-delta
+    client path, which keeps the whole local step in rank-r coordinates and
+    defers the lift to the weight read. Non-target (``DenseMoments``) leaves
+    are plain Adam either way.
 
     ``projected=True`` is the **lift-free** consumption mode: the incoming
     gradients are *already* in rank-r coordinates (the projected-cotangent
@@ -354,15 +380,8 @@ def galore_transform_update(cfg: GaloreConfig, grads, state: GaloreState,
     refresh (hoisted :func:`maybe_refresh_instep` before the forward, so the
     cotangents arrive on the refreshed basis); every leaf must be a target
     block (:func:`all_blocks_projected`)."""
-    count = state.count + 1
-    refresh_idx = state.count // cfg.refresh_every
-    do_refresh = (state.count % cfg.refresh_every) == 0
-
-    leaves = jax.tree_util.tree_flatten_with_path(grads)[0]
-    treedef = jax.tree_util.tree_structure(grads)
-    blk_leaves = jax.tree_util.tree_leaves(
-        state.blocks, is_leaf=lambda x: isinstance(x, (GaloreBlockState,
-                                                       DenseMoments)))
+    count, refresh_idx, do_refresh, leaves, treedef, blk_leaves = \
+        _step_operands(cfg, grads, state)
     if projected:
         updates, new_blocks = [], []
         for (path, g), st in zip(leaves, blk_leaves):
@@ -376,39 +395,41 @@ def galore_transform_update(cfg: GaloreConfig, grads, state: GaloreState,
             updates.append(proj.project_back(ut, st.basis, side)
                            if project_back else ut)
             new_blocks.append(GaloreBlockState(basis=st.basis, m=m, v=v))
-        return (jax.tree_util.tree_unflatten(treedef, updates),
-                GaloreState(count=count, seed=state.seed,
-                            blocks=jax.tree_util.tree_unflatten(treedef,
-                                                                new_blocks)))
-    if cfg.fused:
-        updates, new_blocks = _bucketed_update(
-            cfg, _resolve_use_pallas(cfg), [g for _, g in leaves],
-            blk_leaves, count, refresh_idx, do_refresh, state.seed,
-            project_back=project_back)
-    else:
-        updates, new_blocks = [], []
-        for block_id, ((path, g), st) in enumerate(zip(leaves,
-                                                       blk_leaves)):
-            if isinstance(st, GaloreBlockState):
-                u, nst = _block_update(cfg, g, st, count, refresh_idx,
-                                       do_refresh, state.seed, block_id,
-                                       project_back=project_back)
-            else:
-                u, nst = _dense_update(cfg, g, st, count)
-            updates.append(u)
-            new_blocks.append(nst)
-    return (jax.tree_util.tree_unflatten(treedef, updates),
-            GaloreState(count=count, seed=state.seed,
-                        blocks=jax.tree_util.tree_unflatten(treedef,
-                                                            new_blocks)))
+        return _step_result(state, count, treedef, updates, new_blocks)
+    updates, new_blocks = _bucketed_update(
+        cfg, _resolve_use_pallas(cfg), [g for _, g in leaves],
+        blk_leaves, count, refresh_idx, do_refresh, state.seed,
+        project_back=project_back)
+    return _step_result(state, count, treedef, updates, new_blocks)
+
+
+def galore_transform_update_per_leaf(cfg: GaloreConfig, grads,
+                                     state: GaloreState,
+                                     project_back: bool = True):
+    """Reference for :func:`galore_transform_update`: the same step as a
+    per-leaf loop — one refresh cond and one projected Adam chain per target
+    block, each block keyed by its own leaf index. Tests hold the bucketed
+    step to it."""
+    count, refresh_idx, do_refresh, leaves, treedef, blk_leaves = \
+        _step_operands(cfg, grads, state)
+    updates, new_blocks = [], []
+    for block_id, ((_, g), st) in enumerate(zip(leaves, blk_leaves)):
+        if isinstance(st, GaloreBlockState):
+            u, nst = _block_update(cfg, g, st, count, refresh_idx,
+                                   do_refresh, state.seed, block_id,
+                                   project_back=project_back)
+        else:
+            u, nst = _dense_update(cfg, g, st, count)
+        updates.append(u)
+        new_blocks.append(nst)
+    return _step_result(state, count, treedef, updates, new_blocks)
 
 
 def scale_by_galore(cfg: GaloreConfig,
                     target_fn: Callable = default_target_fn,
                     seed: int = 0) -> GradientTransformation:
     """GaLore preconditioning as a GradientTransformation (chain with weight
-    decay + lr like AdamW). ``cfg.fused`` selects the bucketed/fused default
-    path; ``fused=False`` runs the per-leaf reference loop (parity oracle)."""
+    decay + lr like AdamW), on the shape-bucketed step."""
 
     def init(params):
         return galore_init(cfg, params, target_fn, seed)
@@ -499,21 +520,11 @@ def _bucketed_manual_refresh(cfg: GaloreConfig, blk_leaves, grads_leaves,
     return out
 
 
-def manual_refresh(cfg: GaloreConfig, state: GaloreState, refresh_idx,
-                   grads: Optional[PyTree] = None) -> GaloreState:
-    """Refresh every block basis *now* (round-boundary refresh used by the
-    federated engine; the in-step ``count % τ`` path is used by the compiled
-    production train step).
-
-    Data-driven (RSVD/SVD of ``grads``) when ``grads`` is given and
-    ``refresh_idx < adaptive_steps``; seeded-random otherwise. With
-    ``grads=None`` (the engine's seeded-broadcast round boundary) the refresh
-    index may be a traced value, so the refresh is jit/scan-safe and the
-    fused round program can run it with a scanned round counter. The default
-    ``cfg.fused`` execution is shape-bucketed (one vmapped key-derivation +
-    QR + transfer per bucket); ``fused=False`` keeps the per-leaf reference
-    loop as the parity oracle.
-    """
+def _refresh_operands(cfg: GaloreConfig, state: GaloreState, refresh_idx,
+                      grads: Optional[PyTree]):
+    """The shared head of a round-boundary refresh: the gradient leaves a
+    data-driven refresh reads (None for the seeded-random one), the refresh
+    index as uint32, and the flattened block-state leaves."""
     grads_leaves = None
     if grads is not None:
         # Data-driven refreshes need a *concrete* refresh index (the round
@@ -524,16 +535,41 @@ def manual_refresh(cfg: GaloreConfig, state: GaloreState, refresh_idx,
         if adaptive:
             grads_leaves = jax.tree_util.tree_leaves(grads)
     refresh_idx = jnp.asarray(refresh_idx, jnp.uint32)
+    blk_leaves, treedef = jax.tree_util.tree_flatten(state.blocks,
+                                                     is_leaf=_is_block)
+    return grads_leaves, refresh_idx, blk_leaves, treedef
 
-    blk_leaves, treedef = jax.tree_util.tree_flatten(
-        state.blocks, is_leaf=lambda x: isinstance(x, (GaloreBlockState,
-                                                       DenseMoments)))
-    if cfg.fused:
-        out = _bucketed_manual_refresh(cfg, blk_leaves, grads_leaves,
-                                       refresh_idx, state.seed)
-        return GaloreState(count=state.count, seed=state.seed,
-                           blocks=jax.tree_util.tree_unflatten(treedef, out))
 
+def manual_refresh(cfg: GaloreConfig, state: GaloreState, refresh_idx,
+                   grads: Optional[PyTree] = None) -> GaloreState:
+    """Refresh every block basis *now* (round-boundary refresh used by the
+    federated engine; the in-step ``count % τ`` path is used by the compiled
+    production train step).
+
+    Data-driven (RSVD/SVD of ``grads``) when ``grads`` is given and
+    ``refresh_idx < adaptive_steps``; seeded-random otherwise. With
+    ``grads=None`` (the engine's seeded-broadcast round boundary) the refresh
+    index may be a traced value, so the refresh is jit/scan-safe and the
+    fused round program can run it with a scanned round counter. Execution
+    is shape-bucketed (one vmapped key-derivation + QR + transfer per
+    bucket); :func:`manual_refresh_per_leaf` is its reference.
+    """
+    grads_leaves, refresh_idx, blk_leaves, treedef = _refresh_operands(
+        cfg, state, refresh_idx, grads)
+    out = _bucketed_manual_refresh(cfg, blk_leaves, grads_leaves,
+                                   refresh_idx, state.seed)
+    return GaloreState(count=state.count, seed=state.seed,
+                       blocks=jax.tree_util.tree_unflatten(treedef, out))
+
+
+def manual_refresh_per_leaf(cfg: GaloreConfig, state: GaloreState,
+                            refresh_idx,
+                            grads: Optional[PyTree] = None) -> GaloreState:
+    """Reference for :func:`manual_refresh`: the same refresh as a per-leaf
+    loop (one key derivation, basis draw and transfer per target block).
+    Tests hold the bucketed refresh to it."""
+    grads_leaves, refresh_idx, blk_leaves, treedef = _refresh_operands(
+        cfg, state, refresh_idx, grads)
     out = []
     for block_id, st in enumerate(blk_leaves):
         if not isinstance(st, GaloreBlockState):
@@ -723,7 +759,7 @@ def factored_adamw_step(cfg: GaloreConfig, grads, opt_state, deltas,
     cotangents consumed with the ``Pᵀg`` projection skipped, and global-norm
     clipping driven by the exact dense-norm probes — same arithmetic as
     ``clip_by_global_norm`` on gradients that never materialized."""
-    from ..optim.base import ClipState, ScaleByLrState, global_norm
+    from ..optim.base import ScaleByLrState, global_norm
     if isinstance(opt_state, GaloreState):
         states = [opt_state]
     else:

@@ -556,9 +556,7 @@ class PopulationRunner:
     leading (C, T, ...) axes (e.g. ``lambda ids, r:
     batcher.round_batches(T, clients=list(ids))``).
 
-    Requires the fused factored round (``fused_round and factored_sync``) —
-    the harvest reads the engine's retained post-round client buffers, which
-    only the fused path keeps.
+    The harvest reads the engine's retained post-round client buffers.
 
     Defense-in-depth layers (all off by default):
 
@@ -587,10 +585,6 @@ class PopulationRunner:
                  snapshot_dir: Optional[str] = None, snapshot_every: int = 0,
                  snapshot_keep: int = 3, drift_tripwire: float = 0.0,
                  loss_tripwire: float = 0.0, tripwire_retries: int = 1):
-        if not (engine.cfg.fused_round and engine.cfg.factored_sync):
-            raise ValueError("PopulationRunner requires the fused factored "
-                             "round (it harvests the retained client "
-                             "buffers)")
         self.engine = engine
         self.batches_for = batches_for
         self.cohort = int(cohort)

@@ -30,8 +30,8 @@ still closes the round-trip over per-client r×r transfer Grams ``Q_iᵀ Q_0``
 — averaging picks up the transfer directly, rank-r SVD factors through the
 (C·r)×(C·r) Grams of the two skinny lift factors, and AJIVE composes the
 basis change into its score Gram (`ajive.ajive_sync_hetero_factored`). No
-default configuration executes a dense lift; :func:`sync_block` and the
-per-client dense lift remain as parity oracles.
+round program executes a dense lift; :func:`sync_block` and the per-client
+dense lift (`core.reference.dense_sync_block`) remain as references.
 
 Chunk-streamed rounds (``core.fed`` / ``launch.steps`` with ``client_chunk``)
 assemble the full (C, ·, r) ṽ/basis stacks from per-chunk outputs before
@@ -290,7 +290,7 @@ def sync_block_hetero_factored(protocol: str, v_stack: jnp.ndarray,
     raise ValueError(protocol)
 
 
-def map_sync_leaves(leaf_fn, v_leaves, b_leaves, bucketed: bool = True):
+def map_sync_leaves(leaf_fn, v_leaves, b_leaves):
     """Apply ``leaf_fn(v_stack, b_stack) -> synced`` over parallel per-leaf
     lists, one **vmapped program per shape bucket**.
 
@@ -301,9 +301,8 @@ def map_sync_leaves(leaf_fn, v_leaves, b_leaves, bucketed: bool = True):
     ``(v.shape, v.dtype, b.shape, b.dtype)`` — mirroring the PR-1 refresh
     bucketing (`galore.bucket_by_shape`) — stacks each bucket and emits the
     chain once under ``jax.vmap``, so the r×r eigendecompositions lower as
-    one batched solve (kernel-routed on TPU). On CPU the batched eigh is
-    bit-identical to the per-leaf loop, which survives under
-    ``bucketed=False`` as the parity oracle.
+    one batched solve (kernel-routed on TPU). :func:`map_sync_leaves_per_leaf`
+    is its reference.
 
     ``None`` v-leaves (non-adapted blocks) pass through as ``None``.
     ``leaf_fn`` must not return ``None`` (dispatch protocol='none' before
@@ -311,11 +310,6 @@ def map_sync_leaves(leaf_fn, v_leaves, b_leaves, bucketed: bool = True):
     """
     from .galore import bucket_by_shape
     out = [None] * len(v_leaves)
-    if not bucketed:
-        for i, (v, b) in enumerate(zip(v_leaves, b_leaves)):
-            if v is not None:
-                out[i] = leaf_fn(v, b)
-        return out
     keys = [None if v is None else
             (tuple(v.shape), str(v.dtype), tuple(b.shape), str(b.dtype))
             for v, b in zip(v_leaves, b_leaves)]
@@ -331,6 +325,13 @@ def map_sync_leaves(leaf_fn, v_leaves, b_leaves, bucketed: bool = True):
         for j, i in enumerate(idxs):
             out[i] = res[j]
     return out
+
+
+def map_sync_leaves_per_leaf(leaf_fn, v_leaves, b_leaves):
+    """Reference for :func:`map_sync_leaves`: ``leaf_fn`` on one leaf at a
+    time, ``None`` v-leaves passing through."""
+    return [None if v is None else leaf_fn(v, b)
+            for v, b in zip(v_leaves, b_leaves)]
 
 
 def sync_block_factored(protocol: str, v_stack: jnp.ndarray,

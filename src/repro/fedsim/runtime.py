@@ -11,55 +11,50 @@ out of the mesh onto the host, and the jitted call donates the stacked
 client buffers (global trainable + per-client optimizer states), so each
 round's outputs reuse the previous round's memory.
 
-Client memory model: with the default ``factored_clients=True`` a client's
-round state is the rank-r factored accumulator ``R_i`` around the shared
-global base, and with the default ``lift_free=True`` the local step is
-**lift-free**: target leaves flow into the model as delta-context nodes
-(``models.layers.LowRankDelta``) whose split-matmul apply and projected-
-cotangent VJP replace both the per-leaf ``base_scale·W + lift(R_i)``
-transient and the dense m×n gradient (``lift_free=False`` keeps the
-transient-lift read as the parity oracle; ``refresh_mode='svd'`` forces it —
-data-driven refreshes need dense gradients). Decoupled weight decay rides
-the scalar ``base_scale`` and 𝒜 collapses to ``base_scale·W + Σ wᵢ
-lift(Rᵢ)``, so no dense ``(C, m, n)`` per-client weight stack exists
-anywhere in the round program; per-client persistent state is O(r(m+n)) per
-block (the projected moments + basis). ``client_chunk=B``
-additionally streams the cohort through the round in C/B sequential chunks,
-bounding the dense forward/backward working set by B clients and decoupling
-cohort size from peak memory (C≈512 rounds on a single host). The stacked
-optimizer states ride the GaLore count/seed unbatched (``galore.
-stack_opt_state``), keeping the in-step refresh predicate scalar under the
-client vmap. ``factored_clients=False`` restores the dense per-client weight
-stacks (the parity oracle, and the required fallback when
-``refresh_every % local_steps != 0`` would let a mid-round refresh strand a
-non-zero accumulator on a stale basis).
+Client memory model (``launch.steps`` module docstring): when every in-step
+refresh lands on local step 0 (``refresh_every % local_steps == 0``) a
+client's round state is the rank-r factored accumulator ``R_i`` around the
+shared global base, and the local step is **lift-free**: target leaves flow
+into the model as delta-context nodes (``models.layers.LowRankDelta``) whose
+split-matmul apply and projected-cotangent VJP replace both the per-leaf
+``base_scale·W + lift(R_i)`` transient and the dense m×n gradient
+(``refresh_mode='svd'`` takes the transient-lift read — data-driven
+refreshes need dense gradients). Decoupled weight decay rides the scalar
+``base_scale`` and 𝒜 collapses to ``base_scale·W + Σ wᵢ lift(Rᵢ)``, so no
+dense ``(C, m, n)`` per-client weight stack exists anywhere in the round
+program; per-client persistent state is O(r(m+n)) per block (the projected
+moments + basis). ``client_chunk=B`` additionally streams the cohort
+through the round in C/B sequential chunks, bounding the dense
+forward/backward working set by B clients and decoupling cohort size from
+peak memory (C≈512 rounds on a single host). The stacked optimizer states
+ride the GaLore count/seed unbatched (``galore.stack_opt_state``), keeping
+the in-step refresh predicate scalar under the client vmap. Refresh
+schedules that could strand a non-zero accumulator on a stale basis keep
+dense per-client weight stacks.
 
-The server sync runs **factored** in every default configuration: the
-uplinked ṽ are synchronized directly in projected coordinates
-(`state_sync.sync_block_synced_factored` on the shared seeded basis;
-`state_sync.sync_block_hetero_factored` via r×r transfer Grams when
-data-driven refreshes diverge the bases, e.g. ``refresh_mode='svd'``) — no
-``(C, m, n)`` lifted view, ``(n, n)`` joint projector, or dense per-client
-broadcast is ever materialized. ``factored_sync=False`` restores the dense
-lift (the parity oracle), and ``fused_round=False`` restores the legacy
-jit-𝒯𝒜 + host-𝒮 round (the eager reference for benchmarks).
+The server sync runs **factored**: the uplinked ṽ are synchronized directly
+in projected coordinates (`state_sync.sync_block_synced_factored` on the
+shared seeded basis; `state_sync.sync_block_hetero_factored` via r×r
+transfer Grams when data-driven refreshes diverge the bases, e.g.
+``refresh_mode='svd'``) — no ``(C, m, n)`` lifted view, ``(n, n)`` joint
+projector, or dense per-client broadcast is ever materialized. The round
+with dense per-client weights and the dense-lift 𝒮 that tests hold this
+runtime to is ``core.reference.make_dense_round_step``.
 
 :meth:`ShardedFederation.run_rounds` drives K rounds as a single
-``lax.scan`` dispatch for benchmark sweeps. With the default
-``pipeline_sync=True`` (and a method that syncs) the scan runs the
-**one-round-deep pipelined schedule**: the body defers round k's 𝒮 to the
-top of round k+1's iteration (a raw ``state_sync=None`` round core returns
-the unsynced states, which ride the carry), and a post-scan drain runs the
-final round's 𝒮 so the returned states match the sequential schedule
-state-for-state. This is a pure re-association of the same round math —
-round k+1's first local update still consumes round-k *synced* moments, and
-the parity suite pins pipelined ≡ sequential bit-tight — but it lets XLA
-overlap the r×r sync chain with round k+1's independent gradient work
-instead of serializing 𝒮 between rounds. ``pipeline_sync=False`` keeps the
-strictly sequential scan as the oracle. Quarantined scans pipeline too: the
-raw round core returns its post-screen effective weights
-(``return_weights``), which ride the scan carry so the deferred 𝒮 reduces
-over exactly the clients the quarantine kept.
+``lax.scan`` dispatch for benchmark sweeps. When the method syncs, the scan
+runs the **one-round-deep pipelined schedule**: the body defers round k's 𝒮
+to the top of round k+1's iteration (a raw ``state_sync=None`` round core
+returns the unsynced states, which ride the carry), and a post-scan drain
+runs the final round's 𝒮 so the returned states match K successive
+:meth:`ShardedFederation.run_round` calls state-for-state. This is a pure
+re-association of the same round math — round k+1's first local update
+still consumes round-k *synced* moments — but it lets XLA overlap the r×r
+sync chain with round k+1's independent gradient work instead of
+serializing 𝒮 between rounds. Quarantined scans pipeline too: the raw round
+core returns its post-screen effective weights (``return_weights``), which
+ride the scan carry so the deferred 𝒮 reduces over exactly the clients the
+quarantine kept.
 
 This is the production counterpart of core.fed.FedEngine (which vmaps
 clients on a single host).
@@ -95,27 +90,18 @@ class ShardedFederation:
 
     def __init__(self, cfg: ArchConfig, spec: steps_lib.TrainSpec, mesh,
                  n_clients: int, state_sync: str = "ajive", seed: int = 0,
-                 factored_sync: bool = True, fused_round: bool = True,
-                 factored_clients: bool = True,
                  client_chunk: Optional[int] = None,
-                 lift_free: Optional[bool] = None,
                  participation: Optional[
                      pop_lib.ParticipationConfig] = None,
                  robust_agg: str = "none", quarantine: bool = False,
                  quarantine_zmax: float = 6.0, robust_trim: float = 0.2,
-                 robust_iters: int = 8, robust_tol: float = 1e-6,
-                 bucketed_sync: bool = True,
-                 pipeline_sync: bool = True):
+                 robust_iters: int = 8, robust_tol: float = 1e-6):
         self.cfg = cfg
         self.spec = spec
         self.mesh = rules_lib.auto_axes(mesh)
         self.n_clients = n_clients
         self.state_sync = state_sync
-        self.factored_sync = factored_sync
-        self.fused_round = fused_round
         self.participation = participation
-        self.bucketed_sync = bucketed_sync
-        self.pipeline_sync = pipeline_sync
         self.quarantine = quarantine
         self.round_idx = 0
 
@@ -141,10 +127,9 @@ class ShardedFederation:
         # refresh a real cond under the client vmap).
         self.opt_states = gal.stack_opt_state(opt_state, n_clients,
                                               copy=True)
-        # Fused default: 𝒮 + install + seed bump lower inside the round
-        # program; the stacked buffers are donated so round k+1's outputs
-        # reuse round k's memory. state_sync=None lowers the legacy 𝒯𝒜-only
-        # program used by the eager reference path.
+        # 𝒮 + install + seed bump lower inside the round program; the
+        # stacked buffers are donated so round k+1's outputs reuse round k's
+        # memory.
         # Defense knobs lower INSIDE the round program (steps.
         # make_fed_round_step): quarantine screens the factored uplink and
         # folds failures into the zero-weight mask path; robust_agg swaps
@@ -155,21 +140,17 @@ class ShardedFederation:
         # the guarded (exclusion-aware) program applies it to each client's
         # uplink before the screen.
         self._step_kwargs = dict(
-            factored_sync=factored_sync, factored_clients=factored_clients,
-            client_chunk=client_chunk, lift_free=lift_free,
+            client_chunk=client_chunk,
             robust_agg=robust_agg, quarantine=quarantine,
             quarantine_zmax=quarantine_zmax, robust_trim=robust_trim,
-            robust_iters=robust_iters, robust_tol=robust_tol,
-            bucketed_sync=bucketed_sync)
+            robust_iters=robust_iters, robust_tol=robust_tol)
         self._robust_sync_kwargs = dict(
             robust_agg=robust_agg, robust_trim=robust_trim,
             robust_iters=robust_iters, robust_tol=robust_tol)
         self._round_core = steps_lib.make_fed_round_step(
-            cfg, spec, n_clients,
-            state_sync=(state_sync if fused_round else None),
+            cfg, spec, n_clients, state_sync=state_sync,
             **self._step_kwargs)
-        self._round = jax.jit(self._round_core,
-                              donate_argnums=(0, 2) if fused_round else ())
+        self._round = jax.jit(self._round_core, donate_argnums=(0, 2))
         self._rounds_scan = None
         # Participation-masked variants (built lazily — a federation that
         # never sees a partial mask never compiles them).
@@ -223,11 +204,10 @@ class ShardedFederation:
         if self._round_masked is None:
             self._round_masked_core = steps_lib.make_fed_round_step(
                 self.cfg, self.spec, self.n_clients,
-                state_sync=(self.state_sync if self.fused_round else None),
+                state_sync=self.state_sync,
                 exclude_zero_weights=True, **self._step_kwargs)
-            self._round_masked = jax.jit(
-                self._round_masked_core,
-                donate_argnums=(0, 2) if self.fused_round else ())
+            self._round_masked = jax.jit(self._round_masked_core,
+                                         donate_argnums=(0, 2))
         return self._round_masked
 
     def _base_weights(self, weights):
@@ -255,13 +235,9 @@ class ShardedFederation:
         the AJIVE joint basis — an exact no-op on all-positive weights,
         matching the engine's guarded jit); an all-ones attack
         short-circuits onto the honest program, bit-identical to no attack.
-        Requires the fused factored round."""
+        Requires the factored client round."""
         mask = self._canon_mask(mask)
         attack = self._canon_attack(attack)
-        if attack is not None and not self.fused_round:
-            raise ValueError("attack injection requires fused_round=True "
-                             "(the legacy host-𝒮 round syncs with pre-"
-                             "quarantine weights)")
         w = self._base_weights(weights)
         if mask is None and attack is None:
             round_fn = self._round
@@ -271,20 +247,10 @@ class ShardedFederation:
                 w = w * jnp.asarray(mask, w.dtype)
         extra = () if attack is None else (attack,)
         with jax.set_mesh(self.mesh):
-            new_global, out_states, losses, v_upload = round_fn(
+            # 𝒮 runs in-mesh; the returned states are next-round-ready.
+            self.global_trainable, self.opt_states, losses, _ = round_fn(
                 self.global_trainable, self.frozen, self.opt_states,
                 batches, w, *extra)
-        self.global_trainable = new_global
-        if self.fused_round:
-            # 𝒮 already ran in-mesh; the returned states are next-round-ready.
-            self.opt_states = out_states
-        else:
-            # Unmasked: raw w, exactly the pre-participation call. Masked:
-            # renormalize over participants (mirrors the in-program 𝒜
-            # normalization) and exclude the zero-weight clients from 𝒮.
-            w_sync = w if mask is None else w / jnp.sum(w)
-            self.opt_states = self._sync_and_reinit(
-                out_states, v_upload, w_sync, exclude_zero=mask is not None)
         self.round_idx += 1
         return {"losses": losses,
                 "mean_final_loss": float(jnp.mean(losses[:, -1]))}
@@ -293,8 +259,7 @@ class ShardedFederation:
                    weights: Optional[jnp.ndarray] = None, masks=None):
         """K rounds as ONE dispatch: ``lax.scan`` over the in-mesh round.
 
-        batches: pytree with leading (K rounds, C, T, b, ...) axes. Requires
-        the fused round (𝒮 must lower inside the scanned program).
+        batches: pytree with leading (K rounds, C, T, b, ...) axes.
 
         ``masks`` (optional bool (K, C)) applies per-round participation
         masks: the per-round mask-zeroed weights ride the scan as xs and the
@@ -304,14 +269,10 @@ class ShardedFederation:
         When :meth:`_pipeline_rounds` holds, the scan is the one-round-deep
         pipelined schedule (see the module docstring): each body syncs the
         *previous* round's states before its local phase and a post-scan
-        drain syncs the last round, so results are state-for-state identical
-        to the sequential scan while 𝒮 overlaps the next round's gradient
+        drain syncs the last round, so results are state-for-state those of
+        K :meth:`run_round` calls while 𝒮 overlaps the next round's gradient
         work.
         """
-        if not self.fused_round:
-            raise ValueError("run_rounds requires fused_round=True: the "
-                             "legacy round program returns unsynced states "
-                             "and would silently skip 𝒮 inside the scan")
         leading = jax.tree_util.tree_leaves(batches)[0].shape
         k_rounds = leading[0]
         w = self._base_weights(weights)
@@ -451,13 +412,11 @@ class ShardedFederation:
     # ------------------------------------------------ pipelined rounds ------
     def _pipeline_rounds(self) -> bool:
         """Whether :meth:`run_rounds` scans the one-round-deep pipelined
-        schedule. Requires a fused round whose method actually syncs.
-        Quarantined scans pipeline too: the raw round core returns the
-        post-screen effective weights (``return_weights``), which ride the
-        scan carry so the deferred 𝒮 reproduces the post-quarantine
-        weighting exactly."""
-        return (self.pipeline_sync and self.fused_round
-                and self.state_sync != "none")
+        schedule: whenever the method syncs. Quarantined scans pipeline too:
+        the raw round core returns the post-screen effective weights
+        (``return_weights``), which ride the scan carry so the deferred 𝒮
+        reproduces the post-quarantine weighting exactly."""
+        return self.state_sync != "none"
 
     def _raw_round(self):
         """Raw (state_sync=None) round core for the pipelined scans: the
@@ -483,24 +442,10 @@ class ShardedFederation:
         def sync(states, w):
             return steps_lib.sync_client_states(
                 states, w, self.n_clients, self.state_sync,
-                factored=self.factored_sync,
                 bases_shared=self._bases_shared(),
                 exclude_zero_weights=exclude_zero,
-                bucketed=self.bucketed_sync, **self._robust_sync_kwargs)
+                **self._robust_sync_kwargs)
         return sync
-
-    # ---------------------------------------------- 𝒮 (eager reference) -----
-    def _sync_and_reinit(self, out_states, v_upload, w, exclude_zero=False):
-        """Host-side 𝒮 of the legacy round: the same server filter as the
-        in-mesh tail of the fused round (`steps.sync_client_states`), run
-        eagerly between jit boundaries — the reference the fused round is
-        benchmarked against."""
-        del v_upload    # sync_client_states re-extracts from the states
-        return steps_lib.sync_client_states(
-            out_states, w, self.n_clients, self.state_sync,
-            factored=self.factored_sync, bases_shared=self._bases_shared(),
-            exclude_zero_weights=exclude_zero,
-            bucketed=self.bucketed_sync, **self._robust_sync_kwargs)
 
     def _bases_shared(self) -> bool:
         """The shared-basis factored sync requires every client on the
@@ -509,6 +454,5 @@ class ShardedFederation:
         every in-step refresh is seeded-random from the broadcast seed —
         shared by construction. 'svd' refreshes from each client's own
         gradient, so bases diverge and the sync takes the heterogeneous
-        factored path (dense per-client lift only with
-        ``factored_sync=False``)."""
+        factored path."""
         return self.spec.refresh_mode != "svd"

@@ -14,40 +14,43 @@ factored on the projected ṽ (shared-basis rounds) or via heterogeneous-basis
 r×r transfer Grams (``refresh_mode='svd'``, diverged bases), followed by the
 synced-state install and seed bump for the next round. The paper's full
 𝒯→𝒜→𝒮 pipeline is one SPMD program: the round never drops out of the mesh
-onto the host. Passing ``state_sync=None`` lowers the legacy 𝒯→𝒜 program
-(raw end-of-round states returned; the caller syncs on the host — the eager
-reference path).
+onto the host. Passing ``state_sync=None`` lowers the 𝒯→𝒜 program alone
+(raw end-of-round states returned): the dry-run's default, and the body of
+the runtime's pipelined scan, which runs :func:`sync_client_states` one
+round late.
 
-Client memory model of the round program (mirrors ``core.fed``): with the
-default ``factored_clients=True`` every client's round state is the rank-r
-factored accumulator ``R_i`` around the broadcast global base, and with the
-default ``lift_free=True`` the local step is **lift-free**: target leaves
-enter the model as ``models.layers.LowRankDelta`` nodes whose delta-aware
-projections compute ``base_scale·(x@W) + split-matmul(R_i)`` directly
-(``kernels.lowrank_linear`` on TPU) and whose custom VJP returns the ``R_i``
-cotangent already in rank-r coordinates — no ``base_scale·W + lift(R_i)``
-transient, no dense m×n gradient, exact global-norm clipping via the VJP's
-dense-norm probes. 𝒜 collapses to ``base_scale·W + Σ wᵢ lift(Rᵢ)`` with no
-dense (C, m, n) weight stack anywhere in the program. ``lift_free=False``
-keeps the transient-lift read (the parity oracle); ``refresh_mode='svd'``
-forces it too, since data-driven refreshes need the dense per-client
-gradient. In-step seeded-random refreshes are hoisted before the forward
-(``galore.maybe_refresh_instep``) so cotangents arrive on the refreshed
-basis. ``client_chunk=B`` streams the cohort through the round in C/B
-sequential chunks (a ``lax.scan`` over the chunked client axis), bounding
-the dense forward/backward working set by B clients. Stacked client
-optimizer states ride the GaLore count/seed UNBATCHED (``galore.
-stack_opt_state`` layout) so the in-step ``count % τ`` refresh stays a real
-``lax.cond`` under the client vmap. The factored client path requires every
-refresh to land on local step 0 (where R_i ≡ 0): ``refresh_every %
-local_steps == 0``; otherwise the dense client round (retained under
-``factored_clients=False`` as the parity oracle) is used.
+Client memory model of the round program (mirrors ``core.fed``): when every
+in-step refresh lands on local step 0 (``refresh_every % local_steps == 0``)
+and every trainable leaf is a target block, each client's round state is the
+rank-r factored accumulator ``R_i`` around the broadcast global base, and
+the local step is **lift-free** (:func:`client_round_liftfree`): target
+leaves enter the model as ``models.layers.LowRankDelta`` nodes whose
+delta-aware projections compute ``base_scale·(x@W) + split-matmul(R_i)``
+directly (``kernels.lowrank_linear`` on TPU) and whose custom VJP returns
+the ``R_i`` cotangent already in rank-r coordinates — no ``base_scale·W +
+lift(R_i)`` transient, no dense m×n gradient, exact global-norm clipping via
+the VJP's dense-norm probes. 𝒜 collapses to ``base_scale·W + Σ wᵢ
+lift(Rᵢ)`` with no dense (C, m, n) weight stack anywhere in the program.
+``refresh_mode='svd'`` (data-driven refreshes need the dense per-client
+gradient) and MLA under blockwise attention take the transient-lift read
+(:func:`client_round_factored`) instead. In-step seeded-random refreshes are
+hoisted before the forward (``galore.maybe_refresh_instep``) so cotangents
+arrive on the refreshed basis. ``client_chunk=B`` streams the cohort through
+the round in C/B sequential chunks (a ``lax.scan`` over the chunked client
+axis), bounding the dense forward/backward working set by B clients.
+Stacked client optimizer states ride the GaLore count/seed UNBATCHED
+(``galore.stack_opt_state`` layout) so the in-step ``count % τ`` refresh
+stays a real ``lax.cond`` under the client vmap. Rounds whose refreshes do
+not land on step 0 keep dense per-client weight stacks.
+
+The dense-per-client round with the dense-lift 𝒮 that tests hold this
+program to is ``core.reference.make_dense_round_step``.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -65,8 +68,8 @@ PyTree = Any
 
 
 def galore_target_fn(cfg: ArchConfig) -> Callable:
-    """The paper's target modules, adapted per family (DESIGN.md §4):
-    attention + dense-MLP projections; Mamba in/out projections; RWKV6
+    """The paper's target modules, adapted per family: attention +
+    dense-MLP projections; Mamba in/out projections; RWKV6
     time-mix/channel-mix matrices. Experts, routers, embeddings and the
     output head (LM or classifier) frozen: RoBERTa-base trains its 72
     projections (q, k, v, o, up, down x 12), its biases ride frozen."""
@@ -108,18 +111,10 @@ class TrainSpec:
     local_steps: int = 8                # T (round step only)
     seed: int = 0
     refresh_mode: str = "random"        # production steady-state step
-    # Fused/bucketed GaLore execution (core.galore module docstring):
-    # fused=True batches same-shape target blocks per step; use_pallas=None
+    # The GaLore step's kernel route (core.galore module docstring): None
     # auto-selects the fused Pallas kernel on TPU (interpret fallback on CPU
     # when forced True).
-    fused: bool = True
     use_pallas: Optional[bool] = None
-    # Lift-free factored local steps (module docstring): delta-aware forward
-    # + projected-cotangent backward instead of the per-leaf transient lift.
-    # Auto-disabled when the factored client model doesn't apply or
-    # refresh_mode='svd' needs dense gradients. False = transient-lift
-    # oracle.
-    lift_free: bool = True
     # Mesh axes carrying the client dimension. jax.vmap(spmd_axis_name=...)
     # pins every per-client intermediate's leading dim to these axes —
     # without it SPMD replicated the client dim across the data axis
@@ -130,7 +125,7 @@ class TrainSpec:
 def make_galore_cfg(spec: TrainSpec) -> gal.GaloreConfig:
     return gal.GaloreConfig(rank=spec.rank, refresh_every=spec.refresh_every,
                             adaptive_steps=0, refresh_mode=spec.refresh_mode,
-                            fused=spec.fused, use_pallas=spec.use_pallas)
+                            use_pallas=spec.use_pallas)
 
 
 def make_galore_tx(cfg: ArchConfig, spec: TrainSpec):
@@ -184,9 +179,8 @@ def make_fed_local_step(cfg: ArchConfig, spec: TrainSpec,
 
 
 def sync_client_states(out_st, w, n_clients: int, state_sync: str,
-                       factored: bool, bases_shared: bool,
+                       bases_shared: bool,
                        exclude_zero_weights: bool = False,
-                       bucketed: bool = True,
                        robust_agg: str = "none",
                        robust_trim: float = 0.2,
                        robust_iters: int = 8,
@@ -196,21 +190,19 @@ def sync_client_states(out_st, w, n_clients: int, state_sync: str,
 
     Synchronizes each adapted block's projected ṽ — factored on the shared
     seeded basis, or via heterogeneous r×r transfer Grams when client bases
-    diverged (``bases_shared=False``), or through the dense per-client lift
-    oracle (``factored=False``) — installs the broadcast result in every
-    client slot, and bumps the round seed. No dense ``(C, m, n)`` view is
-    built on any factored path. ``exclude_zero_weights`` (the
+    diverged (``bases_shared=False``) — installs the broadcast result in
+    every client slot, and bumps the round seed. No dense ``(C, m, n)`` view
+    is built; ``core.reference.sync_client_states_dense`` is the dense-lift
+    reference. Shape-identical leaves run as one vmapped program per bucket
+    (`state_sync.map_sync_leaves`). ``exclude_zero_weights`` (the
     participation-masked round) additionally drops zero-weight clients from
     the AJIVE joint-basis estimate — without it they only vanish from the
     final weighted mean, not from the unweighted joint-subspace phases.
-    ``bucketed`` runs shape-identical leaves as one vmapped program per
-    bucket (`state_sync.map_sync_leaves`); False keeps the per-leaf loop as
-    the parity oracle. ``robust_agg`` is robust 𝒮: the weighted-mean
-    reductions over the projected-moment stacks inside the factored sync
-    protocols are swapped for the robust estimator (trimmed-mean /
-    geomedian; heterogeneous bases are first re-based onto the client-0
-    basis via the r×r transfer Grams) — ``'none'`` lowers exactly the plain
-    program, bitwise.
+    ``robust_agg`` is robust 𝒮: the weighted-mean reductions over the
+    projected-moment stacks inside the factored sync protocols are swapped
+    for the robust estimator (trimmed-mean / geomedian; heterogeneous bases
+    are first re-based onto the client-0 basis via the r×r transfer Grams) —
+    ``'none'`` lowers exactly the plain program, bitwise.
     """
     g_stack = gal.galore_state_of(out_st)
     if state_sync != "none":
@@ -223,9 +215,6 @@ def sync_client_states(out_st, w, n_clients: int, state_sync: str,
         def leaf_fn(v_stack, b_stack):
             rank = b_stack.shape[-1]
             side = proj.RIGHT if v_stack.shape[-1] == rank else proj.LEFT
-            if not factored:
-                return _dense_sync_block(state_sync, v_stack, b_stack, w,
-                                         rank, side)
             if bases_shared:
                 # Factored 𝒮: sync the (C, ., r) uplink directly; the shared
                 # seeded basis cancels, so no (C, m, n) lift and no (n, n)
@@ -236,16 +225,14 @@ def sync_client_states(out_st, w, n_clients: int, state_sync: str,
                     robust=robust_agg, trim=robust_trim, iters=robust_iters,
                     tol=robust_tol), 0.0)
             # Diverged bases (data-driven refreshes): the lift → 𝒮 →
-            # re-project round-trip closes over r×r transfer Grams —
-            # the dense per-client lift stays a parity oracle.
+            # re-project round-trip closes over r×r transfer Grams.
             return jnp.maximum(sync_lib.sync_block_hetero_factored(
                 state_sync, v_stack, b_stack, side, w, rank,
                 exclude_zero_weights=exclude_zero_weights,
                 robust=robust_agg, trim=robust_trim, iters=robust_iters,
                 tol=robust_tol), 0.0)
 
-        synced_leaves = sync_lib.map_sync_leaves(leaf_fn, vs, bs,
-                                                 bucketed=bucketed)
+        synced_leaves = sync_lib.map_sync_leaves(leaf_fn, vs, bs)
         # every client slot shares the synced projected state (a broadcast
         # view of the O(dim·r) buffer, not a dense tensor)
         out = [None if s is None else
@@ -260,33 +247,62 @@ def sync_client_states(out_st, w, n_clients: int, state_sync: str,
     return gal.replace_galore_state(out_st, g_new)
 
 
-def _dense_sync_block(state_sync, v_stack, b_stack, w, rank, side):
-    """Dense reference 𝒮 (parity oracle): lift each client's ṽ with its
-    *own* end-of-round basis (correct under diverged bases), run the
-    configured protocol on the lifted views, re-project onto the
-    client-0 basis."""
-    def sync_one(v_cl, b_cl):
-        # v_cl (C, m, r) | (C, r, n); b_cl (C, dim, r)
-        v32 = v_cl.astype(jnp.float32)
-        b32 = b_cl.astype(jnp.float32)
-        if side == proj.RIGHT:
-            views = jnp.einsum("kmr,knr->kmn", v32, b32)
-        else:
-            views = jnp.einsum("kmr,krn->kmn", b32, v32)
-        lifted = sync_lib.sync_lifted_views(state_sync, views, w, rank)
-        return jnp.maximum(sync_lib.project_state(lifted, b_cl[0], side), 0.0)
+def client_round_factored(cfg: ArchConfig, spec: TrainSpec, deltas, frozen,
+                          opt_state, batches, global_trainable):
+    """T factored local steps on one client with the transient-lift read:
+    every step materializes ``base_scale·W + lift(R_i)`` per target leaf,
+    takes the dense gradient, and updates the rank-r accumulator
+    (``galore.factored_adamw_step``). Returns (deltas, opt_state, losses
+    (T,), base_scale)."""
+    gcfg = make_galore_cfg(spec)
 
-    if v_stack.ndim == 4:         # stacked scan blocks: (C, nb, ., r)
-        return jax.vmap(sync_one, in_axes=(1, 1))(v_stack, b_stack)
-    return sync_one(v_stack, b_stack)
+    def one(carry, batch):
+        dl, scale, st = carry
+        tr = gal.lift_client_trainable(global_trainable, dl,
+                                       gal.galore_state_of(st), scale)
+
+        def loss_of(t):
+            return model_lib.loss_fn(merge_dense(frozen, t), cfg, batch)
+        loss, grads = jax.value_and_grad(loss_of)(tr)
+        dl, scale, st = gal.factored_adamw_step(
+            gcfg, grads, st, dl, scale, lr=spec.lr,
+            weight_decay=spec.weight_decay, clip_norm=spec.clip_norm)
+        return (dl, scale, st), loss
+    (deltas, scale, opt_state), losses = jax.lax.scan(
+        one, (deltas, jnp.ones([], jnp.float32), opt_state), batches)
+    return deltas, opt_state, losses, scale
+
+
+def client_round_liftfree(cfg: ArchConfig, spec: TrainSpec, deltas, frozen,
+                          opt_state, batches, global_trainable):
+    """T lift-free local steps on one client: hoisted seeded-random refresh,
+    delta-context forward (LowRankDelta leaves — no per-leaf transient lift),
+    projected-cotangent backward, factored AdamW on the LiftFreeGrads bundle
+    (projection GEMM skipped, clipping via the norm probes). Same signature
+    and results as :func:`client_round_factored`."""
+    gcfg = make_galore_cfg(spec)
+
+    def one(carry, batch):
+        dl, scale, st = carry
+        g0 = gal.maybe_refresh_instep(gcfg, gal.galore_state_of(st))
+        st = gal.replace_galore_state(st, g0)
+
+        def loss_of(t):
+            return model_lib.loss_fn(merge_dense(frozen, t), cfg, batch)
+        loss, grads = gal.liftfree_value_and_grad(
+            loss_of, global_trainable, dl, g0, scale)
+        dl, scale, st = gal.factored_adamw_step(
+            gcfg, grads, st, dl, scale, lr=spec.lr,
+            weight_decay=spec.weight_decay, clip_norm=spec.clip_norm)
+        return (dl, scale, st), loss
+    (deltas, scale, opt_state), losses = jax.lax.scan(
+        one, (deltas, jnp.ones([], jnp.float32), opt_state), batches)
+    return deltas, opt_state, losses, scale
 
 
 def make_fed_round_step(cfg: ArchConfig, spec: TrainSpec, n_clients: int,
                         state_sync: Optional[str] = None,
-                        factored_sync: bool = True,
-                        factored_clients: bool = True,
                         client_chunk: Optional[int] = None,
-                        lift_free: Optional[bool] = None,
                         exclude_zero_weights: bool = False,
                         robust_agg: str = "none",
                         quarantine: bool = False,
@@ -294,34 +310,29 @@ def make_fed_round_step(cfg: ArchConfig, spec: TrainSpec, n_clients: int,
                         robust_trim: float = 0.2,
                         robust_iters: int = 8,
                         robust_tol: float = 1e-6,
-                        bucketed_sync: bool = True,
                         return_weights: bool = False) -> Callable:
     """A full federated round (Algorithm 1) as one SPMD program:
 
       broadcast (implicit: clients start from the shared global base) →
       T local GaLoreAdamW steps (lax.scan), streamed over cohort chunks →
       𝒜: factored ``base_scale·W + Σ wᵢ lift(Rᵢ)`` (or the dense weighted
-      mean over the client axis under ``factored_clients=False``) →
+      mean over the client axis for dense client stacks) →
       𝒮 (when ``state_sync`` is a protocol name): factored sync of the
       projected second moments, install + seed bump — all inside the mesh;
       the returned states are ready for the next round.
 
-    ``factored_clients`` selects the rank-r factored client memory model
-    (module docstring); it requires in-step refreshes to land on local step 0
-    (``refresh_every % local_steps == 0``) and every trainable leaf to be a
-    target block, falling back to the dense client round otherwise.
-    ``lift_free`` (None = ``spec.lift_free``) additionally runs the factored
-    local phase through the delta context — zero lift GEMMs and zero dense
-    gradient cotangents; auto-disabled for ``refresh_mode='svd'``.
-    ``client_chunk=B`` (must divide ``n_clients``, and B must still cover the
+    The client memory model is the rank-r factored one whenever in-step
+    refreshes land on local step 0 (``refresh_every % local_steps == 0``)
+    and every trainable leaf is a target block, with the lift-free local
+    phase unless ``refresh_mode='svd'`` or MLA under blockwise attention
+    needs the transient-lift read (module docstring); dense client stacks
+    otherwise. ``client_chunk=B`` (must divide ``n_clients``, and B must still cover the
     client mesh axes) runs the local phase in C/B sequential chunks.
-    ``state_sync=None`` preserves the legacy 𝒯→𝒜 program: raw end-of-round
-    states are returned and the caller runs 𝒮 on the host (the eager
-    reference path, and the dry-run default). It is also the building block
-    of the runtime's pipelined scan (`fedsim.runtime.ShardedFederation.
-    run_rounds`), which defers each round's `sync_client_states` to the top
-    of the next round's body. ``bucketed_sync`` selects the bucketed/vmapped
-    𝒮 leaf execution (see `sync_client_states`).
+    ``state_sync=None`` lowers the 𝒯→𝒜 program alone: raw end-of-round
+    states are returned with their ṽ upload (the dry-run default). It is
+    also the building block of the runtime's pipelined scan
+    (`fedsim.runtime.ShardedFederation.run_rounds`), which defers each
+    round's `sync_client_states` to the top of the next round's body.
     ``exclude_zero_weights`` lowers the participation-masked round variant:
     the caller feeds pre-masked weights (zero for non-participants — the
     in-program normalization renormalizes over the participants) and 𝒮
@@ -358,26 +369,21 @@ def make_fed_round_step(cfg: ArchConfig, spec: TrainSpec, n_clients: int,
     the quarantined scan pipeline one round deep like the honest path.
     """
     tx = make_galore_tx(cfg, spec)
-    gcfg = make_galore_cfg(spec)
     if robust_agg not in agg_lib.ROBUST_MODES:
         raise ValueError(f"robust_agg={robust_agg!r} not in "
                          f"{agg_lib.ROBUST_MODES}")
     guard = quarantine or robust_agg != "none"
     # Factored deltas are exact only while the basis is fixed whenever any
     # R_i ≠ 0, i.e. refreshes only at local step 0 (count ≡ 0 mod τ there).
-    factored_ok = (factored_clients
-                   and spec.refresh_every % spec.local_steps == 0)
+    factored_ok = spec.refresh_every % spec.local_steps == 0
     # Lift-free needs every in-step refresh to be seeded-random (the hoisted
     # refresh never sees a gradient): 'svd' mode keeps the transient read.
     # MLA with blockwise attention reads kv_b once per chunk, which breaks
     # the clip-norm probe's exactness (per-use ‖·‖² sum misses cross-chunk
     # terms — models.layers.lowrank_apply): keep the transient read there.
-    if lift_free is None:
-        lift_free = spec.lift_free
     multi_read = (cfg.attn_chunk and any(
         mix == "mla" for mix, _ in cfg.layer_kinds()))
-    liftfree_ok = (lift_free and spec.refresh_mode != "svd"
-                   and not multi_read)
+    liftfree_ok = spec.refresh_mode != "svd" and not multi_read
     chunk = client_chunk or n_clients
     if n_clients % chunk:
         raise ValueError(f"client_chunk={chunk} must divide n_clients="
@@ -395,45 +401,6 @@ def make_fed_round_step(cfg: ArchConfig, spec: TrainSpec, n_clients: int,
         (trainable, opt_state), losses = jax.lax.scan(
             one, (trainable, opt_state), batches)
         return trainable, opt_state, losses
-
-    def client_round_factored(deltas, frozen, opt_state, batches,
-                              global_trainable):
-        def one(carry, batch):
-            dl, scale, st = carry
-            tr = gal.lift_client_trainable(global_trainable, dl,
-                                           gal.galore_state_of(st), scale)
-            def loss_of(t):
-                return model_lib.loss_fn(merge_dense(frozen, t), cfg, batch)
-            loss, grads = jax.value_and_grad(loss_of)(tr)
-            dl, scale, st = gal.factored_adamw_step(
-                gcfg, grads, st, dl, scale, lr=spec.lr,
-                weight_decay=spec.weight_decay, clip_norm=spec.clip_norm)
-            return (dl, scale, st), loss
-        (deltas, scale, opt_state), losses = jax.lax.scan(
-            one, (deltas, jnp.ones([], jnp.float32), opt_state), batches)
-        return deltas, opt_state, losses, scale
-
-    def client_round_liftfree(deltas, frozen, opt_state, batches,
-                              global_trainable):
-        """The lift-free local phase: hoisted seeded-random refresh, delta-
-        context forward (LowRankDelta leaves — no per-leaf transient lift),
-        projected-cotangent backward, factored AdamW on the LiftFreeGrads
-        bundle (projection GEMM skipped, clipping via the norm probes)."""
-        def one(carry, batch):
-            dl, scale, st = carry
-            g0 = gal.maybe_refresh_instep(gcfg, gal.galore_state_of(st))
-            st = gal.replace_galore_state(st, g0)
-            def loss_of(t):
-                return model_lib.loss_fn(merge_dense(frozen, t), cfg, batch)
-            loss, grads = gal.liftfree_value_and_grad(
-                loss_of, global_trainable, dl, g0, scale)
-            dl, scale, st = gal.factored_adamw_step(
-                gcfg, grads, st, dl, scale, lr=spec.lr,
-                weight_decay=spec.weight_decay, clip_norm=spec.clip_norm)
-            return (dl, scale, st), loss
-        (deltas, scale, opt_state), losses = jax.lax.scan(
-            one, (deltas, jnp.ones([], jnp.float32), opt_state), batches)
-        return deltas, opt_state, losses, scale
 
     from ..models.layers import batch_axes_override
 
@@ -467,8 +434,9 @@ def make_fed_round_step(cfg: ArchConfig, spec: TrainSpec, n_clients: int,
             is_leaf=lambda x: isinstance(x, (gal.GaloreBlockState,
                                              gal.DenseMoments)))
 
-        client_fn = (client_round_liftfree if liftfree_ok
-                     else client_round_factored)
+        client_fn = functools.partial(
+            client_round_liftfree if liftfree_ok else client_round_factored,
+            cfg, spec)
 
         def local_fn(opt_chunk, batch_chunk):
             with batch_axes_override(()):
@@ -483,8 +451,7 @@ def make_fed_round_step(cfg: ArchConfig, spec: TrainSpec, n_clients: int,
 
     def _local_phase_dense(global_trainable, frozen, opt_states, batches,
                            axes):
-        """Chunk-streamed dense local phase (the parity-oracle client model:
-        per-client weight stacks)."""
+        """Chunk-streamed dense local phase (per-client weight stacks)."""
         stacked = jax.tree_util.tree_map(
             lambda x: jnp.broadcast_to(x[None], (chunk,) + x.shape),
             global_trainable)
@@ -565,8 +532,8 @@ def make_fed_round_step(cfg: ArchConfig, spec: TrainSpec, n_clients: int,
             if guard:
                 raise ValueError(
                     "quarantine/robust_agg require the factored client "
-                    "round (factored_clients with step-0-aligned refreshes "
-                    "and all-target trainables)")
+                    "round (step-0-aligned refreshes and all-target "
+                    "trainables)")
             out_tr, out_st, losses = _local_phase_dense(
                 global_trainable, frozen, opt_states, batches, axes)
             # 𝒜: weighted average over the client axis -> all-reduce on mesh
@@ -580,10 +547,10 @@ def make_fed_round_step(cfg: ArchConfig, spec: TrainSpec, n_clients: int,
             # clients from the joint basis even when the caller didn't ask
             # for the masked variant (exact no-op on all-positive weights).
             out_st = sync_client_states(
-                out_st, w, n_clients, state_sync, factored=factored_sync,
+                out_st, w, n_clients, state_sync,
                 bases_shared=(spec.refresh_mode != "svd"),
                 exclude_zero_weights=exclude_zero_weights or quarantine,
-                bucketed=bucketed_sync, robust_agg=robust_agg,
+                robust_agg=robust_agg,
                 robust_trim=robust_trim, robust_iters=robust_iters,
                 robust_tol=robust_tol)
             if return_weights:

@@ -1,11 +1,14 @@
-"""Parity: the fused/bucketed scale_by_galore path vs the per-leaf reference
-loop, and the Pallas (interpret-mode) kernel path, over a multi-block pytree
-with right, left, and stacked 3-D blocks plus a dense (bias) leaf."""
+"""Parity: the bucketed scale_by_galore step and round-boundary refresh vs
+their per-leaf references (``galore.galore_transform_update_per_leaf``,
+``galore.manual_refresh_per_leaf``), and the Pallas (interpret-mode) kernel
+path, over a multi-block pytree with right, left, and stacked 3-D blocks
+plus a dense (bias) leaf."""
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import galore as gal
+from repro.core import reference
 
 KEY = jax.random.PRNGKey(0)
 
@@ -27,44 +30,88 @@ def tree():
     return params, grads
 
 
-def _run(cfg, params, grads, steps=7):
-    tx = gal.scale_by_galore(cfg)
-    st = tx.init(params)
+def _run(cfg, params, grads, steps=7, per_leaf=False):
+    """``steps`` updates of the bucketed step (``scale_by_galore``) or of its
+    per-leaf reference, each step one jitted call."""
+    st = gal.galore_init(cfg, params)
+    update = (gal.galore_transform_update_per_leaf if per_leaf
+              else gal.scale_by_galore(cfg).update)
+    step = jax.jit(lambda g, s: (update(cfg, g, s) if per_leaf
+                                 else update(g, s)))
     outs = []
     for _ in range(steps):
-        u, st = tx.update(grads, st)
+        u, st = step(grads, st)
         outs.append(u)
     return outs, st
 
 
+def _rel_err(a, b):
+    """max |a - b| over max |b|: a norm-wise relative error."""
+    return float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b)))
+
+
+# Bucketed vs per-leaf: twice the worst relative error of 20 repeated CPU
+# runs (1.27e-5, the 'auto' RSVD refresh), the headroom for other CPUs'
+# code generation.
+BUCKET_RTOL = 2.5e-5
+
+
 @pytest.mark.parametrize("refresh_mode", ["random", "auto"])
 def test_bucketed_matches_reference_loop(tree, refresh_mode):
+    """Seven bucketed steps (a refresh every third, the first data-driven
+    under 'auto') against the per-leaf reference: updates, bases and second
+    moments within the relative ``BUCKET_RTOL``. The bound is relative and
+    not an absolute 1e-5: the bucketed and per-leaf steps are differently
+    fused programs and XLA re-associates their fp32 reductions differently
+    (the RSVD refresh under 'auto' moves updates near 10 by ~3e-5)."""
     params, grads = tree
-    kw = dict(rank=4, refresh_every=3, adaptive_steps=1,
-              refresh_mode=refresh_mode)
-    u_f, st_f = _run(gal.GaloreConfig(fused=True, use_pallas=False, **kw),
-                     params, grads)
-    u_r, st_r = _run(gal.GaloreConfig(fused=False, **kw), params, grads)
+    cfg = gal.GaloreConfig(rank=4, refresh_every=3, adaptive_steps=1,
+                           refresh_mode=refresh_mode, use_pallas=False)
+    u_f, st_f = _run(cfg, params, grads)
+    u_r, st_r = _run(cfg, params, grads, per_leaf=True)
     for uf, ur in zip(u_f, u_r):
         for k in params:
-            assert jnp.allclose(uf[k], ur[k], atol=1e-5), k
+            if k == "bias":
+                assert jnp.array_equal(uf[k], ur[k])
+                continue
+            assert _rel_err(uf[k], ur[k]) <= BUCKET_RTOL, k
     for k in ("a", "b", "c", "d"):
-        # bucketed seeded refresh must reproduce the per-leaf bases exactly
-        # (the server-broadcast-a-seed protocol depends on it)
-        assert jnp.allclose(st_f.blocks[k].basis, st_r.blocks[k].basis,
-                            atol=1e-6), k
-        assert jnp.allclose(st_f.blocks[k].v, st_r.blocks[k].v, atol=1e-6), k
+        # the bucketed refresh draws the per-leaf reference's bases (the
+        # server-broadcast-a-seed protocol depends on it)
+        assert _rel_err(st_f.blocks[k].basis,
+                        st_r.blocks[k].basis) <= BUCKET_RTOL, k
+        assert _rel_err(st_f.blocks[k].v, st_r.blocks[k].v) <= BUCKET_RTOL, k
+
+
+@pytest.mark.parametrize("data_driven", [False, True])
+def test_bucketed_refresh_matches_per_leaf(tree, data_driven):
+    """The bucketed round-boundary refresh (``manual_refresh``) against its
+    per-leaf reference, seeded-random and data-driven (RSVD of the given
+    gradients): same bases, same re-projected moments."""
+    params, grads = tree
+    cfg = gal.GaloreConfig(rank=4, adaptive_steps=1, refresh_mode="auto")
+    st = gal.galore_init(cfg, params, seed=3)
+    _, st = jax.jit(gal.scale_by_galore(cfg).update)(grads, st)  # moments
+    g = grads if data_driven else None
+    got = jax.jit(lambda s: gal.manual_refresh(cfg, s, 0, g))(st)
+    want = jax.jit(lambda s: gal.manual_refresh_per_leaf(cfg, s, 0, g))(st)
+    for k in ("a", "b", "c", "d"):
+        for field in ("basis", "m", "v"):
+            a = getattr(got.blocks[k], field)
+            b = getattr(want.blocks[k], field)
+            assert _rel_err(a, b) <= BUCKET_RTOL, (k, field)
+    assert jnp.array_equal(got.blocks["bias"].m, want.blocks["bias"].m)
 
 
 def test_pallas_path_matches_reference_loop(tree):
     params, grads = tree
     kw = dict(rank=4, refresh_every=3, adaptive_steps=1,
               refresh_mode="random")
-    u_p, st_p = _run(gal.GaloreConfig(fused=True, use_pallas=True,
+    u_p, st_p = _run(gal.GaloreConfig(use_pallas=True,
                                       pallas_block_rows=16, **kw),
                      params, grads, steps=4)
-    u_r, st_r = _run(gal.GaloreConfig(fused=False, **kw), params, grads,
-                     steps=4)
+    u_r, st_r = _run(gal.GaloreConfig(**kw), params, grads, steps=4,
+                     per_leaf=True)
     for up, ur in zip(u_p, u_r):
         for k in params:
             assert jnp.allclose(up[k], ur[k], atol=1e-5), k
@@ -77,8 +124,7 @@ def test_fused_inside_jit_and_scan(tree):
     wraps it in lax.scan)."""
     params, grads = tree
     cfg = gal.GaloreConfig(rank=4, refresh_every=2, adaptive_steps=0,
-                           refresh_mode="random", fused=True,
-                           use_pallas=False)
+                           refresh_mode="random", use_pallas=False)
     tx = gal.scale_by_galore(cfg)
     st = tx.init(params)
 
@@ -95,8 +141,10 @@ def test_fused_inside_jit_and_scan(tree):
 
 
 def test_fed_engine_factored_matches_dense_sync():
-    """FedEngine trajectories with factored_sync on/off coincide (shared-basis
-    rounds use the factored 𝒮; the adaptive round-0 falls back to dense)."""
+    """FedEngine trajectories coincide with the eager reference round's
+    (``reference.run_round_eager``: dense per-client weights, dense-lift 𝒮)
+    — the shared-basis rounds' factored 𝒮 and the adaptive round 0's
+    heterogeneous factored 𝒮 both against the dense lift."""
     key = jax.random.PRNGKey(0)
     from repro.core.fed import FedConfig, FedEngine
 
@@ -117,10 +165,13 @@ def test_fed_engine_factored_matches_dense_sync():
     finals = {}
     for factored in (True, False):
         eng = FedEngine(FedConfig(method="fedgalore", rank=4, lr=1e-2,
-                                  local_steps=2, factored_sync=factored),
+                                  local_steps=2),
                         loss, params, target_fn=lambda p, l: l.ndim == 2)
         for r in range(3):
-            eng.run_round(batches(r))
+            if factored:
+                eng.run_round(batches(r))
+            else:
+                reference.run_round_eager(eng, batches(r))
         finals[factored] = eng.global_trainable
     for a, b in zip(jax.tree_util.tree_leaves(finals[True]),
                     jax.tree_util.tree_leaves(finals[False])):

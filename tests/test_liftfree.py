@@ -1,9 +1,10 @@
 """Lift-free factored rounds: the delta-context forward (split-matmul
 weight read), the projected-cotangent VJP (gradients arrive in rank-r
 coordinates, clipping via exact dense-norm probes), kernel-vs-reference
-parity, engine/runtime lift-free ≡ transient-lift parity for all GaLore
-methods, the jaxpr shape probe (zero dense m×n lift GEMMs / gradient
-cotangents), and LoRA methods' indifference to the delta context."""
+parity, engine/runtime lift-free ≡ transient-lift parity of the local phase
+for all GaLore methods, the jaxpr shape probe (zero dense m×n lift GEMMs /
+gradient cotangents), and LoRA methods' indifference to the delta
+context."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -267,30 +268,31 @@ def _trees_close(a, b, atol):
 
 @pytest.mark.parametrize("method", sorted(GALORE_METHODS))
 def test_liftfree_matches_transient_lift_all_galore_methods(method):
-    """3 rounds lift-free ≡ transient-lift ≤ 1e-5, per GaLore method, with
-    an ACTIVE global-norm clip (clip_norm=0.5 — the dense-norm probes must
-    reproduce the dense path's clip factor exactly) and weight decay. The
-    toy covers both projection sides (l1 (8,16) left, l2 (16,4) right) and
-    the adaptive round-0 transient cond."""
+    """One client's T local steps, lift-free (``_local_train_liftfree_one``)
+    vs the transient-lift read (``_local_train_factored_one``) from the same
+    InitState ≤ 1e-5, per GaLore method, with an ACTIVE global-norm clip
+    (clip_norm=0.5 — the dense-norm probes must reproduce the dense path's
+    clip factor exactly) and weight decay. The InitState is round 1's after
+    a real round 0, so it carries the method's synced ṽ and the
+    seeded-random refresh the lift-free read serves. The toy covers both
+    projection sides (l1 (8,16) left, l2 (16,4) right). Whole rounds against
+    the dense reference: test_fed_round_fused.py."""
     params, loss = _problem()
-    engines = {}
-    for lf in (True, False):
-        eng = FedEngine(FedConfig(method=method, rank=4, lr=3e-2,
-                                  local_steps=5, clip_norm=0.5,
-                                  weight_decay=0.01, lift_free=lf),
-                        loss, params)
-        assert eng._lift_free is lf
-        for r in range(3):
-            m = eng.run_round(_round_batches(r))
-            assert jnp.all(jnp.isfinite(m["local_loss"]))
-        engines[lf] = eng
-    _trees_close(engines[True].global_trainable,
-                 engines[False].global_trainable, atol=1e-5)
-    if engines[False].synced_v is not None:
-        _trees_close(engines[True].synced_v, engines[False].synced_v,
-                     atol=1e-5)
-    else:
-        assert engines[True].synced_v is None
+    eng = FedEngine(FedConfig(method=method, rank=4, lr=3e-2,
+                              local_steps=5, clip_norm=0.5,
+                              weight_decay=0.01), loss, params)
+    assert eng._lift_free
+    eng.run_round(_round_batches(0))
+    st0 = eng._init_state0(eng.round_idx, eng.synced_v, eng.global_trainable)
+    d0 = gal.zero_client_deltas(gal.galore_state_of(st0))
+    batches = jax.tree_util.tree_map(lambda x: x[1], _round_batches(1))
+    outs = {}
+    for lf, fn in ((True, eng._local_train_liftfree_one),
+                   (False, eng._local_train_factored_one)):
+        outs[lf] = jax.jit(lambda d, st, b, fn=fn: fn(
+            d, st, b, eng.frozen, eng.global_trainable))(d0, st0, batches)
+        assert jnp.all(jnp.isfinite(outs[lf][2]))
+    _trees_close(outs[True], outs[False], atol=1e-5)
 
 
 def test_liftfree_scan_over_rounds_matches_per_round():
@@ -311,22 +313,35 @@ def test_liftfree_scan_over_rounds_matches_per_round():
     _trees_close(eng_a.global_trainable, eng_b.global_trainable, atol=1e-6)
 
 
+def _round_primitives(eng, batches):
+    """Every primitive name in the traced round program of ``eng``."""
+    k = jax.tree_util.tree_leaves(batches)[0].shape[0]
+    jaxpr = jax.make_jaxpr(lambda b: eng._round_core(
+        eng.global_trainable, eng.frozen, eng.synced_v,
+        jnp.asarray(1, jnp.int32), b, eng._normalize_weights(None, k)))(
+        batches)
+    return _primitive_names(jaxpr.jaxpr, set())
+
+
 @pytest.mark.parametrize("method", LORA_METHODS + ["fedavg_full"])
 def test_lora_and_dense_methods_untouched_by_delta_context(method):
-    """The delta context only engages for factored GaLore clients: LoRA and
-    dense methods must be BIT-identical under lift_free True/False."""
+    """The delta context only engages for factored GaLore clients: the
+    round program of a LoRA or dense method holds no custom-VJP call (the
+    LowRankDelta apply is one; a factored GaLore round, the positive
+    control, holds it), and its rounds run."""
     params, loss = _problem()
-    engines = {}
-    for lf in (True, False):
-        eng = FedEngine(FedConfig(method=method, rank=4, lr=3e-2,
-                                  local_steps=3, lift_free=lf), loss, params)
-        assert eng._lift_free is False
-        for r in range(2):
-            eng.run_round(_round_batches(r))
-        engines[lf] = eng
-    for la, lb in zip(jax.tree_util.tree_leaves(engines[True].global_trainable),
-                      jax.tree_util.tree_leaves(engines[False].global_trainable)):
-        assert jnp.array_equal(la, lb)
+    eng = FedEngine(FedConfig(method=method, rank=4, lr=3e-2,
+                              local_steps=3), loss, params)
+    assert eng._lift_free is False
+    prims = _round_primitives(eng, _round_batches(0, t=3))
+    assert not any("custom_vjp" in p for p in prims), prims
+    control = FedEngine(FedConfig(method="fedgalore_minus", rank=4,
+                                  lr=3e-2, local_steps=3), loss, params)
+    assert any("custom_vjp" in p
+               for p in _round_primitives(control, _round_batches(0, t=3)))
+    for r in range(2):
+        m = eng.run_round(_round_batches(r, t=3))
+        assert jnp.all(jnp.isfinite(m["local_loss"]))
 
 
 def test_liftfree_chunked_bit_identical():
@@ -374,6 +389,15 @@ def _dot_shapes(jaxpr, acc):
     return acc
 
 
+def _primitive_names(jaxpr, acc):
+    for eqn in jaxpr.eqns:
+        acc.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in _as_jaxprs(v):
+                _primitive_names(sub, acc)
+    return acc
+
+
 def _as_jaxprs(v):
     if hasattr(v, "jaxpr") and hasattr(v, "consts"):    # ClosedJaxpr
         return [v.jaxpr]
@@ -389,14 +413,15 @@ def _as_jaxprs(v):
 
 def _local_step_dot_shapes(lift_free: bool):
     """All dot_general output shapes in ONE compiled local training phase
-    (the T-step scan for one client) of the factored round. rank=3 keeps
+    (the T-step scan for one client) of the factored round, lift-free
+    (``_local_train_liftfree_one``) or with the transient-lift read
+    (``_local_train_factored_one``). rank=3 keeps
     every projected-space shape (m,3)/(3,n) distinct from the dense (m,n)
     target shapes the probe asserts on."""
     params, loss = _problem()
     eng = FedEngine(FedConfig(method="fedgalore_minus", rank=3, lr=3e-2,
                               local_steps=2, clip_norm=0.5,
-                              weight_decay=0.01, lift_free=lift_free),
-                    loss, params)
+                              weight_decay=0.01), loss, params)
     st0 = eng._init_state0(jnp.asarray(1, jnp.int32), None,
                            eng.global_trainable)
     d0 = gal.zero_client_deltas(gal.galore_state_of(st0))
@@ -412,51 +437,50 @@ def _local_step_dot_shapes(lift_free: bool):
 def test_liftfree_local_step_has_no_dense_mn_gemm():
     """The acceptance probe: the lift-free local phase lowers ZERO
     dot_generals with a dense (m, n) target-leaf output — no lift GEMM, no
-    dense gradient cotangent, no dense projection. The transient-lift oracle
+    dense gradient cotangent, no dense projection. The transient-lift read
     (positive control) lowers several."""
     target_shapes = {(8, 16), (16, 4)}          # the toy's target leaves
     lf = _local_step_dot_shapes(lift_free=True)
     assert not (lf & target_shapes), lf & target_shapes
     transient = _local_step_dot_shapes(lift_free=False)
-    assert transient & target_shapes            # the oracle does lift
+    assert transient & target_shapes            # the transient read lifts
 
 
 # ------------------------------------------------------ runtime parity ------
 
 def test_sharded_runtime_liftfree_matches_transient():
-    """ShardedFederation lift-free (default) vs the transient-lift oracle
-    (lift_free=False) on the smoke transformer: same per-round losses and
-    ≤5e-4 state agreement after 2 rounds. The two formulations are
-    mathematically identical; early-step Adam (√v̂ ≈ eps coordinates)
-    amplifies reduction-order noise to ~4e-5 measured — each step stays
-    lr-bounded, so the drift is noise-shaped, not divergent."""
+    """The runtime's lift-free local phase (``steps.client_round_liftfree``,
+    the default client round) vs its transient-lift read
+    (``steps.client_round_factored``) on one client of the smoke
+    transformer, from the same round-start state: same per-step losses and
+    ≤5e-4 agreement of the accumulators and optimizer states after T=2
+    steps. The two formulations are mathematically identical; early-step
+    Adam (√v̂ ≈ eps coordinates) amplifies reduction-order noise — each
+    step stays lr-bounded, so the drift is noise-shaped, not divergent."""
     from repro.configs import get_config, smoke_variant
-    from repro.fedsim import ShardedFederation
+    from repro.launch import steps as steps_lib
     from repro.launch.mesh import make_host_mesh
     from repro.launch.steps import TrainSpec
+    from repro.models.layers import batch_axes_override
 
     cfg = smoke_variant(get_config("qwen1.5-0.5b"))
     mesh = make_host_mesh(1)
     spec = TrainSpec(rank=4, lr=1e-3, local_steps=2, refresh_mode="random")
-
-    def batches(seed):
-        kk = jax.random.PRNGKey(seed)
-        toks = jax.random.randint(kk, (3, 2, 2, 8), 0, cfg.vocab_size)
-        return {"tokens": toks, "labels": toks}
-
-    feds = {lf: ShardedFederation(cfg, spec, mesh, 3, state_sync="ajive",
-                                  lift_free=lf)
-            for lf in (True, False)}
-    for r in range(2):
-        b = batches(r)
-        mf = feds[True].run_round(b)
-        mt = feds[False].run_round(b)
-        assert jnp.allclose(mf["losses"], mt["losses"], atol=1e-4)
-    for la, lb in zip(jax.tree_util.tree_leaves(feds[True].global_trainable),
-                      jax.tree_util.tree_leaves(feds[False].global_trainable)):
-        assert jnp.allclose(la.astype(jnp.float32), lb.astype(jnp.float32),
-                            atol=5e-4)
-    for la, lb in zip(jax.tree_util.tree_leaves(feds[True].opt_states),
-                      jax.tree_util.tree_leaves(feds[False].opt_states)):
+    trainable, frozen, opt_state = steps_lib.init_train_state(
+        jax.random.PRNGKey(0), cfg, spec)
+    d0 = gal.zero_client_deltas(gal.galore_state_of(opt_state))
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 2, 8), 0,
+                              cfg.vocab_size)
+    batches = {"tokens": toks, "labels": toks}
+    outs = {}
+    with jax.set_mesh(mesh), batch_axes_override(()):
+        for lf, fn in ((True, steps_lib.client_round_liftfree),
+                       (False, steps_lib.client_round_factored)):
+            outs[lf] = jax.jit(lambda d, st, b, fn=fn: fn(
+                cfg, spec, d, frozen, st, b, trainable))(d0, opt_state,
+                                                        batches)
+    assert jnp.allclose(outs[True][2], outs[False][2], atol=1e-4)
+    for la, lb in zip(jax.tree_util.tree_leaves(outs[True]),
+                      jax.tree_util.tree_leaves(outs[False])):
         assert jnp.allclose(la.astype(jnp.float32), lb.astype(jnp.float32),
                             atol=5e-4)

@@ -190,12 +190,6 @@ def test_population_runner_faulted_rounds(tmp_path):
     assert runner.store.spills > 0
 
 
-def test_population_runner_requires_fused_factored():
-    eng = _engine(fused_round=False, factored_sync=False)
-    with pytest.raises(ValueError, match="fused"):
-        _runner(eng, pop.ParticipationConfig())
-
-
 # ------------------------------------------------------ client-state store --
 
 def _store_template():

@@ -200,9 +200,12 @@ def test_robust_agg_bounds_scale_attack():
 
 
 def test_guarded_round_requires_factored_clients():
+    """The guard runs on rank-r factored stacks: a method without factored
+    clients (a LoRA baseline) refuses a quarantine config and an attack."""
     with pytest.raises(ValueError, match="factored"):
-        _engine(quarantine=True, factored_clients=False)
-    eng = _engine(factored_clients=False)
+        _engine(method="fedit", quarantine=True)
+    eng = _engine(method="fedit")
+    assert not eng._factored
     attack = np.ones(4, np.float32)
     attack[0] = -1.0          # all-ones canonicalizes away; this cannot
     with pytest.raises(ValueError, match="factored"):
@@ -461,11 +464,17 @@ def test_sharded_runtime_quarantine_honest_bit_identity():
 
 
 def test_sharded_runtime_rejects_robust_dense_clients():
+    """Refreshes that do not land on local step 0 keep dense client stacks,
+    which the quarantine screen cannot read."""
+    import dataclasses
+
     from repro.fedsim import ShardedFederation
 
     cfg, mesh, spec, _ = _runtime_setup(3)
+    spec = dataclasses.replace(spec, refresh_every=3)
+    assert spec.refresh_every % spec.local_steps
     fed = ShardedFederation(cfg, spec, mesh, 3, state_sync="ajive",
-                            factored_clients=False, quarantine=True)
+                            quarantine=True)
     with pytest.raises(ValueError, match="factored"):
         fed.run_round({"tokens": np.zeros((3, 2, 2, 8), np.int32),
                        "labels": np.zeros((3, 2, 2, 8), np.int32)})
@@ -671,18 +680,6 @@ def test_sharded_runtime_quarantine_matches_masked_round(attack_val):
                       jax.tree_util.tree_leaves(fed_m.global_trainable)):
         assert jnp.allclose(la, lb, atol=1e-5), float(
             jnp.max(jnp.abs(la - lb)))
-
-
-def test_sharded_runtime_attack_requires_fused_round():
-    from repro.fedsim import ShardedFederation
-
-    c = 3
-    cfg, mesh, spec, batches = _runtime_setup(c)
-    fed = ShardedFederation(cfg, spec, mesh, c, fused_round=False)
-    attack = np.ones(c, np.float32)
-    attack[0] = -1.0            # all-ones would canonicalize away
-    with pytest.raises(ValueError, match="fused_round"):
-        fed.run_round(batches(0), attack=attack)
 
 
 def test_sharded_runtime_robust_sync_bounds_scale_attack():

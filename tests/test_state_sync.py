@@ -3,6 +3,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import projector as proj
+from repro.core import reference
 from repro.core import state_sync as sync
 
 
@@ -117,31 +118,19 @@ def _hetero_bases(key, k, dim, r):
                       for i in range(k)])
 
 
-def _dense_hetero_oracle(protocol, v_stack, b_stack, side, w, rank):
-    """The dense per-client lift 𝒮 (what the engine's eager round-0 and the
-    runtime's factored_sync=False path execute): lift each client with its
-    own basis, sync, re-project onto the client-0 basis."""
-    v32 = v_stack.astype(jnp.float32)
-    b32 = b_stack.astype(jnp.float32)
-    if side == proj.RIGHT:
-        views = jnp.einsum("kmr,knr->kmn", v32, b32)
-    else:
-        views = jnp.einsum("kmr,krn->kmn", b32, v32)
-    lifted = sync.sync_lifted_views(protocol, views, w, rank)
-    return sync.project_state(lifted, b_stack[0], side)
-
-
 @pytest.mark.parametrize("side", [proj.RIGHT, proj.LEFT])
 @pytest.mark.parametrize("protocol", ["avg", "avg_svd", "ajive"])
 def test_hetero_factored_matches_dense_lift(side, protocol):
-    """sync_block_hetero_factored ≡ the dense per-client lift oracle to ≤1e-5
-    for every protocol and both sides — the r×r transfer-Gram path replaces
-    the last dense (C, m, n) 𝒮."""
+    """sync_block_hetero_factored ≡ the dense per-client lift
+    (``reference.dense_sync_block``: lift each client with its own basis,
+    sync, re-project onto the client-0 basis) to ≤1e-5 for every protocol
+    and both sides — the r×r transfer-Gram path replaces the last dense
+    (C, m, n) 𝒮."""
     r, dim, k = 4, 24, 5
     v_stack = _structured_stack(jax.random.PRNGKey(3), side, k=k, r=r)
     b_stack = _hetero_bases(jax.random.PRNGKey(7), k, dim, r)
     w = jnp.array([1, 2, 1, 1, 3.0])
-    dense = _dense_hetero_oracle(protocol, v_stack, b_stack, side, w, r)
+    dense = reference.dense_sync_block(protocol, v_stack, b_stack, w, r, side)
     fact = sync.sync_block_hetero_factored(protocol, v_stack, b_stack, side,
                                            weights=w, rank=r)
     assert fact.shape == dense.shape == v_stack.shape[1:]

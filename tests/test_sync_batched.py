@@ -1,18 +1,20 @@
 """Lock-in suite for the batched-bucket 𝒮 and the one-round pipelined scan.
 
-Two independent equivalences, each with its surviving oracle:
+Two independent equivalences, each against its reference:
 
 * **Bucketed 𝒮 ≡ per-leaf 𝒮** (`state_sync.map_sync_leaves`): shape-bucketed
-  vmapped sync must reproduce the per-leaf loop (`bucketed=False`) for every
-  protocol, both sides, stacked scan-block leaves, shared AND heterogeneous
-  (transfer-Gram) bases, masked cohorts, and the robust-𝒜 round variants.
-  On CPU the batched eigh is bit-identical, so tolerances are fp-noise tight.
+  vmapped sync must reproduce the per-leaf loop
+  (`state_sync.map_sync_leaves_per_leaf`) for every protocol, both sides,
+  stacked scan-block leaves, shared AND heterogeneous (transfer-Gram) bases
+  and masked cohorts — in units, and on the engine's and the runtime's own
+  uplinked states. On CPU the batched eigh is bit-identical, so tolerances
+  are fp-noise tight.
 
 * **Pipelined rounds ≡ sequential rounds**: the pipelined `run_rounds` scan
   (round k's 𝒮 deferred to the top of round k+1, post-scan drain) is a pure
-  re-association of the sequential schedule — state-for-state identical
-  results in both the engine (`core.fed`) and the sharded runtime
-  (`fedsim.runtime`), with `pipeline_sync=False` as the oracle.
+  re-association of the sequential schedule — state-for-state the results
+  of K successive `run_round` calls in both the engine (`core.fed`) and the
+  sharded runtime (`fedsim.runtime`).
 """
 import jax
 import jax.numpy as jnp
@@ -95,8 +97,8 @@ def test_map_sync_leaves_bucketed_matches_per_leaf(protocol, hetero, masked):
         return sync.sync_block_synced_factored(
             protocol, v_stack, side, w, rank, exclude_zero_weights=masked)
 
-    ref = sync.map_sync_leaves(leaf_fn, v_leaves, b_leaves, bucketed=False)
-    out = sync.map_sync_leaves(leaf_fn, v_leaves, b_leaves, bucketed=True)
+    ref = sync.map_sync_leaves_per_leaf(leaf_fn, v_leaves, b_leaves)
+    out = sync.map_sync_leaves(leaf_fn, v_leaves, b_leaves)
     assert out[5] is None and ref[5] is None
     for o, rf in zip(out, ref):
         if rf is None:
@@ -134,7 +136,7 @@ def test_ajive_sketch_route_matches_dense_oracle():
     out = sync.map_sync_leaves(
         lambda v, b: sync.sync_block_synced_factored(
             "ajive", v, proj.RIGHT, w, r),
-        [v_stack, v_stack + 0.01], [jnp.zeros((c, n, r))] * 2, bucketed=True)
+        [v_stack, v_stack + 0.01], [jnp.zeros((c, n, r))] * 2)
     assert jnp.allclose(out[0], fact, atol=1e-6)
 
 
@@ -177,52 +179,86 @@ def _engine(method, **over):
     return FedEngine(FedConfig(**cfg), loss, params)
 
 
+def _per_leaf(fn, *args):
+    """``fn(*args)`` jitted with the per-leaf 𝒮 mapping in place of the
+    bucketed one. A fresh wrapper is traced inside the swap (jit's trace
+    cache is keyed on the function, so ``jax.jit(fn)`` could reuse the
+    bucketed trace), and the swap must have been reached unless the method
+    has no 𝒮."""
+    calls = []
+
+    def per_leaf(*a):
+        calls.append(1)
+        return sync.map_sync_leaves_per_leaf(*a)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sync, "map_sync_leaves", per_leaf)
+        out = jax.jit(lambda *a: fn(*a))(*args)
+    assert calls or out is None
+    return out
+
+
 @pytest.mark.parametrize("method", GALORE_METHODS)
 def test_engine_bucketed_sync_matches_per_leaf(method):
-    """bucketed_sync=True ≡ bucketed_sync=False through full engine rounds —
-    covers the adaptive round-0 hetero (transfer-Gram) 𝒮 and the shared-basis
-    steady state, on a tree with a genuine multi-leaf shape bucket."""
-    engines = {}
-    for bucketed in (True, False):
-        eng = _engine(method, bucketed_sync=bucketed)
-        for r in range(2):
-            eng.run_round(_round_batches(r))
-        engines[bucketed] = eng
-    _trees_close(engines[True].global_trainable,
-                 engines[False].global_trainable, atol=1e-6)
-    if engines[False].synced_v is not None:
-        _trees_close(engines[True].synced_v, engines[False].synced_v,
-                     atol=1e-6)
+    """The engine's bucketed 𝒮 ≡ the per-leaf loop on its own uplinked
+    states (each round's end-of-round client optimizer states) — covers the
+    adaptive round-0 hetero (transfer-Gram) 𝒮 and the shared-basis steady
+    state, on a tree with a genuine multi-leaf shape bucket. The bucketed
+    result is also what the fused round installed."""
+    eng = _engine(method)
+    for r in range(2):
+        eng.run_round(_round_batches(r))
+        w = eng._normalize_weights(None, 4)
+        up = eng._client_opt
+
+        def sync_fn(o, r=r):
+            return eng._sync_states_pure(o, w, r)
+        bucketed = jax.jit(sync_fn)(up)
+        per_leaf = _per_leaf(sync_fn, up)
+        if bucketed is None:
+            assert per_leaf is None and eng.synced_v is None
+            continue
+        _trees_close(bucketed, per_leaf, atol=1e-6)
+        _trees_close(bucketed, eng.synced_v, atol=1e-6)
+
+
+def _engine_rounds_one_by_one(eng, round_batches, masks=None):
+    """K successive run_round calls; (global, synced ṽ, (K, C, T) losses)."""
+    k_rounds = jax.tree_util.tree_leaves(round_batches)[0].shape[0]
+    losses = [eng.run_round(
+        jax.tree_util.tree_map(lambda x, r=r: x[r], round_batches),
+        mask=None if masks is None else masks[r])["local_loss"]
+        for r in range(k_rounds)]
+    return eng.global_trainable, eng.synced_v, jnp.stack(losses)
 
 
 @pytest.mark.parametrize("method", GALORE_METHODS)
 def test_engine_pipelined_rounds_match_sequential(method):
-    """Pipelined K-round scan ≡ sequential scan (pipeline_sync=False oracle)
-    over K=5 rounds: global trainable, synced moments, and every per-round
-    loss, for every GaLore method."""
-    outs = {}
-    for pipe in (True, False):
-        eng = _engine(method, pipeline_sync=pipe)
-        m = eng.run_rounds(_round_batches(3, k_rounds=5))
-        outs[pipe] = (eng.global_trainable, eng.synced_v, m["local_loss"])
-    for a, b in zip(outs[True], outs[False]):
+    """Pipelined K-round scan ≡ K successive run_round calls over K=5
+    rounds: global trainable, synced moments, and every per-round loss, for
+    every GaLore method."""
+    rb = _round_batches(3, k_rounds=5)
+    eng = _engine(method)
+    m = eng.run_rounds(rb)
+    pipe = (eng.global_trainable, eng.synced_v, m["local_loss"])
+    seq = _engine_rounds_one_by_one(_engine(method), rb)
+    for a, b in zip(pipe, seq):
         _trees_close(a, b, atol=1e-6)
 
 
 def test_engine_pipelined_masked_rounds_match_sequential():
     """Per-round participation masks ride the pipelined scan: the deferred 𝒮
     must use the *previous* round's mask-zeroed weights (carried alongside
-    the unsynced states), matching the sequential masked scan exactly."""
+    the unsynced states), matching K masked run_round calls exactly."""
     k_rounds, c = 5, 4
     masks = np.ones((k_rounds, c), bool)
     masks[1, 0] = False
     masks[3, 2] = masks[3, 3] = False
-    outs = {}
-    for pipe in (True, False):
-        eng = _engine("fedgalore", pipeline_sync=pipe)
-        m = eng.run_rounds(_round_batches(5, k_rounds=k_rounds), masks=masks)
-        outs[pipe] = (eng.global_trainable, eng.synced_v, m["local_loss"])
-    for a, b in zip(outs[True], outs[False]):
+    rb = _round_batches(5, k_rounds=k_rounds)
+    eng = _engine("fedgalore")
+    m = eng.run_rounds(rb, masks=masks)
+    pipe = (eng.global_trainable, eng.synced_v, m["local_loss"])
+    seq = _engine_rounds_one_by_one(_engine("fedgalore"), rb, masks)
+    for a, b in zip(pipe, seq):
         _trees_close(a, b, atol=1e-6)
 
 
@@ -230,28 +266,15 @@ def test_engine_pipelined_masked_rounds_match_sequential():
 def test_engine_pipelined_robust_agg_matches_sequential(robust):
     """The guarded (robust-𝒜) scan pipelines too: skip_sync captures the
     post-guard effective weights, so deferring 𝒮 by one round changes
-    nothing."""
-    outs = {}
-    for pipe in (True, False):
-        eng = _engine("fedgalore", robust_agg=robust, pipeline_sync=pipe)
-        m = eng.run_rounds(_round_batches(7, k_rounds=3))
-        outs[pipe] = (eng.global_trainable, eng.synced_v, m["local_loss"])
-    for a, b in zip(outs[True], outs[False]):
+    nothing against K guarded run_round calls."""
+    rb = _round_batches(7, k_rounds=3)
+    eng = _engine("fedgalore", robust_agg=robust)
+    m = eng.run_rounds(rb)
+    pipe = (eng.global_trainable, eng.synced_v, m["local_loss"])
+    seq = _engine_rounds_one_by_one(_engine("fedgalore", robust_agg=robust),
+                                    rb)
+    for a, b in zip(pipe, seq):
         _trees_close(a, b, atol=1e-6)
-
-
-def test_engine_single_round_ignores_pipeline_flag():
-    """run_round (one round) has nothing to overlap — pipeline_sync must not
-    change its result vs the sequential engine."""
-    engines = {}
-    for pipe in (True, False):
-        eng = _engine("fedgalore", pipeline_sync=pipe)
-        for r in range(2):
-            eng.run_round(_round_batches(r))
-        engines[pipe] = eng
-    _trees_close(engines[True].global_trainable,
-                 engines[False].global_trainable, atol=0.0)
-    _trees_close(engines[True].synced_v, engines[False].synced_v, atol=0.0)
 
 
 # ---------------------------------------------- sharded runtime (fedsim) ----
@@ -275,30 +298,46 @@ def _runtime_setup(c_clients=3):
     return cfg, mesh, spec, batches
 
 
-def test_runtime_bucketed_sync_matches_per_leaf():
-    """ShardedFederation bucketed in-mesh 𝒮 ≡ the per-leaf loop on the real
-    transformer tree (shared seeded bases)."""
+def _runtime_uplink(cfg, spec, mesh, c, bat):
+    """The runtime's own uplinked client states after one round: a
+    ``state_sync='none'`` federation's round leaves them in place (its 𝒮
+    only bumps the seed)."""
     from repro.fedsim import ShardedFederation
 
+    fed = ShardedFederation(cfg, spec, mesh, c, state_sync="none")
+    fed.run_round(bat)
+    return fed.opt_states
+
+
+def _runtime_sync_matches_per_leaf(cfg, spec, mesh, c, bat, bases_shared):
+    from repro.launch import steps as steps_lib
+
+    up = _runtime_uplink(cfg, spec, mesh, c, bat)
+    w = jnp.full((c,), 1.0 / c)
+
+    def sync_fn(st):
+        return steps_lib.sync_client_states(st, w, c, "ajive",
+                                            bases_shared=bases_shared)
+    with jax.set_mesh(mesh):
+        bucketed = jax.jit(sync_fn)(up)
+        per_leaf = _per_leaf(sync_fn, up)
+    _trees_close(bucketed, per_leaf, atol=1e-6)
+
+
+def test_runtime_bucketed_sync_matches_per_leaf():
+    """ShardedFederation's bucketed in-mesh 𝒮 (`steps.sync_client_states`)
+    ≡ the per-leaf loop on the runtime's own uplink of the real transformer
+    tree (shared seeded bases)."""
     c = 3
     cfg, mesh, spec, batches = _runtime_setup(c)
-    feds = {b: ShardedFederation(cfg, spec, mesh, c, state_sync="ajive",
-                                 bucketed_sync=b)
-            for b in (True, False)}
-    for r in range(2):
-        bat = batches(r)
-        feds[True].run_round(bat)
-        feds[False].run_round(bat)
-    _trees_close(feds[True].global_trainable, feds[False].global_trainable,
-                 atol=1e-6)
-    _trees_close(feds[True].opt_states, feds[False].opt_states, atol=1e-6)
+    _runtime_sync_matches_per_leaf(cfg, spec, mesh, c, batches(0),
+                                   bases_shared=True)
 
 
 def test_runtime_bucketed_hetero_sync_matches_per_leaf():
     """refresh_mode='svd' diverges the bases, so the bucketed 𝒮 runs the
     transfer-Gram hetero path under vmap — must match the per-leaf loop."""
     from repro.configs import get_config, smoke_variant
-    from repro.fedsim import ShardedFederation
     from repro.launch.mesh import make_host_mesh
     from repro.launch.steps import TrainSpec
 
@@ -309,68 +348,81 @@ def test_runtime_bucketed_hetero_sync_matches_per_leaf():
     toks = jax.random.randint(jax.random.PRNGKey(3), (3, 2, 2, 8), 0,
                               cfg.vocab_size)
     bat = {"tokens": toks, "labels": toks}
-    feds = {b: ShardedFederation(cfg, spec, mesh, 3, state_sync="ajive",
-                                 bucketed_sync=b)
-            for b in (True, False)}
-    feds[True].run_round(bat)
-    feds[False].run_round(bat)
-    _trees_close(feds[True].global_trainable, feds[False].global_trainable,
-                 atol=1e-6)
-    _trees_close(feds[True].opt_states, feds[False].opt_states, atol=1e-6)
+    _runtime_sync_matches_per_leaf(cfg, spec, mesh, 3, bat,
+                                   bases_shared=False)
+
+
+def _runtime_rounds_one_by_one(fed, bat, masks=None):
+    """K successive run_round calls; (global, states, (K, C, T) losses)."""
+    k_rounds = jax.tree_util.tree_leaves(bat)[0].shape[0]
+    losses = [fed.run_round(
+        jax.tree_util.tree_map(lambda x, r=r: x[r], bat),
+        mask=None if masks is None else masks[r])["losses"]
+        for r in range(k_rounds)]
+    return fed.global_trainable, fed.opt_states, jnp.stack(losses)
+
+
+def _restart(fed, start):
+    """Put ``fed`` back at round 0 on copies of the ``start`` (global
+    trainable, client states): every round donates its inputs. One
+    federation per driver then serves every pass, compiling each of its
+    programs once."""
+    fed.global_trainable, fed.opt_states = jax.tree_util.tree_map(
+        jnp.copy, start)
+    fed.round_idx = 0
+    return fed
+
+
+def _pipelined_vs_one_by_one(make, bat, mask_sets):
+    """For each entry of ``mask_sets``: ``run_rounds`` (pipelined) against
+    K successive ``run_round`` calls from the same start, ≤1e-6."""
+    pipe_fed, seq_fed = make(), make()
+    assert pipe_fed._pipeline_rounds()
+    start = jax.tree_util.tree_map(
+        jnp.copy, (pipe_fed.global_trainable, pipe_fed.opt_states))
+    for mk in mask_sets:
+        fed = _restart(pipe_fed, start)
+        m = fed.run_rounds(bat, masks=mk)
+        pipe = (fed.global_trainable, fed.opt_states, m["losses"])
+        seq = _runtime_rounds_one_by_one(_restart(seq_fed, start), bat, mk)
+        for a, b in zip(pipe, seq):
+            _trees_close(a, b, atol=1e-6)
 
 
 def test_runtime_pipelined_rounds_match_sequential():
-    """Pipelined run_rounds ≡ sequential in the sharded runtime, unmasked
-    and with per-round participation masks (the deferred 𝒮 carries each
-    round's mask-zeroed weights)."""
+    """Pipelined run_rounds ≡ K successive run_round calls in the sharded
+    runtime, unmasked and with per-round participation masks (the deferred
+    𝒮 carries each round's mask-zeroed weights)."""
     from repro.fedsim import ShardedFederation
 
     c, k_rounds = 3, 5
     cfg, mesh, spec, batches = _runtime_setup(c)
-    bat = batches(7, k_rounds=k_rounds)
     masks = np.ones((k_rounds, c), bool)
     masks[0, 1] = False
     masks[2, 0] = False
     masks[4, 1] = masks[4, 2] = False
-    for mk in (None, masks):
-        outs = {}
-        for pipe in (True, False):
-            fed = ShardedFederation(cfg, spec, mesh, c, state_sync="ajive",
-                                    pipeline_sync=pipe)
-            m = fed.run_rounds(bat, masks=mk)
-            outs[pipe] = (fed.global_trainable, fed.opt_states, m["losses"])
-        for a, b in zip(outs[True], outs[False]):
-            _trees_close(a, b, atol=1e-6)
+    _pipelined_vs_one_by_one(
+        lambda: ShardedFederation(cfg, spec, mesh, c, state_sync="ajive"),
+        batches(7, k_rounds=k_rounds), (None, masks))
 
 
 def test_runtime_quarantine_pipelines_and_matches_sequential():
     """Quarantine used to force the sequential scan (the screen rewrites
     effective weights inside the round, invisible to the deferred 𝒮). The
-    raw round core now returns its post-screen weights (return_weights) and
+    raw round core returns its post-screen weights (return_weights) and
     they ride the scan carry — so the quarantined scan pipelines AND
-    matches the sequential oracle, unmasked and masked."""
+    matches K quarantined run_round calls, unmasked and masked. A method
+    without 𝒮 has nothing to pipeline."""
     from repro.fedsim import ShardedFederation
 
     c, k_rounds = 3, 4
     cfg, mesh, spec, batches = _runtime_setup(c)
-    fed = ShardedFederation(cfg, spec, mesh, c, state_sync="ajive",
-                            quarantine=True, pipeline_sync=True)
-    assert fed._pipeline_rounds()
-    fed_off = ShardedFederation(cfg, spec, mesh, c, state_sync="ajive",
-                                pipeline_sync=False)
-    assert not fed_off._pipeline_rounds()
-
-    bat = batches(9, k_rounds=k_rounds)
     masks = np.ones((k_rounds, c), bool)
     masks[1, 0] = False
     masks[3, 2] = False
-    for mk in (None, masks):
-        outs = {}
-        for pipe in (True, False):
-            fed = ShardedFederation(cfg, spec, mesh, c, state_sync="ajive",
-                                    quarantine=True, quarantine_zmax=50.0,
-                                    pipeline_sync=pipe)
-            m = fed.run_rounds(bat, masks=mk)
-            outs[pipe] = (fed.global_trainable, fed.opt_states, m["losses"])
-        for a, b in zip(outs[True], outs[False]):
-            _trees_close(a, b, atol=1e-6)
+    _pipelined_vs_one_by_one(
+        lambda: ShardedFederation(cfg, spec, mesh, c, state_sync="ajive",
+                                  quarantine=True, quarantine_zmax=50.0),
+        batches(9, k_rounds=k_rounds), (None, masks))
+    no_sync = ShardedFederation(cfg, spec, mesh, c, state_sync="none")
+    assert not no_sync._pipeline_rounds()
